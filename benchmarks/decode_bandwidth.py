@@ -23,7 +23,12 @@ through the cache read path).
 
 Usage:
     PYTHONPATH=src python benchmarks/decode_bandwidth.py [--smoke] \
-        [--out BENCH_decode.json]
+        [--interpret] [--out BENCH_decode.json]
+
+--interpret runs the Pallas kernels in the interpreter, the way to run on
+a CPU; its walls are not device timings, and the script refuses it on a
+TPU. Without it the kernels are compiled, which needs a TPU. The report's
+meta records both `backend` and `interpret`.
 """
 from __future__ import annotations
 
@@ -121,7 +126,7 @@ def _elements(t: int) -> int:
     return 2 * t * BENCH_CFG.num_kv_heads * d_pad
 
 
-def run(t_values, reps: int) -> dict:
+def run(t_values, reps: int, interpret: bool) -> dict:
     rng = np.random.default_rng(0)
     rows = []
     for t in t_values:
@@ -138,9 +143,8 @@ def run(t_values, reps: int) -> dict:
             qz = _quantizer(storage)
             cache = _filled_quant_cache(qz, t, rng)
             for name in ("quant-xla", "quant-pallas"):
-                # interpret=None: compiled kernel on TPU, interpreter on CPU
-                # CI — timings are only meaningful on real hardware
-                be = backends_lib.get_backend(name, BENCH_CFG, qz)
+                be = backends_lib.get_backend(name, BENCH_CFG, qz,
+                                              interpret=interpret)
                 nbytes = be.attend_stream_bytes(cache)
                 csec, sec = _time_attend(be, cache, rng, reps)
                 row = dict(
@@ -219,10 +223,16 @@ def main(argv=None) -> int:
     ap.add_argument("--out", type=Path, default=Path("BENCH_decode.json"))
     ap.add_argument("--reps", type=int, default=0,
                     help="timing reps per cell (0 -> 1 smoke / 3 full)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in the interpreter (CPU); "
+                         "without it they are compiled, which needs a TPU")
     args = ap.parse_args(argv)
+    if args.interpret and jax.default_backend() == "tpu":
+        ap.error("--interpret on a TPU would time the Pallas interpreter "
+                 "and label it tpu; drop --interpret")
     t_values = SMOKE_T if args.smoke else FULL_T
     reps = args.reps or (1 if args.smoke else 3)
-    rows = run(t_values, reps)
+    rows = run(t_values, reps, args.interpret)
     report = {
         "meta": {
             "model": {k: getattr(BENCH_CFG, k) for k in
@@ -232,6 +242,7 @@ def main(argv=None) -> int:
             "v_norm": rates.NORM_V4_LOG.describe(),
             "smoke": args.smoke,
             "backend": jax.default_backend(),
+            "interpret": args.interpret,
         },
         "rows": rows,
         "summary": summarize(rows),

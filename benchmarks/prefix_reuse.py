@@ -28,7 +28,12 @@ Emits BENCH_prefix.json and exits non-zero when
 
 Usage:
     PYTHONPATH=src python benchmarks/prefix_reuse.py [--smoke] \
-        [--out BENCH_prefix.json]
+        [--interpret] [--out BENCH_prefix.json]
+
+--interpret runs the Pallas kernels in the interpreter, the way to run on
+a CPU; its walls are not device timings, and the script refuses it on a
+TPU. Without it the kernels are compiled, which needs a TPU. The report's
+meta records both `backend` and `interpret`.
 """
 from __future__ import annotations
 
@@ -143,7 +148,13 @@ def main(argv=None) -> int:
                     help="tiny trace for CI")
     ap.add_argument("--out", type=Path, default=Path("BENCH_prefix.json"))
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in the interpreter (CPU); "
+                         "without it they are compiled, which needs a TPU")
     args = ap.parse_args(argv)
+    if args.interpret and jax.default_backend() == "tpu":
+        ap.error("--interpret on a TPU would time the Pallas interpreter "
+                 "and label it tpu; drop --interpret")
     p = SMOKE if args.smoke else FULL
 
     params, _ = transformer.init_params(jax.random.PRNGKey(0), BENCH_CFG)
@@ -152,7 +163,7 @@ def main(argv=None) -> int:
         schedule=mixedkv.uniform(BENCH_CFG.num_layers),
         k_norm=rates.NORM_K8, v_norm=rates.NORM_V4_LOG, storage="bitpack"))
     backend = backends_lib.QuantPallasBackend(
-        BENCH_CFG, qz, interpret=None, block_t=p["page_size"])
+        BENCH_CFG, qz, interpret=args.interpret, block_t=p["page_size"])
     reqs = make_trace(p, args.seed)
 
     cold_toks, cold_stats = run_mode(p, params, backend, reqs, "cold")
@@ -168,6 +179,7 @@ def main(argv=None) -> int:
             "trace": {k: p[k] for k in p},
             "smoke": args.smoke,
             "backend": jax.default_backend(),
+            "interpret": args.interpret,
         },
         "tokens_match": match,
         "cold": cold_stats,

@@ -13,7 +13,12 @@ backend at EQUAL slot capacity:
                 it gets the best case of all requests present at t=0 and
                 the same kernel block size as the paged engine
                 (block_t = page_size), so the comparison isolates
-                *scheduling*, not kernel granularity.
+                *scheduling*, not kernel granularity. One cost differs:
+                the contiguous attend (`kernels.qattn.qattn`) copies each
+                layer's cache into page layout on every call, which the
+                paged engine does not; until that copy is measured on a
+                chip, a device speedup over this baseline overstates what
+                scheduling alone buys.
     continuous  `serving.scheduler.PagedServingEngine` — requests admitted
                 into decode slots on arrival, chunked prefill, burst
                 decoding, eviction on budget with pages freed immediately.
@@ -28,7 +33,12 @@ BENCH_serve.json and exits non-zero when
 
 Usage:
     PYTHONPATH=src python benchmarks/serve_throughput.py [--smoke] \
-        [--out BENCH_serve.json]
+        [--interpret] [--out BENCH_serve.json]
+
+--interpret runs the Pallas kernels in the interpreter, the way to run on
+a CPU; its walls are not device timings, and the script refuses it on a
+TPU. Without it the kernels are compiled, which needs a TPU. The report's
+meta records both `backend` and `interpret`.
 """
 from __future__ import annotations
 
@@ -220,7 +230,13 @@ def main(argv=None) -> int:
                     help="tiny trace for CI")
     ap.add_argument("--out", type=Path, default=Path("BENCH_serve.json"))
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in the interpreter (CPU); "
+                         "without it they are compiled, which needs a TPU")
     args = ap.parse_args(argv)
+    if args.interpret and jax.default_backend() == "tpu":
+        ap.error("--interpret on a TPU would time the Pallas interpreter "
+                 "and label it tpu; drop --interpret")
     p = SMOKE if args.smoke else FULL
 
     params, _ = transformer.init_params(jax.random.PRNGKey(0), BENCH_CFG)
@@ -232,7 +248,7 @@ def main(argv=None) -> int:
     # granularity as the paged engine (and makes the token comparison
     # bit-for-bit: identical online-softmax accumulation order)
     backend = backends_lib.QuantPallasBackend(
-        BENCH_CFG, qz, interpret=None, block_t=p["page_size"])
+        BENCH_CFG, qz, interpret=args.interpret, block_t=p["page_size"])
     reqs = make_trace(p, args.seed)
 
     static_toks, static_stats = run_static(params, backend, reqs,
@@ -249,6 +265,7 @@ def main(argv=None) -> int:
             "trace": {k: p[k] for k in p},
             "smoke": args.smoke,
             "backend": jax.default_backend(),
+            "interpret": args.interpret,
         },
         "tokens_match": match,
         "static": static_stats,

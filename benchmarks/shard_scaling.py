@@ -1,9 +1,12 @@
 """Sharded-serving scaling: per-device pool HBM shrinks ~1/N, tokens exact.
 
-Serves one trace through `serving.scheduler.PagedServingEngine` at mesh
-sizes {1, 2, 4} on a simulated host mesh (the module forces
---xla_force_host_platform_device_count=8 before importing jax, so it
-runs anywhere) and reports, per mesh size:
+A CPU rehearsal tool: it forces the CPU platform and
+--xla_force_host_platform_device_count=8 before importing jax and runs
+the Pallas kernels in the interpreter, so it runs anywhere and never
+touches an accelerator. (The mesh path on real chips is run by
+`python chip_smoke.py --chips 4`.) Serves one trace through
+`serving.scheduler.PagedServingEngine` at mesh sizes {1, 2, 4} on the
+simulated host mesh and reports, per mesh size:
 
   * bitwise token parity against the mesh=None single-device engine —
     THE sharding contract; `tokens_match` gates,

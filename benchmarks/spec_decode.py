@@ -29,7 +29,12 @@ bandwidth-bound hardware).
 
 Usage:
     PYTHONPATH=src python benchmarks/spec_decode.py [--smoke] \
-        [--out BENCH_spec.json]
+        [--interpret] [--out BENCH_spec.json]
+
+--interpret runs the Pallas kernels in the interpreter, the way to run on
+a CPU; its walls are not device timings, and the script refuses it on a
+TPU. Without it the kernels are compiled, which needs a TPU. The report's
+meta records both `backend` and `interpret`.
 """
 from __future__ import annotations
 
@@ -148,7 +153,13 @@ def main(argv=None) -> int:
                     help="tiny trace for CI")
     ap.add_argument("--out", type=Path, default=Path("BENCH_spec.json"))
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the Pallas kernels in the interpreter (CPU); "
+                         "without it they are compiled, which needs a TPU")
     args = ap.parse_args(argv)
+    if args.interpret and jax.default_backend() == "tpu":
+        ap.error("--interpret on a TPU would time the Pallas interpreter "
+                 "and label it tpu; drop --interpret")
     p = SMOKE if args.smoke else FULL
 
     params, _ = transformer.init_params(jax.random.PRNGKey(0), BENCH_CFG)
@@ -156,7 +167,8 @@ def main(argv=None) -> int:
         head_dim=BENCH_CFG.head_dim,
         schedule=mixedkv.uniform(BENCH_CFG.num_layers),
         k_norm=rates.NORM_K8, v_norm=rates.NORM_V4_LOG, storage="bitpack"))
-    backend = backends_lib.QuantPallasBackend(BENCH_CFG, qz, interpret=None)
+    backend = backends_lib.QuantPallasBackend(BENCH_CFG, qz,
+                                              interpret=args.interpret)
     reqs = make_trace(p, args.seed)
 
     t0 = time.perf_counter()
@@ -174,6 +186,7 @@ def main(argv=None) -> int:
             "trace": {k: p[k] for k in p},
             "smoke": args.smoke,
             "backend": jax.default_backend(),
+            "interpret": args.interpret,
             "bench_wall_s": time.perf_counter() - t0,
         },
         "tokens_match": match,

@@ -2,9 +2,9 @@
 
 TPU adaptation of the usual GPU warp-shuffle packers: everything is a
 vectorized shift/or over a trailing "codes-per-word" axis, which lowers to
-plain VPU integer ops (and is reused verbatim inside Pallas kernels — the
-qattn decode kernel calls `unpack_bits` on its VMEM word block and the
-encode kernel calls `pack_bits` before its store).
+plain VPU integer ops (and is reused inside Pallas kernels — the qattn
+decode kernel calls `unpack_bits_T` on its VMEM word block and the encode
+kernel calls `pack_bits` before its store).
 
 Layout: the last axis of `codes` (length m) is treated as a flat
 little-endian bitstream of m*b bits, stored in ceil(m*b/32) uint32 words.
@@ -54,83 +54,15 @@ def pack_bits(codes: jax.Array, bits: int) -> jax.Array:
     return jnp.sum(flat << word_shifts, axis=-1, dtype=jnp.uint32)
 
 
-#: unpack_bits implementations. "bitplane" expands every stream bit
-#: (O(bits) vector ops per code, the TPU-lane-friendly scheme); "gather"
-#: reads each code's at-most-two straddled words directly (O(1) ops per
-#: code — two static gathers, shift, or, mask); "sliced" exploits the
-#: lcm(bits, 32) periodicity of the code->word map to unpack with static
-#: slices and shifts only — no gather at all, which matters on backends
-#: where gather lowers to a scalar loop (XLA CPU). Same bit layout,
-#: bitwise identical outputs; which one is fastest is a backend property,
-#: so the kernel wrapper / autotuner picks (sliced wins on CPU interpret,
-#: bitplane is the known-good vectorization on TPU VPU lanes).
-UNPACK_METHODS = ("bitplane", "gather", "sliced")
-
-
-def unpack_bits(words: jax.Array, bits: int, m: int,
-                method: str = "bitplane") -> jax.Array:
+def unpack_bits(words: jax.Array, bits: int, m: int) -> jax.Array:
     """Inverse of pack_bits -> int32 (..., m).
 
-    `method` selects the implementation (`UNPACK_METHODS`); both read the
-    identical little-endian layout and return identical bits.
+    Expands every stream bit (O(bits) vector ops per code) and recombines
+    each code's `bits` consecutive stream bits.
     """
     n_words = packed_words(m, bits)
     if words.shape[-1] != n_words:
         raise ValueError(f"expected {n_words} words, got {words.shape[-1]}")
-    if method == "sliced":
-        # the code->word map repeats every lcm(bits, 32) stream bits, i.e.
-        # every g_c = 32/gcd codes spanning g_w = bits/gcd whole words, so
-        # within a group each code's word index and shift are *static*:
-        # the whole unpack is slices, shifts and ors — no gather. Needs the
-        # periodic structure to tile m exactly; falls back to gather when
-        # it does not (then the tail word would be a partial group).
-        g = int(np.gcd(bits, 32))
-        g_c, g_w = 32 // g, bits // g
-        if m % g_c == 0:
-            wg = words.astype(jnp.uint32).reshape(
-                *words.shape[:-1], m // g_c, g_w)
-            mask = jnp.uint32((1 << bits) - 1)
-            outs = []
-            for j in range(g_c):
-                lo, sh = j * bits // 32, j * bits % 32
-                part = wg[..., lo] >> jnp.uint32(sh)
-                if sh + bits > 32:  # code straddles into the next word
-                    part = part | (wg[..., lo + 1] << jnp.uint32(32 - sh))
-                outs.append(part & mask)
-            out = jnp.stack(outs, axis=-1)  # (..., m//g_c, g_c)
-            return out.reshape(*words.shape[:-1], m).astype(jnp.int32)
-        method = "gather"
-    if method == "gather":
-        # code i occupies stream bits [i*b, (i+1)*b): low part in word
-        # i*b//32 at offset i*b%32, any straddle in the next word. The
-        # index/shift vectors are derived from an iota (not closed-over
-        # arrays) so the scheme is usable inside Pallas kernel bodies,
-        # which reject captured array constants. The word stream is
-        # extended by one tail word so lo+1 is always in range and the
-        # lo/hi takes share one index vector — XLA fuses the adjacent
-        # gathers, ~3x cheaper than two independently-clamped takes.
-        pos = jax.lax.broadcasted_iota(jnp.uint32, (m,), 0) * jnp.uint32(
-            bits)
-        lo = (pos // 32).astype(jnp.int32)
-        sh = pos % 32
-        w = words.astype(jnp.uint32)
-        if 32 % bits:  # codes can straddle a word boundary
-            wext = jnp.concatenate([w, w[..., -1:]], axis=-1)
-            pair = jnp.stack([jnp.take(wext, lo, axis=-1),
-                              jnp.take(wext, lo + 1, axis=-1)], axis=-1)
-            lo_part = pair[..., 0] >> sh
-            # (32 - sh) % 32 keeps the shift defined at sh == 0, where the
-            # where() masks the hi contribution off anyway
-            hi_part = jnp.where(sh + jnp.uint32(bits) > 32,
-                                pair[..., 1] << ((jnp.uint32(32) - sh) % 32),
-                                jnp.uint32(0))
-            lo_part = lo_part | hi_part
-        else:
-            lo_part = jnp.take(w, lo, axis=-1) >> sh
-        return (lo_part & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
-    if method != "bitplane":
-        raise ValueError(
-            f"unknown unpack method {method!r}; expected {UNPACK_METHODS}")
     word_shifts = jnp.arange(32, dtype=jnp.uint32)
     bits_arr = (words[..., None] >> word_shifts) & jnp.uint32(1)
     flat = bits_arr.reshape(*words.shape[:-1], n_words * 32)
@@ -139,39 +71,33 @@ def unpack_bits(words: jax.Array, bits: int, m: int,
     return jnp.sum(flat << shifts, axis=-1, dtype=jnp.uint32).astype(jnp.int32)
 
 
-def unpack_bits_T(words: jax.Array, bits: int, m: int,
-                  method: str = "bitplane") -> jax.Array:
+def unpack_bits_T(words: jax.Array, bits: int, m: int) -> jax.Array:
     """unpack_bits with a transposed contract: (bt, words) -> (m, bt).
 
-    The code axis LEADS the output — the layout the qattn kernels dequant
-    in (token-minor tiles). For the "gather" method this is the layout
-    where the two word lookups become whole-row copies (every output code
-    row reads ONE word row), which vectorizes on backends where minor-axis
-    gathers lower to scalar loops (XLA CPU). Other methods unpack in
-    natural layout and transpose. 2-D input only; bitwise identical to
-    `unpack_bits(words, bits, m, method).T`.
+    The code axis LEADS the output — the token-minor layout the qattn
+    kernels dequant in. The word block is transposed once; code i is then
+    its word row `i*bits // 32` shifted right by `i*bits % 32`, OR-ed with
+    the next word row when the code straddles a word boundary. Every slice
+    and shift is static, so the unpack is whole-row integer ops plus one
+    row concatenate: no gather, no reshape of the lane axis and no
+    unsigned reduction, which is what the TPU kernel compiler accepts at
+    every width. Bitwise identical to `unpack_bits(words, bits, m).T`.
     """
     if words.ndim != 2:
         raise ValueError(f"unpack_bits_T needs 2-D words, got {words.shape}")
     n_words = packed_words(m, bits)
     if words.shape[-1] != n_words:
         raise ValueError(f"expected {n_words} words, got {words.shape[-1]}")
-    if method != "gather":
-        return unpack_bits(words, bits, m, method=method).T
-    w = words.astype(jnp.uint32).T  # (n_words, bt)
-    # one spare row keeps lo+1 in range; its value never lands in a code
-    # (the straddle where() masks it off at sh + bits <= 32)
-    wext = jnp.concatenate([w, w[-1:]], axis=0)
-    pos = jax.lax.broadcasted_iota(jnp.uint32, (m, 1), 0) * jnp.uint32(bits)
-    lo = (pos // 32).astype(jnp.int32)[:, 0]
-    sh = pos % 32  # (m, 1), broadcasts down the token columns
-    out = jnp.take(wext, lo, axis=0) >> sh
-    if 32 % bits:  # codes can straddle a word boundary
-        hi = jnp.take(wext, lo + 1, axis=0)
-        out = out | jnp.where(sh + jnp.uint32(bits) > 32,
-                              hi << ((jnp.uint32(32) - sh) % 32),
-                              jnp.uint32(0))
-    return (out & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
+    w = jax.lax.bitcast_convert_type(words, jnp.int32).T  # (n_words, bt)
+    rows = []
+    for i in range(m):
+        lo, sh = divmod(i * bits, 32)
+        row = jax.lax.shift_right_logical(w[lo:lo + 1], jnp.int32(sh))
+        if sh + bits > 32:  # straddles into the next word
+            row = row | jax.lax.shift_left(w[lo + 1:lo + 2],
+                                           jnp.int32(32 - sh))
+        rows.append(row & jnp.int32((1 << bits) - 1))
+    return jnp.concatenate(rows, axis=0)
 
 
 def pack_nibbles(codes: jax.Array) -> jax.Array:
@@ -193,9 +119,9 @@ def unpack_nibbles(bytes_arr: jax.Array, m: int) -> jax.Array:
     if bytes_arr.shape[-1] * 2 != m:
         raise ValueError(
             f"expected {m // 2} bytes for m={m}, got {bytes_arr.shape[-1]}")
-    b = bytes_arr.astype(jnp.uint8)
-    return jnp.concatenate(
-        [b & jnp.uint8(0xF), b >> 4], axis=-1).astype(jnp.int32)
+    # widen first: the TPU kernel compiler has no 8-bit shifts
+    b = bytes_arr.astype(jnp.int32)
+    return jnp.concatenate([b & 0xF, b >> 4], axis=-1)
 
 
 def storage_bits_per_code(bits: int, mode: str) -> float:

@@ -76,12 +76,12 @@ def param_shardings(specs, mesh: Mesh, rules: ShardingRules,
 def paged_pool_pspec() -> P:
     """PartitionSpec for paged-pool QuantizedKV leaves.
 
-    Every pool leaf is rank 5 — (L, num_pages, page_size, n_kv, X) where X
+    Every pool leaf is rank 5 — (L, num_pages, n_kv, page_size, X) where X
     is the packed trailing dim (index words / norm codes / range scalars) —
     so one spec covers the whole tree: shard the kv-head axis over "model",
-    replicate everything else. The trailing dim is implicitly replicated
+    replicate everything else. The trailing dims are implicitly replicated
     (a PartitionSpec is a prefix)."""
-    return P(None, None, None, "model")
+    return P(None, None, "model")
 
 
 def kv_shard_count(cfg, mesh: Mesh) -> int:

@@ -1,30 +1,21 @@
-"""Measured autotuner for the quantized-attention kernel knobs.
+"""Measured autotuner for the quantized-attention kernel's token block.
 
-`default_block_t` derives the token-block size from a VMEM budget and
-`default_unpack` picks the bitstream unpack scheme per platform — both
-are *model-based* defaults, and PR 1-5 showed how far a model can drift
-from the clock (the CPU bitpack-slower-than-uint8 anomaly was exactly a
-plausible default losing to a measured alternative). This module closes
-the loop: it times the real kernel over a candidate grid of
-(block_t, unpack) pairs on the caller's exact geometry and caches the
-winner in a JSON file keyed by (geometry, backend, platform), so the
-measurement is paid once per machine, not per process.
+`default_block_t` derives the token-block size from a VMEM budget — a
+*model-based* default, and a model can drift from the clock. This module
+closes the loop: it times the real kernel over candidate `block_t` values
+on the caller's exact geometry and caches the winner in a JSON file keyed
+by (geometry, backend, platform), so the measurement is paid once per
+machine, not per process.
 
-Two knobs, one measurement:
+`block_t` is the contiguous kernel's token block, which runs the paged
+kernel over `block_t`-token pages — so the best page-sized candidate is
+also reported as `page_size` for `SchedulerConfig`.
 
-  block_t   the contiguous kernel's token-block (grid-step tile). Also a
-            direct proxy for the *paged* kernel's `page_size` — a paged
-            grid step runs the identical dequant + dot over one page, so
-            the best contiguous block_t among page-sized candidates is
-            reported as `page_size` for `SchedulerConfig`.
-  unpack    bitstream unpack scheme (`packing.UNPACK_METHODS`) — bitwise
-            identical outputs, wildly different lowering (minor-axis
-            gathers vs whole-row copies vs bitplane shifts).
-
-All candidates produce bitwise-identical attention outputs (pinned by
-tests), so the tuner is pure perf policy: `tuned_backend` applies a
-cached entry to a `QuantPallasBackend` without re-measuring, and
-`tools/autotune.py` is the CLI for measuring / printing the cache.
+All candidates produce the same attention outputs up to the online
+softmax's block order, so the tuner is pure perf policy: `tuned_backend`
+applies a cached entry to a `QuantPallasBackend` without re-measuring, and
+`tools/autotune.py` is the CLI for measuring / printing the cache. The
+serving path never reads this cache.
 """
 from __future__ import annotations
 
@@ -40,7 +31,6 @@ import numpy as np
 
 from repro.cache import kvcache
 from repro.configs.base import ModelConfig
-from repro.core import packing
 from repro.core.quantizer import KVQuantizer
 from repro.kernels.qattn import ops as qattn_ops
 
@@ -101,8 +91,7 @@ def _filled_cache(cfg: ModelConfig, qz: KVQuantizer, t: int, rng):
 
 
 def measure_attend(cfg: ModelConfig, qz: KVQuantizer, *, t: int,
-                   block_t: int, unpack: str, reps: int,
-                   interpret: bool, rng) -> float:
+                   block_t: int, reps: int, interpret: bool, rng) -> float:
     """Steady-state milliseconds per contiguous-kernel attend call at the
     given knob setting (compile excluded: one warmup call, then the
     median of `reps` timed calls)."""
@@ -118,7 +107,7 @@ def measure_attend(cfg: ModelConfig, qz: KVQuantizer, *, t: int,
     def fn(q, lk, lv, lengths):
         return qattn_ops.attend_quant_cache_op(
             q, lk, lv, nk0, nv0, lengths, cfg, qz,
-            interpret=interpret, block_t=block_t, unpack=unpack)
+            interpret=interpret, block_t=block_t)
 
     fn(q, layer_k, layer_v, cache.lengths).block_until_ready()
     times = []
@@ -129,44 +118,35 @@ def measure_attend(cfg: ModelConfig, qz: KVQuantizer, *, t: int,
     return float(np.median(times)) * 1e3
 
 
-def autotune(cfg: ModelConfig, qz: KVQuantizer, *, t: int = 1024,
-             reps: int = 3, block_ts=None, unpacks=None,
-             interpret: bool | None = None, cache_path: Path | None = None,
-             refresh: bool = False, seed: int = 0) -> dict:
-    """Measure the candidate grid and cache the winner.
+def autotune(cfg: ModelConfig, qz: KVQuantizer, *, interpret: bool,
+             t: int = 1024, reps: int = 3, block_ts=None,
+             cache_path: Path | None = None, refresh: bool = False,
+             seed: int = 0) -> dict:
+    """Measure the candidate block sizes and cache the winner.
 
-    Returns the cache entry: {block_t, unpack, page_size, attend_ms, t,
-    measured: {"bt=..,unpack=..": ms}}. A cached entry for the same key
-    is returned as-is unless `refresh`.
+    Returns the cache entry: {block_t, page_size, attend_ms, t,
+    measured: {"bt=..": ms}}. A cached entry for the same key is returned
+    as-is unless `refresh`.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     key = cache_key(cfg, qz)
     entries = load_cache(cache_path)
     if not refresh and key in entries:
         return entries[key]
     block_ts = tuple(b for b in (block_ts or DEFAULT_BLOCK_TS) if b <= t)
-    unpacks = tuple(unpacks or packing.UNPACK_METHODS)
     rng = np.random.default_rng(seed)
-    measured: dict[str, float] = {}
-    best = None
-    for bt in block_ts:
-        for up in unpacks:
-            ms = measure_attend(cfg, qz, t=t, block_t=bt, unpack=up,
-                                reps=reps, interpret=interpret, rng=rng)
-            measured[f"bt={bt},unpack={up}"] = ms
-            if best is None or ms < best[2]:
-                best = (bt, up, ms)
-    bt_best, up_best, ms_best = best
-    # page_size proposal: best block among page-sized candidates with the
-    # winning unpack (a paged grid step is the same tile of work)
-    page_cands = [b for b in DEFAULT_PAGE_CANDIDATES if b <= t
-                  and f"bt={b},unpack={up_best}" in measured]
-    page_size = (min(page_cands,
-                     key=lambda b: measured[f"bt={b},unpack={up_best}"])
+    measured = {f"bt={bt}": measure_attend(cfg, qz, t=t, block_t=bt,
+                                           reps=reps, interpret=interpret,
+                                           rng=rng)
+                for bt in block_ts}
+    bt_best = min(block_ts, key=lambda b: measured[f"bt={b}"])
+    ms_best = measured[f"bt={bt_best}"]
+    # page_size proposal: best block among page-sized candidates (a paged
+    # grid step is the same tile of work)
+    page_cands = [b for b in DEFAULT_PAGE_CANDIDATES if f"bt={b}" in measured]
+    page_size = (min(page_cands, key=lambda b: measured[f"bt={b}"])
                  if page_cands else bt_best)
     entry = {
-        "block_t": bt_best, "unpack": up_best, "page_size": page_size,
+        "block_t": bt_best, "page_size": page_size,
         "attend_ms": ms_best, "t": t, "reps": reps,
         "interpret": interpret, "measured": measured,
     }
@@ -182,12 +162,11 @@ def best(cfg: ModelConfig, qz: KVQuantizer,
 
 
 def tuned_backend(backend, cache_path: Path | None = None):
-    """Apply a cached tuning entry to a QuantPallasBackend (block_t +
-    unpack), or return the backend unchanged when nothing is cached.
+    """Apply a cached tuning entry to a QuantPallasBackend (block_t),
+    or return the backend unchanged when nothing is cached.
     Never measures — the cache is populated by `autotune` /
     `tools/autotune.py --refresh`."""
     entry = best(backend.cfg, backend.quantizer, cache_path)
     if entry is None:
         return backend
-    return dataclasses.replace(backend, block_t=int(entry["block_t"]),
-                               unpack=str(entry["unpack"]))
+    return dataclasses.replace(backend, block_t=int(entry["block_t"]))

@@ -33,9 +33,8 @@ def attend_quant_cache_op(
     cfg: ModelConfig,
     qz: KVQuantizer,
     *,
-    interpret: bool = True,
+    interpret: bool,
     block_t: int | None = None,
-    unpack: str | None = None,
 ) -> jax.Array:
     b, _, nq, h = q.shape
     nkv, g = cfg.num_kv_heads, cfg.q_per_kv
@@ -76,8 +75,6 @@ def attend_quant_cache_op(
         v_nq_packed=qz.config.norm_packed(vc),
         block_t=block_t,
         interpret=interpret,
-        unpack=unpack,
-        n_bins_cap=1 << qz.config.index_width,
     )
     out = qz.unrotate_output(out_y)  # one inverse transform per query
     return out.reshape(b, 1, nq, h)
@@ -85,7 +82,7 @@ def attend_quant_cache_op(
 
 def paged_attend_quant_cache_op(
     q: jax.Array,  # (B, 1, nq, h) RoPE'd query, logical head dim
-    layer_kq: QuantizedKV,  # (P, page_size, n_kv, ...) one layer's pool
+    layer_kq: QuantizedKV,  # (P, n_kv, page_size, ...) one layer's pool
     layer_vq: QuantizedKV,
     n_bins_k,  # int or traced i32 scalar
     n_bins_v,
@@ -94,8 +91,7 @@ def paged_attend_quant_cache_op(
     cfg: ModelConfig,
     qz: KVQuantizer,
     *,
-    interpret: bool = True,
-    unpack: str | None = None,
+    interpret: bool,
 ) -> jax.Array:
     """Paged mirror of `attend_quant_cache_op`: the kernel resolves each
     grid step's K/V block through the scalar-prefetched page table instead
@@ -128,8 +124,6 @@ def paged_attend_quant_cache_op(
         v_bits=vc.bits, v_log=vc.log_space,
         v_nq_packed=qz.config.norm_packed(vc),
         interpret=interpret,
-        unpack=unpack,
-        n_bins_cap=1 << qz.config.index_width,
     )
     out = qz.unrotate_output(out_y)
     return out.reshape(b, 1, nq, h)
@@ -137,7 +131,7 @@ def paged_attend_quant_cache_op(
 
 def paged_attend_multi_quant_cache_op(
     q: jax.Array,  # (B, q_len, nq, h) RoPE'd queries, logical head dim
-    layer_kq: QuantizedKV,  # (P, page_size, n_kv, ...) one layer's pool
+    layer_kq: QuantizedKV,  # (P, n_kv, page_size, ...) one layer's pool
     layer_vq: QuantizedKV,
     n_bins_k,
     n_bins_v,
@@ -146,8 +140,7 @@ def paged_attend_multi_quant_cache_op(
     cfg: ModelConfig,
     qz: KVQuantizer,
     *,
-    interpret: bool = True,
-    unpack: str | None = None,
+    interpret: bool,
 ) -> jax.Array:
     """Fused speculative verify: q_len query rows per slot share ONE page
     walk (`qattn.paged_qattn_multi`); query row j applies its own causal
@@ -187,8 +180,6 @@ def paged_attend_multi_quant_cache_op(
         v_bits=vc.bits, v_log=vc.log_space,
         v_nq_packed=qz.config.norm_packed(vc),
         interpret=interpret,
-        unpack=unpack,
-        n_bins_cap=1 << qz.config.index_width,
     )
     out_y = out_y.reshape(b, nkv, q_len, g, dp).transpose(0, 2, 1, 3, 4)
     out = qz.unrotate_output(out_y.reshape(b * q_len, nkv, g, dp))

@@ -5,16 +5,15 @@ Why this is the perf-critical kernel: long-context decode is bound by
 reading the KV cache once per token. Storing angles+norms at ~6.6 bits/elem
 cuts those HBM bytes ~2.4x vs bf16 — but only if the dequant happens INSIDE
 the attention kernel; a separate dequant pass would write the f32 cache back
-to HBM and forfeit the entire win (exactly what the pure-XLA path does,
-measured in EXPERIMENTS.md §Perf).
+to HBM and forfeit the entire win (exactly what the pure-XLA path does).
 
 Bit-packed streams: the cache's default representation is a little-endian
 uint32 word stream (~3.5 angle bits/elem at K128) plus two-per-byte norm
-nibbles. The kernel reads those words directly and unpacks them in VMEM via
-the vectorized shift/or scheme of `core/packing.py` (plain VPU integer ops),
-so the HBM stream per step is the packed payload itself — the paper's bit
-budget is what physically moves. The legacy "uint8" container path is kept
-for comparison benchmarks (`idx_bits=None`).
+nibbles. The kernel reads those words directly and unpacks them in VMEM
+with static row slices and shifts (`packing.unpack_bits_T`), so the HBM
+stream per step is the packed payload itself — the paper's bit budget is
+what physically moves. The legacy "uint8" container path is kept for
+comparison benchmarks (`idx_bits=None`).
 
 Beyond-paper fusion: scores are taken directly against Hadamard-domain keys
 (q.k == (HDq).(HDk)) and the weighted value sum is accumulated in the
@@ -22,24 +21,29 @@ Hadamard domain — the inverse FWHT runs ONCE per query on the output instead
 of once per cached token (O(T d log d) -> O(d log d) reconstruction FLOPs).
 
 Layout note: inside the kernel, y-vectors live in split-half ("[even|odd]")
-order — pair p contributes columns p and p+pairs instead of 2p and 2p+1.
-Dot products are permutation-invariant, so the wrapper permutes the (tiny)
-query once per call and un-permutes the (tiny) output once per call; the
-hot loop then builds each (block_t, d_pad) tile with one concatenate
-instead of a strided stack/reshape interleave per step.
+order — pair p contributes rows p and p+pairs instead of 2p and 2p+1, and
+tokens run along the minor (lane) axis. Dot products are
+permutation-invariant, so the wrapper permutes the (tiny) query once per
+call and un-permutes the (tiny) output once per call; the hot loop then
+builds each (d_pad, page_size) tile with one concatenate.
 
-Grid: (B, n_kv, T/block_t), accumulating online-softmax state in VMEM
-scratch across the sequential T dimension. `block_t` defaults to a
-VMEM-budget-derived value (see `default_block_t`) instead of a hardcoded
-constant: the two f32 dequant tiles plus the packed code streams for a
-block must fit the budget with double-buffering headroom.
+Pool layout: every pool leaf is (P, n_kv, page_size, X). A grid step's
+block is one page of one kv head, a (page_size, X) tile whose two minor
+dims are whole array dims — the form the TPU lowering accepts for any X
+(index words, norm codes, range scalars). Grid: (B, n_kv, max_pages),
+accumulating online-softmax state in VMEM scratch across the sequential
+page dimension; the page id of each step comes from the scalar-prefetched
+page table in the BlockSpec index_map.
 
-Serving integration: `length` is a per-sequence (B,) vector (ragged batches)
-and the codebook sizes `n_bins_k`/`n_bins_v` are *runtime* scalars fed
-through a (1, 2) scalar block — they ride along the per-layer MixedKV scan
+Serving integration: `lengths` is a per-sequence (B,) vector (ragged
+batches) and the codebook sizes `n_bins_k`/`n_bins_v` are *runtime*
+scalars prefetched into SMEM — they ride along the per-layer MixedKV scan
 as traced values, so one compiled kernel serves every layer of a mixed
 schedule. Only the storage geometry (index bits, norm format) is
 compile-time static.
+
+`interpret` has no default: callers choose the Pallas interpreter (CPU
+tests) or the compiled kernel (TPU) explicitly.
 """
 from __future__ import annotations
 
@@ -49,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
 
@@ -59,6 +64,13 @@ NEG_INF = -1e30
 # streams), out of ~16 MiB/core; the rest is left for q/output blocks,
 # softmax scratch and the pipeline's double buffering.
 DEFAULT_VMEM_BUDGET = 4 * 1024 * 1024
+
+# the kernel's dots run in full f32 on the MXU (several bf16 passes), the
+# precision the dequantized tiles are computed in; the CPU ignores it
+_F32 = jax.lax.Precision.HIGHEST
+
+_STATIC = ("idx_bits", "k_bits", "k_log", "k_nq_packed", "v_bits", "v_log",
+           "v_nq_packed", "interpret")
 
 
 def default_block_t(dp: int, row_stream_bytes: int,
@@ -76,42 +88,27 @@ def default_block_t(dp: int, row_stream_bytes: int,
     return max(128, min(2048, (bt // 128) * 128))
 
 
-# bin counts above this get the elementwise cos/sin path: a trig table
-# would stop paying for itself and bloat VMEM
-DEQUANT_TABLE_CAP = 512
-
-
 def _dequant_block(idx_raw, nq_raw, rmin, rmax, *, n_bins, bits, log,
-                   pairs, idx_bits, nq_packed, unpack="bitplane",
-                   n_bins_cap=None):
+                   pairs, idx_bits, nq_packed):
     """Stored codes -> (2*pairs, bt) y-domain block, f32, TRANSPOSED
     split-half layout: row p is pair p's cos line, row p+pairs its sin
     line, tokens along the minor axis.
 
-    Token-minor tiles are the layout where the packed-stream unpack is
-    whole-row copies instead of minor-axis gathers (`unpack_bits_T`) and
-    the split-half concatenate is two contiguous block copies — the fixes
-    for the CPU bitpack-slower-than-uint8 anomaly. Every value is produced
-    by the same elementwise arithmetic as the natural-layout path, so the
-    result is bitwise `transpose` of it.
-
     idx_raw: (bt, words) uint32 bitstream (idx_bits static) or (bt, pairs)
-    integer container codes (idx_bits None). nq_raw: (bt, pairs//2) nibble
+    int32 container codes (idx_bits None). nq_raw: (bt, pairs//2) nibble
     bytes, (bt, pairs) uint8 codes, or (bt, pairs) f32 norms. n_bins may be
-    a traced i32 scalar (read off the bins ref). `unpack` picks the
-    bitstream unpack scheme (`packing.UNPACK_METHODS`; bitwise identical,
-    perf-only — see `default_unpack`). `n_bins_cap` is the static bound on
-    code values (2^index_width); when given and small, cos/sin run once per
-    *bin* and codes gather from the table, not once per element.
+    a traced i32 scalar (read off the bins ref).
     """
     if idx_bits is None:
         idx = idx_raw.astype(jnp.int32).T  # (pairs, bt)
     else:
-        idx = packing.unpack_bits_T(idx_raw, idx_bits, pairs, method=unpack)
+        idx = packing.unpack_bits_T(idx_raw, idx_bits, pairs)
     if bits is None:
         r = nq_raw.astype(jnp.float32).T
     else:
-        nq = packing.unpack_nibbles(nq_raw, pairs) if nq_packed else nq_raw
+        # widen to i32 before f32: the TPU lowering has no u8 -> f32 cast
+        nq = (packing.unpack_nibbles(nq_raw, pairs) if nq_packed
+              else nq_raw.astype(jnp.int32))
         levels = float(2**bits - 1)
         scale = jnp.maximum(rmax - rmin, 1e-12)
         v = nq.astype(jnp.float32) / levels * scale + rmin
@@ -119,231 +116,45 @@ def _dequant_block(idx_raw, nq_raw, rmin, rmax, *, n_bins, bits, log,
     # bin-center angle folded into one multiply-add:
     # (k + 0.5) * 2pi/n == k * s + 0.5 * s with s = 2pi/n
     ang = TWO_PI / jnp.asarray(n_bins, jnp.float32)
-    if n_bins_cap is not None and n_bins_cap <= DEQUANT_TABLE_CAP:
-        # codes take at most n_bins_cap distinct values: evaluate the
-        # bin-center trig once per bin (iota-built, so Pallas-safe) and
-        # gather — the table inputs j*ang + 0.5*ang are the exact f32
-        # values the elementwise path feeds cos/sin, so outputs are
-        # bitwise identical.
-        th = jax.lax.broadcasted_iota(
-            jnp.float32, (n_bins_cap,), 0) * ang + 0.5 * ang
-        even = r * jnp.take(jnp.cos(th), idx)
-        odd = r * jnp.take(jnp.sin(th), idx)
-    else:
-        theta = idx.astype(jnp.float32) * ang + 0.5 * ang
-        even = r * jnp.cos(theta)
-        odd = r * jnp.sin(theta)
-    return jnp.concatenate([even, odd], axis=0)
+    theta = idx.astype(jnp.float32) * ang + 0.5 * ang
+    return jnp.concatenate([r * jnp.cos(theta), r * jnp.sin(theta)], axis=0)
 
 
-def default_unpack(interpret: bool) -> str:
-    """Platform default for the bitstream unpack scheme.
+def _page_tiles(refs, bins_ref, col_ok, geom):
+    """Dequantize this grid step's K and V pages -> two (dp, ps) tiles,
+    with columns past the frontier zeroed (unwritten pool bytes may decode
+    to NaN/Inf, and 0 * NaN would poison the dots)."""
+    kidx, knq, krmin, krmax, vidx, vnq, vrmin, vrmax = refs
+    common = dict(pairs=geom["pairs"], idx_bits=geom["idx_bits"])
+    y_k = _dequant_block(
+        kidx[...], knq[...], krmin[...], krmax[...], n_bins=bins_ref[0],
+        bits=geom["k_bits"], log=geom["k_log"],
+        nq_packed=geom["k_nq_packed"], **common)
+    y_v = _dequant_block(
+        vidx[...], vnq[...], vrmin[...], vrmax[...], n_bins=bins_ref[1],
+        bits=geom["v_bits"], log=geom["v_log"],
+        nq_packed=geom["v_nq_packed"], **common)
+    return jnp.where(col_ok, y_k, 0.0), jnp.where(col_ok, y_v, 0.0)
 
-    Dequant runs in token-minor (transposed) tiles, where the gather
-    scheme's takes are whole-row copies along the major axis — memcpys on
-    CPU, where minor-axis gathers would lower to scalar loops (the source
-    of the bitpack-slower-than-uint8 anomaly). The bitplane scheme is the
-    known-good TPU VPU vectorization (`unpack_bits_T` runs it in natural
-    layout and transposes, which the Mosaic relayout handles). The
-    autotuner (`kernels.qattn.autotune`) measures all schemes in-kernel
-    and can override either default via `QuantPallasBackend.unpack`.
+
+def paged_qattn_kernel(pt_ref, len_ref, bins_ref, q_ref, *refs,
+                       page_size: int, geom: dict):
+    """One (slot, kv-head, page) grid step of flash-decode.
+
+    The K/V block for grid step p is whatever physical page `pt[b, p]`
+    names — the gather happens in the BlockSpec index_map, so the DMA
+    engine streams exactly the pages the slot owns.
+
+    Per-page work bound: a page past this slot's frontier contributes
+    exactly nothing (masked scores are NEG_INF -> p == 0, m unchanged), so
+    its dequant + dots are skipped — each slot costs its own live page
+    count, which is what lets short requests ride alongside a long-context
+    slot without paying its width. Bit-for-bit identical to computing the
+    masked page.
     """
-    return "gather" if interpret else "bitplane"
-
-
-def qattn_kernel(
-    len_ref, bins_ref, q_ref, kidx_ref, knq_ref, krmin_ref, krmax_ref,
-    vidx_ref, vnq_ref, vrmin_ref, vrmax_ref, o_ref,
-    m_scr, l_scr, acc_scr, *,
-    block_t: int, pairs: int, idx_bits, k_bits, k_log, k_nq_packed,
-    v_bits, v_log, v_nq_packed, unpack: str = "bitplane",
-    n_bins_cap: int | None = None,
-):
-    t_step = pl.program_id(2)
-    n_steps = pl.num_programs(2)
-
-    @pl.when(t_step == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q = q_ref[0, 0]  # (g, dp) pre-rotated, pre-scaled, split-half layout
-    length = len_ref[0, 0]  # this batch row's valid-token count
-    n_bins_k = bins_ref[0, 0]
-    n_bins_v = bins_ref[0, 1]
-
-    # Blocks entirely past this row's frontier contribute exactly nothing
-    # (masked scores are NEG_INF -> p == 0, m unchanged), so skip their
-    # dequant + dots outright: ragged batches then cost each row ITS OWN
-    # context, not the batch maximum. Output is bit-for-bit identical with
-    # or without the skip. (The DMA for the block still runs — this saves
-    # compute, not bandwidth.)
-    @pl.when(t_step * block_t < length)
-    def _work():
-        # y blocks are TRANSPOSED (dp, bt) — tokens along the minor axis
-        # (see _dequant_block), so validity is a column mask here
-        col_pos = t_step * block_t + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_t), 1)
-        col_ok = col_pos < length  # also kills OOB-padding garbage columns
-
-        y_k = _dequant_block(
-            kidx_ref[0, :, 0], knq_ref[0, :, 0], krmin_ref[0, :, 0],
-            krmax_ref[0, :, 0], n_bins=n_bins_k, bits=k_bits, log=k_log,
-            pairs=pairs, idx_bits=idx_bits, nq_packed=k_nq_packed,
-            unpack=unpack, n_bins_cap=n_bins_cap)
-        y_k = jnp.where(col_ok, y_k, 0.0)
-        s = jax.lax.dot_general(
-            q.astype(jnp.float32), y_k,
-            (((1,), (0,)), ((), ())))  # (g, bt)
-        s = jnp.where(col_ok, s, NEG_INF)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[...] = m_new
-
-        y_v = _dequant_block(
-            vidx_ref[0, :, 0], vnq_ref[0, :, 0], vrmin_ref[0, :, 0],
-            vrmax_ref[0, :, 0], n_bins=n_bins_v, bits=v_bits, log=v_log,
-            pairs=pairs, idx_bits=idx_bits, nq_packed=v_nq_packed,
-            unpack=unpack, n_bins_cap=n_bins_cap)
-        y_v = jnp.where(col_ok, y_v, 0.0)  # 0 * garbage NaN would poison p@y_v
-        pv = jax.lax.dot_general(p, y_v, (((1,), (1,)), ((), ())))  # (g, dp)
-        acc_scr[...] = acc_scr[...] * corr + pv
-
-    @pl.when(t_step == n_steps - 1)
-    def _fin():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
-
-
-def _to_split_half(x: jax.Array) -> jax.Array:
-    """(..., dp) interleaved (even0, odd0, even1, ...) -> [evens | odds]."""
-    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-
-
-def _from_split_half(x: jax.Array) -> jax.Array:
-    """Inverse of _to_split_half."""
-    dp = x.shape[-1]
-    pairs = dp // 2
-    return jnp.stack([x[..., :pairs], x[..., pairs:]],
-                     axis=-1).reshape(*x.shape[:-1], dp)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("idx_bits", "k_bits", "k_log", "k_nq_packed", "v_bits",
-                     "v_log", "v_nq_packed", "block_t", "interpret",
-                     "unpack", "n_bins_cap"),
-)
-def qattn(
-    q_rot: jax.Array,  # (B, nkv, G, Dp) f32, pre-scaled
-    k_idx: jax.Array,  # (B, T, nkv, words) uint32 or (B, T, nkv, pairs) int
-    k_nq: jax.Array,
-    k_rmin: jax.Array,  # (B, T, nkv, 1)
-    k_rmax: jax.Array,
-    v_idx: jax.Array,
-    v_nq: jax.Array,
-    v_rmin: jax.Array,
-    v_rmax: jax.Array,
-    length: jax.Array,  # (B,) per-sequence valid counts, or () broadcast
-    *,
-    n_bins_k,  # int or traced i32 scalar (per-layer MixedKV scan value)
-    n_bins_v,
-    idx_bits=None,  # static: packed index width; None -> container codes
-    k_bits=None,
-    k_log: bool = False,
-    k_nq_packed: bool = False,
-    v_bits=None,
-    v_log: bool = False,
-    v_nq_packed: bool = False,
-    block_t: int | None = None,
-    interpret: bool = True,
-    unpack: str | None = None,  # None -> default_unpack(interpret)
-    n_bins_cap: int | None = None,  # static code-value bound (2^index_width)
-) -> jax.Array:
-    b, nkv, g, dp = q_rot.shape
-    t = k_idx.shape[1]
-    pairs = dp // 2
-    if unpack is None:
-        unpack = default_unpack(interpret)
-    if block_t is None:
-        stream = sum(
-            a.shape[-1] * a.dtype.itemsize
-            for a in (k_idx, k_nq, v_idx, v_nq)) + 4 * 4  # + rmin/rmax pairs
-        block_t = default_block_t(dp, stream)
-    block_t = min(block_t, t)
-    grid = (b, nkv, pl.cdiv(t, block_t))
-
-    from repro.cache.kvcache import per_seq_lengths
-
-    lengths = per_seq_lengths(length, b).reshape(b, 1)
-    bins = jnp.stack([
-        jnp.asarray(n_bins_k, jnp.int32).reshape(()),
-        jnp.asarray(n_bins_v, jnp.int32).reshape(()),
-    ]).reshape(1, 2)
-    q_perm = _to_split_half(q_rot)
-
-    def kv_spec(arr):
-        last = arr.shape[-1]
-        return pl.BlockSpec(
-            (1, block_t, 1, last), lambda bi, ni, ti: (bi, ti, ni, 0))
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    out_perm = pl.pallas_call(
-        functools.partial(
-            qattn_kernel, block_t=block_t, pairs=pairs, idx_bits=idx_bits,
-            k_bits=k_bits, k_log=k_log, k_nq_packed=k_nq_packed,
-            v_bits=v_bits, v_log=v_log, v_nq_packed=v_nq_packed,
-            unpack=unpack, n_bins_cap=n_bins_cap),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, ni, ti: (bi, 0)),  # lengths (B,1)
-            pl.BlockSpec((1, 2), lambda bi, ni, ti: (0, 0)),  # [n_k, n_v]
-            pl.BlockSpec((1, 1, g, dp), lambda bi, ni, ti: (bi, ni, 0, 0)),
-            kv_spec(k_idx), kv_spec(k_nq), kv_spec(k_rmin), kv_spec(k_rmax),
-            kv_spec(v_idx), kv_spec(v_nq), kv_spec(v_rmin), kv_spec(v_rmax),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dp),
-                               lambda bi, ni, ti: (bi, ni, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g, dp), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dp), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lengths, bins, q_perm, k_idx, k_nq, k_rmin,
-      k_rmax, v_idx, v_nq, v_rmin, v_rmax)
-    return _from_split_half(out_perm)
-
-
-# ============================================================ paged =========
-def paged_qattn_kernel(
-    pt_ref, len_ref, bins_ref, q_ref, kidx_ref, knq_ref, krmin_ref,
-    krmax_ref, vidx_ref, vnq_ref, vrmin_ref, vrmax_ref, o_ref,
-    m_scr, l_scr, acc_scr, *,
-    page_size: int, pairs: int, idx_bits, k_bits, k_log, k_nq_packed,
-    v_bits, v_log, v_nq_packed, unpack: str = "bitplane",
-    n_bins_cap: int | None = None,
-):
-    """qattn over a paged pool: identical online-softmax body, but the K/V
-    block for grid step p is whatever physical page `pt[b, p]` names — the
-    gather happens in the BlockSpec index_map (scalar-prefetched page table),
-    so the DMA engine streams exactly the pages the slot owns.
-
-    With page_size == block_t and pages filled in logical order, the
-    accumulation sequence is bit-for-bit the contiguous kernel's: extra
-    fully-masked trailing pages contribute exp(-inf - m) == 0 to l/acc and
-    leave m unchanged (pinned by the paged-vs-contiguous parity tests).
-    """
+    pool_refs, (o_ref, m_scr, l_scr, acc_scr) = refs[:8], refs[8:]
     b_i = pl.program_id(0)
     p_step = pl.program_id(2)
-    n_steps = pl.num_programs(2)
 
     @pl.when(p_step == 0)
     def _init():
@@ -351,32 +162,18 @@ def paged_qattn_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0]  # (g, dp) pre-rotated, pre-scaled, split-half layout
     length = len_ref[b_i]
-    n_bins_k = bins_ref[0]
-    n_bins_v = bins_ref[1]
 
-    # Per-page work bound: a page past this slot's frontier contributes
-    # exactly nothing, so skip its dequant + dots — each slot costs its own
-    # live page count (derived per-page valid counts), which is what lets
-    # short requests ride alongside a long-context slot without paying its
-    # width. Bit-for-bit identical to computing the masked page.
     @pl.when(p_step * page_size < length)
     def _work():
-        # y blocks are TRANSPOSED (dp, ps) — tokens along the minor axis
+        q = q_ref[...]  # (g, dp) pre-rotated, pre-scaled, split-half
         col_pos = p_step * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
-        col_ok = col_pos < length  # per-page valid count, as a column mask
-
-        y_k = _dequant_block(
-            kidx_ref[0, :, 0], knq_ref[0, :, 0], krmin_ref[0, :, 0],
-            krmax_ref[0, :, 0], n_bins=n_bins_k, bits=k_bits, log=k_log,
-            pairs=pairs, idx_bits=idx_bits, nq_packed=k_nq_packed,
-            unpack=unpack, n_bins_cap=n_bins_cap)
-        y_k = jnp.where(col_ok, y_k, 0.0)
+        col_ok = col_pos < length
+        y_k, y_v = _page_tiles(pool_refs, bins_ref, col_ok, geom)
         s = jax.lax.dot_general(
-            q.astype(jnp.float32), y_k,
-            (((1,), (0,)), ((), ())))  # (g, ps)
+            q.astype(jnp.float32), y_k, (((1,), (0,)), ((), ())),
+            precision=_F32)  # (g, ps)
         s = jnp.where(col_ok, s, NEG_INF)
 
         m_prev = m_scr[...]
@@ -385,151 +182,45 @@ def paged_qattn_kernel(
         corr = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_scr[...] = m_new
-
-        y_v = _dequant_block(
-            vidx_ref[0, :, 0], vnq_ref[0, :, 0], vrmin_ref[0, :, 0],
-            vrmax_ref[0, :, 0], n_bins=n_bins_v, bits=v_bits, log=v_log,
-            pairs=pairs, idx_bits=idx_bits, nq_packed=v_nq_packed,
-            unpack=unpack, n_bins_cap=n_bins_cap)
-        y_v = jnp.where(col_ok, y_v, 0.0)
-        pv = jax.lax.dot_general(p, y_v, (((1,), (1,)), ((), ())))  # (g, dp)
+        pv = jax.lax.dot_general(p, y_v, (((1,), (1,)), ((), ())),
+                                 precision=_F32)  # (g, dp)
         acc_scr[...] = acc_scr[...] * corr + pv
 
-    @pl.when(p_step == n_steps - 1)
+    @pl.when(p_step == pl.num_programs(2) - 1)
     def _fin():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...]
+                      / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("idx_bits", "k_bits", "k_log", "k_nq_packed", "v_bits",
-                     "v_log", "v_nq_packed", "interpret", "unpack", "n_bins_cap"),
-)
-def paged_qattn(
-    q_rot: jax.Array,  # (B, nkv, G, Dp) f32, pre-scaled
-    k_idx: jax.Array,  # (P, ps, nkv, words) uint32 — ONE layer's pool
-    k_nq: jax.Array,
-    k_rmin: jax.Array,  # (P, ps, nkv, 1)
-    k_rmax: jax.Array,
-    v_idx: jax.Array,
-    v_nq: jax.Array,
-    v_rmin: jax.Array,
-    v_rmax: jax.Array,
-    page_table: jax.Array,  # (B, max_pages) int32 physical page ids
-    lengths: jax.Array,  # (B,) int32 valid tokens per slot
-    *,
-    n_bins_k,  # int or traced i32 scalar
-    n_bins_v,
-    idx_bits=None,
-    k_bits=None,
-    k_log: bool = False,
-    k_nq_packed: bool = False,
-    v_bits=None,
-    v_log: bool = False,
-    v_nq_packed: bool = False,
-    interpret: bool = True,
-    unpack: str | None = None,  # None -> default_unpack(interpret)
-    n_bins_cap: int | None = None,  # static code-value bound (2^index_width)
-) -> jax.Array:
-    """Flash-decode over the paged pool. The block size IS the page size —
-    one grid step streams one physical page per (slot, kv-head)."""
-    b, nkv, g, dp = q_rot.shape
-    page_size = k_idx.shape[1]
-    mp = page_table.shape[1]
-    pairs = dp // 2
-    if unpack is None:
-        unpack = default_unpack(interpret)
-    grid = (b, nkv, mp)
-
-    bins = jnp.stack([
-        jnp.asarray(n_bins_k, jnp.int32).reshape(()),
-        jnp.asarray(n_bins_v, jnp.int32).reshape(()),
-    ])
-    q_perm = _to_split_half(q_rot)
-
-    def pool_spec(arr):
-        last = arr.shape[-1]
-        return pl.BlockSpec(
-            (1, page_size, 1, last),
-            lambda bi, ni, pi, pt, lens, bins_: (pt[bi, pi], 0, ni, 0))
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # page_table, lengths, bins
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, g, dp),
-                         lambda bi, ni, pi, *_: (bi, ni, 0, 0)),
-            pool_spec(k_idx), pool_spec(k_nq),
-            pool_spec(k_rmin), pool_spec(k_rmax),
-            pool_spec(v_idx), pool_spec(v_nq),
-            pool_spec(v_rmin), pool_spec(v_rmax),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dp),
-                               lambda bi, ni, pi, *_: (bi, ni, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, dp), jnp.float32),
-        ],
-    )
-    out_perm = pl.pallas_call(
-        functools.partial(
-            paged_qattn_kernel, page_size=page_size, pairs=pairs,
-            idx_bits=idx_bits, k_bits=k_bits, k_log=k_log,
-            k_nq_packed=k_nq_packed, v_bits=v_bits, v_log=v_log,
-            v_nq_packed=v_nq_packed, unpack=unpack, n_bins_cap=n_bins_cap),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, g, dp), jnp.float32),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), bins,
-      q_perm, k_idx, k_nq, k_rmin, k_rmax, v_idx, v_nq, v_rmin, v_rmax)
-    return _from_split_half(out_perm)
-
-
-# ================================================= fused multi-query ========
-def paged_qattn_multi_kernel(
-    pt_ref, len_ref, bins_ref, q_ref, kidx_ref, knq_ref, krmin_ref,
-    krmax_ref, vidx_ref, vnq_ref, vrmin_ref, vrmax_ref, o_ref,
-    m_scr, l_scr, acc_scr, *,
-    page_size: int, pairs: int, q_len: int, g: int, idx_bits, k_bits,
-    k_log, k_nq_packed, v_bits, v_log, v_nq_packed,
-    unpack: str = "bitplane",
-    n_bins_cap: int | None = None,
-):
+def paged_qattn_multi_kernel(pt_ref, len_ref, bins_ref, q_ref, *refs,
+                             page_size: int, q_len: int, g: int, geom: dict):
     """Speculative-verify attention: all q_len query rows of a slot share
     ONE walk over its pages.
 
-    The expansion path (`verify_rows` + the single-query kernel) is exact
-    but walks every page q_len times — the verify dispatch then costs
-    q_len plain decode steps of kernel work and speculation's step savings
-    drown in it. Here the q block carries all q_len*g query rows for a
-    (slot, kv-head) pair and each page is dequantized ONCE; row r (query
-    position j = r // g) applies its own causal frontier
+    The q block carries all q_len*g query rows for a (slot, kv-head) pair
+    and each page is dequantized ONCE; row r (query position j = r // g)
+    applies its own causal frontier
 
         lengths[slot] + j + 1
 
     as a score mask. Masked scores are NEG_INF, so their softmax weight is
     exactly zero and each row's m/l/acc sequence is term-for-term the
     single-query kernel's at its own frontier — the fused walk is
-    bit-for-bit the expansion (pinned by tests/test_speculate.py /
-    tests/test_kernels.py parity).
+    bit-for-bit the `verify_rows` expansion (pinned by
+    tests/test_speculate.py).
 
     The dots stay (g, ·)-shaped — a static per-position loop over the
     shared dequantized tiles — rather than one (q_len*g, ·) GEMM: a gemm's
     k-dimension accumulation order can change with the output row count,
     which would break the bitwise-parity contract. The frontier masking
     and the running-max update ARE batched across all q_len*g rows (max
-    and compare are exact, row-count-independent ops), which trims the
-    per-page op count; exp and the scaled l/acc updates stay per-row-group
-    because XLA's codegen for them is shape-dependent at the ulp level
-    (measured: batching either changes output bits on CPU).
+    and compare are exact, row-count-independent ops); exp and the scaled
+    l/acc updates stay per-row-group because XLA's codegen for them is
+    shape-dependent at the ulp level.
     """
+    pool_refs, (o_ref, m_scr, l_scr, acc_scr) = refs[:8], refs[8:]
     b_i = pl.program_id(0)
     p_step = pl.program_id(2)
-    n_steps = pl.num_programs(2)
 
     @pl.when(p_step == 0)
     def _init():
@@ -537,42 +228,21 @@ def paged_qattn_multi_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0]  # (q_len*g, dp) pre-rotated/scaled, split-half layout
     length = len_ref[b_i]
-    n_bins_k = bins_ref[0]
-    n_bins_v = bins_ref[1]
 
-    # The furthest frontier is length + q_len: pages wholly past it
-    # contribute nothing to any row, so skip them (the ragged-batch work
-    # bound, shifted by the optimistic appends).
+    # the furthest frontier is length + q_len: pages wholly past it
+    # contribute nothing to any row
     @pl.when(p_step * page_size < length + q_len)
     def _work():
-        # y blocks are TRANSPOSED (dp, ps) — tokens along the minor axis
+        q = q_ref[...]  # (q_len*g, dp)
         col_pos = p_step * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
-        # beyond every row's frontier lies unwritten pool garbage — zero it
-        # so 0-weight scores can't poison the dots with NaN/Inf
-        col_ok = col_pos < length + q_len
-
-        y_k = _dequant_block(
-            kidx_ref[0, :, 0], knq_ref[0, :, 0], krmin_ref[0, :, 0],
-            krmax_ref[0, :, 0], n_bins=n_bins_k, bits=k_bits, log=k_log,
-            pairs=pairs, idx_bits=idx_bits, nq_packed=k_nq_packed,
-            unpack=unpack, n_bins_cap=n_bins_cap)
-        y_k = jnp.where(col_ok, y_k, 0.0)
-        y_v = _dequant_block(
-            vidx_ref[0, :, 0], vnq_ref[0, :, 0], vrmin_ref[0, :, 0],
-            vrmax_ref[0, :, 0], n_bins=n_bins_v, bits=v_bits, log=v_log,
-            pairs=pairs, idx_bits=idx_bits, nq_packed=v_nq_packed,
-            unpack=unpack, n_bins_cap=n_bins_cap)
-        y_v = jnp.where(col_ok, y_v, 0.0)
-
-        # per-position (g, ps) score dots — parity-pinned shapes — then
-        # one stacked softmax update over all q_len*g rows
+        y_k, y_v = _page_tiles(pool_refs, bins_ref,
+                               col_pos < length + q_len, geom)
         s = jnp.concatenate(
             [jax.lax.dot_general(
                 q[j * g:(j + 1) * g].astype(jnp.float32), y_k,
-                (((1,), (0,)), ((), ())))  # (g, ps)
+                (((1,), (0,)), ((), ())), precision=_F32)  # (g, ps)
              for j in range(q_len)], axis=0)  # (q_len*g, ps)
         # query position j's causal frontier: the committed tokens plus
         # the j+1 this dispatch appended (its own key included)
@@ -592,27 +262,123 @@ def paged_qattn_multi_kernel(
             corr = jnp.exp(m_prev[rows] - m_new[rows])
             l_new.append(l_prev[rows] * corr
                          + jnp.sum(p, axis=-1, keepdims=True))
-            pv = jax.lax.dot_general(p, y_v,
-                                     (((1,), (1,)), ((), ())))
+            pv = jax.lax.dot_general(p, y_v, (((1,), (1,)), ((), ())),
+                                     precision=_F32)
             acc_new.append(acc_prev[rows] * corr + pv)
         l_scr[...] = jnp.concatenate(l_new, axis=0)
         acc_scr[...] = jnp.concatenate(acc_new, axis=0)
 
-    @pl.when(p_step == n_steps - 1)
+    @pl.when(p_step == pl.num_programs(2) - 1)
     def _fin():
-        o_ref[0, 0] = (acc_scr[...]
-                       / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...]
+                      / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("q_len", "g", "idx_bits", "k_bits", "k_log",
-                     "k_nq_packed", "v_bits", "v_log", "v_nq_packed",
-                     "interpret", "unpack", "n_bins_cap"),
-)
+def _to_split_half(x: jax.Array) -> jax.Array:
+    """(..., dp) interleaved (even0, odd0, even1, ...) -> [evens | odds]."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def _from_split_half(x: jax.Array) -> jax.Array:
+    """Inverse of _to_split_half."""
+    dp = x.shape[-1]
+    pairs = dp // 2
+    return jnp.stack([x[..., :pairs], x[..., pairs:]],
+                     axis=-1).reshape(*x.shape[:-1], dp)
+
+
+def _paged_call(kernel, q_rot, pool, page_table, lengths, n_bins_k,
+                n_bins_v, *, interpret: bool):
+    """Run a paged kernel body over the pool: grid (B, n_kv, max_pages),
+    page table / lengths / bins scalar-prefetched into SMEM, one
+    (page_size, X) tile of every pool leaf per step. q_rot is
+    (B, n_kv, rows, dp) in interleaved order; returns the same shape, f32.
+    """
+    b, nkv, rows, dp = q_rot.shape
+    page_size = pool[0].shape[2]
+    mp = page_table.shape[1]
+    bins = jnp.stack([
+        jnp.asarray(n_bins_k, jnp.int32).reshape(()),
+        jnp.asarray(n_bins_v, jnp.int32).reshape(()),
+    ])
+    sq = pl.squeezed
+
+    def pool_spec(arr):
+        return pl.BlockSpec(
+            (sq, sq, page_size, arr.shape[-1]),
+            lambda bi, ni, pi, pt, lens, bins_: (pt[bi, pi], ni, 0, 0))
+
+    q_spec = pl.BlockSpec((sq, sq, rows, dp),
+                          lambda bi, ni, pi, *_: (bi, ni, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # page_table, lengths, bins
+        grid=(b, nkv, mp),
+        in_specs=[q_spec, *[pool_spec(a) for a in pool]],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, dp), jnp.float32),
+        ],
+    )
+    out_perm = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, nkv, rows, dp), jnp.float32),
+        interpret=interpret,
+        name="qattn_paged",
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), bins,
+      _to_split_half(q_rot), *pool)
+    return _from_split_half(out_perm)
+
+
+def _geom(q_rot, idx_bits, k_bits, k_log, k_nq_packed, v_bits, v_log,
+          v_nq_packed) -> dict:
+    return dict(pairs=q_rot.shape[-1] // 2, idx_bits=idx_bits,
+                k_bits=k_bits, k_log=k_log, k_nq_packed=k_nq_packed,
+                v_bits=v_bits, v_log=v_log, v_nq_packed=v_nq_packed)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def paged_qattn(
+    q_rot: jax.Array,  # (B, nkv, G, Dp) f32, pre-scaled
+    k_idx: jax.Array,  # (P, nkv, ps, words) uint32 — ONE layer's pool
+    k_nq: jax.Array,
+    k_rmin: jax.Array,  # (P, nkv, ps, 1)
+    k_rmax: jax.Array,
+    v_idx: jax.Array,
+    v_nq: jax.Array,
+    v_rmin: jax.Array,
+    v_rmax: jax.Array,
+    page_table: jax.Array,  # (B, max_pages) int32 physical page ids
+    lengths: jax.Array,  # (B,) int32 valid tokens per slot
+    *,
+    n_bins_k,  # int or traced i32 scalar
+    n_bins_v,
+    idx_bits=None,  # static: packed index width; None -> container codes
+    k_bits=None,
+    k_log: bool = False,
+    k_nq_packed: bool = False,
+    v_bits=None,
+    v_log: bool = False,
+    v_nq_packed: bool = False,
+    interpret: bool,
+) -> jax.Array:
+    """Flash-decode over the paged pool. The block size IS the page size —
+    one grid step streams one physical page per (slot, kv-head)."""
+    geom = _geom(q_rot, idx_bits, k_bits, k_log, k_nq_packed, v_bits, v_log,
+                 v_nq_packed)
+    kernel = functools.partial(paged_qattn_kernel,
+                               page_size=k_idx.shape[2], geom=geom)
+    pool = (k_idx, k_nq, k_rmin, k_rmax, v_idx, v_nq, v_rmin, v_rmax)
+    return _paged_call(kernel, q_rot, pool, page_table, lengths, n_bins_k,
+                       n_bins_v, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("q_len", "g") + _STATIC)
 def paged_qattn_multi(
     q_rot: jax.Array,  # (B, nkv, q_len*g, Dp) f32, pre-scaled, row r = j*g+gi
-    k_idx: jax.Array,  # (P, ps, nkv, words) uint32 — ONE layer's pool
+    k_idx: jax.Array,  # (P, nkv, ps, words) uint32 — ONE layer's pool
     k_nq: jax.Array,
     k_rmin: jax.Array,
     k_rmax: jax.Array,
@@ -634,67 +400,84 @@ def paged_qattn_multi(
     v_bits=None,
     v_log: bool = False,
     v_nq_packed: bool = False,
-    interpret: bool = True,
-    unpack: str | None = None,
-    n_bins_cap: int | None = None,
+    interpret: bool,
 ) -> jax.Array:
     """Fused speculative-verify flash-decode: q_len query rows per slot,
     one page walk. Returns (B, nkv, q_len*g, Dp) f32 (split-half undone)."""
-    b, nkv, rows, dp = q_rot.shape
+    rows = q_rot.shape[2]
     if rows != q_len * g:
         raise ValueError(f"q_rot rows {rows} != q_len*g = {q_len * g}")
-    page_size = k_idx.shape[1]
-    mp = page_table.shape[1]
-    pairs = dp // 2
-    if unpack is None:
-        unpack = default_unpack(interpret)
-    grid = (b, nkv, mp)
+    geom = _geom(q_rot, idx_bits, k_bits, k_log, k_nq_packed, v_bits, v_log,
+                 v_nq_packed)
+    kernel = functools.partial(paged_qattn_multi_kernel,
+                               page_size=k_idx.shape[2], q_len=q_len, g=g,
+                               geom=geom)
+    pool = (k_idx, k_nq, k_rmin, k_rmax, v_idx, v_nq, v_rmin, v_rmax)
+    return _paged_call(kernel, q_rot, pool, page_table, lengths, n_bins_k,
+                       n_bins_v, interpret=interpret)
 
-    bins = jnp.stack([
-        jnp.asarray(n_bins_k, jnp.int32).reshape(()),
-        jnp.asarray(n_bins_v, jnp.int32).reshape(()),
-    ])
-    q_perm = _to_split_half(q_rot)
 
-    def pool_spec(arr):
-        last = arr.shape[-1]
-        return pl.BlockSpec(
-            (1, page_size, 1, last),
-            lambda bi, ni, pi, pt, lens, bins_: (pt[bi, pi], 0, ni, 0))
+@functools.partial(jax.jit, static_argnames=("block_t",) + _STATIC)
+def qattn(
+    q_rot: jax.Array,  # (B, nkv, G, Dp) f32, pre-scaled
+    k_idx: jax.Array,  # (B, T, nkv, words) uint32 or (B, T, nkv, pairs) int
+    k_nq: jax.Array,
+    k_rmin: jax.Array,  # (B, T, nkv, 1)
+    k_rmax: jax.Array,
+    v_idx: jax.Array,
+    v_nq: jax.Array,
+    v_rmin: jax.Array,
+    v_rmax: jax.Array,
+    length: jax.Array,  # (B,) per-sequence valid counts, or () broadcast
+    *,
+    n_bins_k,  # int or traced i32 scalar (per-layer MixedKV scan value)
+    n_bins_v,
+    idx_bits=None,
+    k_bits=None,
+    k_log: bool = False,
+    k_nq_packed: bool = False,
+    v_bits=None,
+    v_log: bool = False,
+    v_nq_packed: bool = False,
+    block_t: int | None = None,
+    interpret: bool,
+) -> jax.Array:
+    """Flash-decode over a contiguous (B, T, nkv, X) cache.
 
-    from jax.experimental.pallas import tpu as pltpu
+    The cache is viewed as a pool of `block_t`-token pages — (B*T/block_t,
+    nkv, block_t, X), slot b owning pages b*nb .. b*nb+nb-1 in order — and
+    handed to the paged kernel. The transpose into page layout is one XLA
+    copy of the cache per call (the contiguous cache serves the static
+    engine, not the paged serving path); in exchange there is one kernel,
+    and the contiguous attend is by construction bit-for-bit the paged
+    attend at page_size == block_t. `block_t` defaults to a VMEM-budget
+    value (`default_block_t`), clamped to T.
+    """
+    b, t = k_idx.shape[:2]
+    if block_t is None:
+        stream = sum(
+            a.shape[-1] * a.dtype.itemsize
+            for a in (k_idx, k_nq, v_idx, v_nq)) + 4 * 4  # + rmin/rmax pairs
+        block_t = default_block_t(q_rot.shape[-1], stream)
+    block_t = min(block_t, t)
+    nb = -(-t // block_t)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # page_table, lengths, bins
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, rows, dp),
-                         lambda bi, ni, pi, *_: (bi, ni, 0, 0)),
-            pool_spec(k_idx), pool_spec(k_nq),
-            pool_spec(k_rmin), pool_spec(k_rmax),
-            pool_spec(v_idx), pool_spec(v_nq),
-            pool_spec(v_rmin), pool_spec(v_rmax),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, dp),
-                               lambda bi, ni, pi, *_: (bi, ni, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, dp), jnp.float32),
-        ],
-    )
-    out_perm = pl.pallas_call(
-        functools.partial(
-            paged_qattn_multi_kernel, page_size=page_size, pairs=pairs,
-            q_len=q_len, g=g, idx_bits=idx_bits, k_bits=k_bits, k_log=k_log,
-            k_nq_packed=k_nq_packed, v_bits=v_bits, v_log=v_log,
-            v_nq_packed=v_nq_packed, unpack=unpack, n_bins_cap=n_bins_cap),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, rows, dp), jnp.float32),
-        interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), bins,
-      q_perm, k_idx, k_nq, k_rmin, k_rmax, v_idx, v_nq, v_rmin, v_rmax)
-    return _from_split_half(out_perm)
+    def as_pages(a):  # (B, T, nkv, X) -> (B*nb, nkv, block_t, X)
+        a = jnp.pad(a, ((0, 0), (0, nb * block_t - t), (0, 0), (0, 0)))
+        a = a.reshape(b, nb, block_t, *a.shape[2:])
+        return jnp.swapaxes(a, 2, 3).reshape(b * nb, a.shape[3], block_t,
+                                             a.shape[-1])
+
+    from repro.cache.kvcache import per_seq_lengths
+
+    table = jnp.arange(b * nb, dtype=jnp.int32).reshape(b, nb)
+    return paged_qattn(
+        q_rot, *[as_pages(a) for a in (k_idx, k_nq, k_rmin, k_rmax, v_idx,
+                                        v_nq, v_rmin, v_rmax)],
+        table, per_seq_lengths(length, b), n_bins_k=n_bins_k,
+        n_bins_v=n_bins_v, idx_bits=idx_bits, k_bits=k_bits, k_log=k_log,
+        k_nq_packed=k_nq_packed, v_bits=v_bits, v_log=v_log,
+        v_nq_packed=v_nq_packed, interpret=interpret)
 
 
 # ======================================================= verify rows ========

@@ -41,19 +41,3 @@ def qattn_ref(q_rot, k_idx, k_nq, k_rmin, k_rmax, v_idx, v_nq, v_rmin,
     p = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bngt,btnd->bngd", p, y_v)
 
-
-def paged_qattn_ref(q_rot, pool_args, page_table, lengths, **kw):
-    """Oracle for the paged kernel: gather each slot's pages into a
-    contiguous (B, max_pages*ps, ...) view, then run the dense oracle.
-
-    pool_args is the 8-tuple (k_idx, k_nq, k_rmin, k_rmax, v_idx, v_nq,
-    v_rmin, v_rmax) with leading (P, page_size, n_kv, ...) pool layout.
-    """
-    b, mp = page_table.shape
-    ps = pool_args[0].shape[1]
-
-    def take(a):  # (P, ps, n_kv, X) -> (B, mp*ps, n_kv, X)
-        return a[page_table].reshape(b, mp * ps, *a.shape[2:])
-
-    dense = [take(a) for a in pool_args]
-    return qattn_ref(q_rot, *dense, lengths, **kw)

@@ -92,11 +92,6 @@ def collective_bytes(hlo_text: str) -> dict:
 def analyze_compiled(compiled, n_chips: int) -> dict:
     """Roofline terms from one compiled executable."""
     cost = compiled.cost_analysis()
-    # Decode executables (donated-state while bodies) come back in the legacy
-    # one-element-list-of-dict form on this jax version while train/prefill
-    # return a flat dict — the decode_32k cell hit `list.get` otherwise.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)

@@ -2,7 +2,11 @@
 through a pluggable attention backend, report memory/compression stats.
 
     PYTHONPATH=src python -m repro.launch.serve --arch mistral-7b --reduced \
-        --prompt-len 64 --gen 32 --backend quant-pallas
+        --prompt-len 64 --gen 32 --backend quant-pallas --interpret
+
+quant-pallas compiles its Pallas kernels for the TPU unless --interpret
+runs them in the interpreter (the way to use it on a CPU); it never picks
+between the two by itself.
 
 Ragged batches: --prompt-lens 64,48,32,20 gives each row its own prompt
 length (right-padded internally); per-sequence EOS (--eos-id) stops rows
@@ -42,6 +46,7 @@ import numpy as np
 
 from repro.cache import kvcache
 from repro.configs import registry
+from repro.launch import jax_cache
 from repro.launch import steps as steps_lib
 from repro.models import transformer
 from repro.serving import backends as backends_lib
@@ -73,6 +78,10 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--backend", default="auto",
                     choices=("auto",) + backends_lib.BACKEND_NAMES)
+    ap.add_argument("--interpret", action="store_true",
+                    help="quant-pallas: run the Pallas kernels in the "
+                         "interpreter (CPU). Without it the kernels are "
+                         "compiled, which needs a TPU")
     ap.add_argument("--no-quant", action="store_true",
                     help="shorthand for --backend raw")
     ap.add_argument("--storage", default="auto",
@@ -188,6 +197,7 @@ def main(argv=None):
     if args.arch and args.model and args.arch != args.model:
         ap.error("--arch and --model disagree (they are aliases)")
     args.arch = args.arch or args.model
+    jax_cache.use_persistent_cache()
 
     run = registry.get_run_config(args.arch)
     cfg = registry.get_reduced_config(args.arch) if args.reduced \
@@ -200,7 +210,7 @@ def main(argv=None):
         run, model=cfg, backend=backend_name,
         quant=dataclasses.replace(run.quant, storage=args.storage))
     qz = steps_lib.make_quantizer(run)
-    backend = backends_lib.from_run(run, qz)
+    backend = backends_lib.from_run(run, qz, interpret=args.interpret)
 
     if args.prompt_lens:
         lens = [int(x) for x in args.prompt_lens.split(",")]

@@ -218,11 +218,11 @@ class _QuantBackendBase:
         """Encode one token per slot and scatter it through the page table.
 
         layer_cache is one layer's (K, V) pool slice — arrays
-        (P, page_size, n_kv, ...). Inactive slots write the reserved trash
+        (P, n_kv, page_size, ...). Inactive slots write the reserved trash
         page (see serving/pages.py)."""
         layer_kq, layer_vq = layer_cache
         qz = self.quantizer
-        ps = layer_kq.indices.shape[1]
+        ps = layer_kq.indices.shape[2]
         new_kq = qz.encode(new_k, nk, qz.config.k_norm)
         new_vq = qz.encode(new_v, nv, qz.config.v_norm)
         return (
@@ -238,9 +238,8 @@ class _QuantBackendBase:
         Bitwise-identical to a contiguous cache of the same width (parity
         oracle for the kernel path)."""
         layer_kq, layer_vq = layer_cache
-        ps = layer_kq.indices.shape[1]
-        dense_k = pages_lib.gather_pages(layer_kq, page_table, ps)
-        dense_v = pages_lib.gather_pages(layer_vq, page_table, ps)
+        dense_k = pages_lib.gather_pages(layer_kq, page_table)
+        dense_v = pages_lib.gather_pages(layer_vq, page_table)
         y_dtype = getattr(self, "y_dtype", jnp.float32)
         return kvcache.attend_quant_cache(
             q, dense_k, dense_v, nk, nv, lengths, self.cfg, self.quantizer,
@@ -258,7 +257,7 @@ class _QuantBackendBase:
         frontier, masked by every attend path."""
         layer_kq, layer_vq = layer_cache
         qz = self.quantizer
-        ps = layer_kq.indices.shape[1]
+        ps = layer_kq.indices.shape[2]
         new_kq = qz.encode(new_k, nk, qz.config.k_norm)
         new_vq = qz.encode(new_v, nv, qz.config.v_norm)
         return (
@@ -315,34 +314,38 @@ class QuantPallasBackend(_QuantBackendBase):
     bit-packed uint32 word streams (the default; unpacked in VMEM inside
     the kernel) or legacy uint8/uint16 containers.
 
-    interpret=None resolves at call time: compiled on TPU, interpreter
-    everywhere else (CPU CI still exercises the same kernel body).
+    interpret is required and chosen by whoever builds the backend: True
+    runs the kernel bodies in the Pallas interpreter (CPU tests), False
+    compiles them for the TPU. A compiled backend on a platform without a
+    TPU raises at construction — it never falls back to the interpreter.
 
     block_t overrides the kernel's VMEM-derived token-block size; setting
     it to a paged engine's page_size makes the contiguous kernel's
     accumulation order bit-for-bit the paged kernel's (parity tests and
     the serve-throughput baseline use this).
-
-    unpack overrides the kernel's bitstream unpack scheme
-    (`packing.UNPACK_METHODS`; None resolves per platform — gather off-TPU,
-    bitplane on TPU). Bitwise identical either way; the autotuner
-    (`kernels.qattn.autotune`) measures which is faster for a geometry.
     """
 
     name: str = "quant-pallas"
-    interpret: Optional[bool] = None
+    interpret: Optional[bool] = None  # required; default only for order
     block_t: Optional[int] = None
-    unpack: Optional[str] = None
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.interpret is None:
+            raise ValueError(
+                "quant-pallas needs interpret=True (Pallas interpreter) or "
+                "interpret=False (compiled kernels, TPU only)")
+        if not self.interpret and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"compiled quant-pallas kernels need a TPU; the default "
+                f"JAX backend is {jax.default_backend()!r} (pass "
+                f"interpret=True to run them in the Pallas interpreter)")
 
     def attend(self, q, layer_cache, nk, nv, n_valid):
         layer_kq, layer_vq = layer_cache
-        interpret = self.interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         return qattn_ops.attend_quant_cache_op(
             q, layer_kq, layer_vq, nk, nv, n_valid, self.cfg,
-            self.quantizer, interpret=interpret, block_t=self.block_t,
-            unpack=self.unpack)
+            self.quantizer, interpret=self.interpret, block_t=self.block_t)
 
     def attend_stream_bytes(self, cache) -> int:
         """Cache bytes the kernel streams from HBM per decode step.
@@ -364,12 +367,9 @@ class QuantPallasBackend(_QuantBackendBase):
         block resolves through the scalar-prefetched page table, streaming
         only the pages each slot owns — no contiguous materialization."""
         layer_kq, layer_vq = layer_cache
-        interpret = self.interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         return qattn_ops.paged_attend_quant_cache_op(
             q, layer_kq, layer_vq, nk, nv, page_table, lengths, self.cfg,
-            self.quantizer, interpret=interpret, unpack=self.unpack)
+            self.quantizer, interpret=self.interpret)
 
     def paged_attend_multi(self, q, layer_cache, nk, nv, page_table,
                            lengths):
@@ -382,12 +382,9 @@ class QuantPallasBackend(_QuantBackendBase):
         at ~1/q_len the kernel work — the difference between speculation
         saving steps on paper and saving milliseconds."""
         layer_kq, layer_vq = layer_cache
-        interpret = self.interpret
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         return qattn_ops.paged_attend_multi_quant_cache_op(
             q, layer_kq, layer_vq, nk, nv, page_table, lengths, self.cfg,
-            self.quantizer, interpret=interpret, unpack=self.unpack)
+            self.quantizer, interpret=self.interpret)
 
 
 def get_backend(
@@ -398,7 +395,8 @@ def get_backend(
     dtype=jnp.bfloat16,
     interpret: Optional[bool] = None,
 ) -> AttentionBackend:
-    """Construct a backend by name. Quant backends require a quantizer."""
+    """Construct a backend by name. Quant backends require a quantizer;
+    quant-pallas also requires `interpret` (see QuantPallasBackend)."""
     if name == "raw":
         return RawBackend(cfg, dtype=dtype)
     if name == "quant-xla":
@@ -408,23 +406,24 @@ def get_backend(
     raise ValueError(f"unknown backend {name!r}; expected {BACKEND_NAMES}")
 
 
-def default_backend(cfg: ModelConfig,
-                    quantizer: Optional[KVQuantizer]) -> AttentionBackend:
+def default_backend(cfg: ModelConfig, quantizer: Optional[KVQuantizer],
+                    *, interpret: Optional[bool] = None) -> AttentionBackend:
     """Legacy-compatible resolution from a bare (cfg, quantizer) pair."""
     if quantizer is None:
         return RawBackend(cfg)
     if cfg.use_pallas:
-        return QuantPallasBackend(cfg, quantizer)
+        return QuantPallasBackend(cfg, quantizer, interpret=interpret)
     return QuantXLABackend(cfg, quantizer)
 
 
-def from_run(run: RunConfig,
-             quantizer: Optional[KVQuantizer]) -> AttentionBackend:
-    """Resolve `RunConfig.backend` ("auto" defers to quant/use_pallas)."""
+def from_run(run: RunConfig, quantizer: Optional[KVQuantizer], *,
+             interpret: Optional[bool] = None) -> AttentionBackend:
+    """Resolve `RunConfig.backend` ("auto" defers to quant/use_pallas).
+    `interpret` is passed to a quant-pallas backend, which requires it."""
     name = run.backend
     if name == "auto":
-        return default_backend(run.model, quantizer)
+        return default_backend(run.model, quantizer, interpret=interpret)
     if name != "raw" and quantizer is None:
         raise ValueError(
             f"backend {name!r} needs quantization enabled (run.quant)")
-    return get_backend(name, run.model, quantizer)
+    return get_backend(name, run.model, quantizer, interpret=interpret)

@@ -7,7 +7,7 @@ vLLM-style block cache (the property FibQuant calls out as the enabler for
 paged compressed caches). This module provides the two halves:
 
   * device side — `PagedKVCache`: layer-stacked pool arrays
-    `(L, P, page_size, n_kv, ...)` holding the *packed* payload (angle words
+    `(L, P, n_kv, page_size, ...)` holding the *packed* payload (angle words
     + norm nibbles + per-vector min/max), a `(B, max_pages)` page table of
     physical page ids per decode slot, and per-slot `lengths`. Pages are
     shared across layers: physical page p holds the same token range in
@@ -45,7 +45,9 @@ from repro.core.quantizer import KVQuantizer, QuantizedKV
 class PagedKVCache(NamedTuple):
     """Device-side paged pool + per-slot indirection.
 
-    k/v:        QuantizedKV pools, arrays (L, P, page_size, n_kv, ...)
+    k/v:        QuantizedKV pools, arrays (L, P, n_kv, page_size, ...) —
+                one page of one kv head is a (page_size, X) tile, the
+                block the Pallas kernel streams per grid step
     page_table: (B, max_pages) int32 physical page ids (0 = unused/trash;
                 entries past a slot's allocation are masked via lengths)
     lengths:    (B,) int32 — valid tokens per decode slot
@@ -93,7 +95,7 @@ def init_paged_cache(cfg: ModelConfig, qz: KVQuantizer, num_pages: int,
     if num_pages < 2:
         raise ValueError(f"num_pages must be >= 2 (page 0 is reserved), "
                          f"got {num_pages}")
-    lead = (cfg.num_attn_layers, num_pages, page_size, cfg.num_kv_heads)
+    lead = (cfg.num_attn_layers, num_pages, cfg.num_kv_heads, page_size)
     return PagedKVCache(
         k=kvcache._quantized_zeros(qz, lead, qz.config.k_norm),
         v=kvcache._quantized_zeros(qz, lead, qz.config.v_norm),
@@ -112,19 +114,31 @@ def write_prompt_pages(pool: QuantizedKV, codes: QuantizedKV,
                        page_ids: jax.Array, page_size: int) -> QuantizedKV:
     """Scatter a prefill chunk's quantized codes into pool pages.
 
-    pool arrays: (L, P, page_size, n_kv, X); codes arrays: (L, C, n_kv, X)
+    pool arrays: (L, P, n_kv, page_size, X); codes arrays: (L, C, n_kv, X)
     with C == len(page_ids) * page_size (the scheduler pads prompts to a
     whole number of pages; tail slots hold encoded padding that stays masked
     until decode overwrites it — the same invariant as the dense engine).
     """
-    n = page_ids.shape[0]
-
     def put(pool_a, codes_a):
-        l = pool_a.shape[0]
-        resh = codes_a.reshape(l, n, page_size, *codes_a.shape[2:])
-        return pool_a.at[:, page_ids].set(resh.astype(pool_a.dtype))
+        pages = tokens_to_pages(codes_a, page_size, axis=1)
+        return pool_a.at[:, page_ids].set(pages.astype(pool_a.dtype))
 
     return jax.tree.map(put, pool, codes)
+
+
+def tokens_to_pages(a: jax.Array, page_size: int, axis: int) -> jax.Array:
+    """(..., T, n_kv, X) -> (..., T/page_size, n_kv, page_size, X), the
+    token axis at `axis`: token-major codes cut into pool-layout pages."""
+    lead, (t, nkv, x) = a.shape[:axis], a.shape[axis:]
+    a = a.reshape(*lead, t // page_size, page_size, nkv, x)
+    return jnp.swapaxes(a, axis + 1, axis + 2)
+
+
+def pages_to_tokens(a: jax.Array, axis: int) -> jax.Array:
+    """Inverse of `tokens_to_pages`: (..., n, n_kv, page_size, X) ->
+    (..., n*page_size, n_kv, X), the page axis at `axis`."""
+    lead, (n, nkv, ps, x) = a.shape[:axis], a.shape[axis:]
+    return jnp.swapaxes(a, axis + 1, axis + 2).reshape(*lead, n * ps, nkv, x)
 
 
 def append_token_pages(layer_pool: QuantizedKV, new_q: QuantizedKV,
@@ -133,7 +147,7 @@ def append_token_pages(layer_pool: QuantizedKV, new_q: QuantizedKV,
     """Write one token per decode slot at (page_table[i, len//ps], len%ps).
 
     Operates on ONE layer's pool slice (the decode step scans layers with
-    the pool as scan xs): layer_pool arrays (P, ps, n_kv, X), new_q arrays
+    the pool as scan xs): layer_pool arrays (P, n_kv, ps, X), new_q arrays
     (B, 1, n_kv, X). Inactive slots are redirected to the reserved trash
     page 0 so the scatter stays branch-free.
     """
@@ -144,7 +158,8 @@ def append_token_pages(layer_pool: QuantizedKV, new_q: QuantizedKV,
     offset = jnp.where(active, lengths % page_size, 0)
 
     def put(pool_a, new_a):
-        return pool_a.at[phys, offset].set(new_a[:, 0].astype(pool_a.dtype))
+        return pool_a.at[phys, :, offset].set(
+            new_a[:, 0].astype(pool_a.dtype))
 
     return jax.tree.map(put, layer_pool, new_q)
 
@@ -162,7 +177,7 @@ def append_tokens_pages(layer_pool: QuantizedKV, new_q: QuantizedKV,
     redirected to the reserved trash page 0, exactly like inactive slots
     in the single-token `append_token_pages`.
 
-    layer_pool arrays: (P, ps, n_kv, X); new_q arrays: (B, q_len, n_kv, X).
+    layer_pool arrays: (P, n_kv, ps, X); new_q arrays: (B, q_len, n_kv, X).
     """
     b, q_len = valid.shape
     pos = lengths[:, None] + jnp.arange(q_len, dtype=lengths.dtype)[None, :]
@@ -173,7 +188,7 @@ def append_tokens_pages(layer_pool: QuantizedKV, new_q: QuantizedKV,
 
     def put(pool_a, new_a):
         flat = new_a.reshape(b * q_len, *new_a.shape[2:])
-        return pool_a.at[phys, offset].set(flat.astype(pool_a.dtype))
+        return pool_a.at[phys, :, offset].set(flat.astype(pool_a.dtype))
 
     return jax.tree.map(put, layer_pool, new_q)
 
@@ -247,19 +262,13 @@ def pop_tokens(allocator: "PageAllocator", owner, page_table_row: np.ndarray,
     return new_length, np.asarray(freed, np.int32)
 
 
-def gather_pages(pool: QuantizedKV, page_table: jax.Array,
-                 page_size: int) -> QuantizedKV:
+def gather_pages(pool: QuantizedKV, page_table: jax.Array) -> QuantizedKV:
     """Materialize a contiguous (B, max_pages*ps, n_kv, X) view of one
-    layer's pool via the page table — the quant-xla fallback's indirection
-    (the Pallas kernel gathers per-page in its index_map instead and never
-    materializes this)."""
-    b, mp = page_table.shape
-
-    def take(pool_a):  # (P, ps, n_kv, X)
-        g = pool_a[page_table]  # (B, mp, ps, n_kv, X)
-        return g.reshape(b, mp * page_size, *pool_a.shape[2:])
-
-    return jax.tree.map(take, pool)
+    layer's (P, n_kv, ps, X) pool via the page table — the quant-xla
+    fallback's indirection (the Pallas kernel gathers per-page in its
+    index_map instead and never materializes this)."""
+    return jax.tree.map(lambda a: pages_to_tokens(a[page_table], axis=1),
+                        pool)
 
 
 def per_page_valid(length: int, max_pages: int, page_size: int) -> np.ndarray:

@@ -473,9 +473,12 @@ class PagedServingEngine:
         # runs under shard_map (`_mesh_jit`). mesh=None is the legacy
         # single-device path, bitwise- and dispatch-count-identical.
         self._shard: Optional[decoding.ShardInfo] = None
+        self._replicated: Optional[jax.sharding.NamedSharding] = None
         if sched.mesh is not None:
             n_sh = sharding_lib.kv_shard_count(cfg, sched.mesh)
             self._shard = decoding.ShardInfo("model", n_sh)
+            self._replicated = jax.sharding.NamedSharding(
+                sched.mesh, jax.sharding.PartitionSpec())
             self.params = sharding_lib.replicate(self.params, sched.mesh)
         self.allocator = self._make_allocator(sched.num_pages)
         self.pool = None
@@ -719,20 +722,18 @@ class PagedServingEngine:
         `pool_in`/`pool_out` index the arguments/outputs that are paged
         pool trees (kv-head-sharded, `paged_pool_pspec`); everything else
         is replicated. Specs are pytree prefixes, so one spec covers a
-        whole QuantizedKV tree. check_rep=False: the replicated outputs
+        whole QuantizedKV tree. check_vma=False: the replicated outputs
         (logits, tokens, counters) are replicated by construction — every
         device runs the same math on the same replicated operands after
         the all-gather — but shard_map cannot infer that statically."""
         if self._shard is None:
             return jax.jit(fn, donate_argnums=donate)
-        from jax.experimental.shard_map import shard_map
-
         pp = sharding_lib.paged_pool_pspec()
         rep = jax.sharding.PartitionSpec()
         in_specs = tuple(pp if i in pool_in else rep for i in range(n_in))
         out_specs = tuple(pp if i in pool_out else rep for i in range(n_out))
-        wrapped = shard_map(fn, mesh=self.sched.mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False)
+        wrapped = jax.shard_map(fn, mesh=self.sched.mesh, in_specs=in_specs,
+                                out_specs=out_specs, check_vma=False)
         return jax.jit(wrapped, donate_argnums=donate)
 
     def _build_decode(self):
@@ -1233,6 +1234,13 @@ class PagedServingEngine:
         from repro.serving import compile_cache
         return compile_cache.warmup(self, skips=skips)
 
+    def executable(self, key: tuple):
+        """The AOT-compiled executable `warmup` installed for a dispatch
+        key such as ("decode", table_width) — for inspecting the compiled
+        program (`.as_text()`, `.memory_analysis()`). KeyError when the key
+        was not warmed (mesh engines warm their lazy jit cache instead)."""
+        return self._exec[key]
+
     def _dispatch(self, key: tuple, fn, *args):
         """Route a device dispatch through the jit-variant table.
 
@@ -1244,6 +1252,7 @@ class PagedServingEngine:
         perf-smoke CI job asserts stays zero.
         """
         self._last_dispatch_key = key  # watchdog diagnostic breadcrumb
+        args = self._on_mesh(args)
         if key not in self._compiled_keys:
             self._compiled_keys.add(key)
             self._perf["jit_variants_compiled"] += 1
@@ -1251,6 +1260,24 @@ class PagedServingEngine:
                 self._perf["post_warmup_variants"] += 1
         ex = self._exec.get(key)
         return fn(*args) if ex is None else ex(*args)
+
+    def _on_mesh(self, args):
+        """With a mesh, commit every array argument that is not already
+        on it to replication: host (numpy) step inputs go straight from
+        the host, so none is parked on device 0, and so do device values
+        such as rng keys and zero-width prefix buffers. Pools and params
+        keep their own committed shardings. Identity without a mesh."""
+        if self._replicated is None:
+            return args
+        mesh = self._replicated.mesh
+
+        def commit(a):
+            sh = getattr(a, "sharding", None)
+            if isinstance(sh, jax.sharding.NamedSharding) and sh.mesh == mesh:
+                return a
+            return jax.device_put(a, self._replicated)
+
+        return jax.tree.map(commit, args)
 
     def _live_table_width(self, k: int) -> int:
         """Page-table columns a k-step burst can touch, bucketed to the next
@@ -1353,9 +1380,9 @@ class PagedServingEngine:
         pk, pv, targets, emit = self._dispatch(
             ("verify", mp), self._verify_fn,
             self.params, self.pool.k, self.pool.v,
-            jnp.asarray(self.page_table[:, :mp]),
-            jnp.asarray(self.lengths), jnp.asarray(self.active),
-            jnp.asarray(owned), jnp.asarray(fed), jnp.asarray(n_fed))
+            np.asarray(self.page_table[:, :mp]),
+            np.asarray(self.lengths), np.asarray(self.active),
+            np.asarray(owned), np.asarray(fed), np.asarray(n_fed))
         self.pool = self.pool._replace(k=pk, v=pv)
         targets = np.asarray(targets)
         emit = np.asarray(emit)
@@ -1445,10 +1472,10 @@ class PagedServingEngine:
         pk, pv, emitted, out, n_prop, n_acc, n_steps = self._dispatch(
             ("spec", mp), self._spec_fn,
             self.params, self.pool.k, self.pool.v,
-            jnp.asarray(self.page_table[:, :mp]), jnp.asarray(self.lengths),
-            jnp.asarray(self.active), jnp.asarray(owned),
-            jnp.asarray(self.ctx_buf), jnp.asarray(self.ctx_len),
-            jnp.asarray(remaining), jnp.asarray(k_rounds, jnp.int32))
+            np.asarray(self.page_table[:, :mp]), np.asarray(self.lengths),
+            np.asarray(self.active), np.asarray(owned),
+            np.asarray(self.ctx_buf), np.asarray(self.ctx_len),
+            np.asarray(remaining), np.asarray(k_rounds, jnp.int32))
         self.pool = self.pool._replace(k=pk, v=pv)
         emitted = np.asarray(emitted)
         out = np.asarray(out)
@@ -1650,16 +1677,14 @@ class PagedServingEngine:
         if n_pages in self._prefix_load_fns:
             return self._prefix_load_fns[n_pages]
         cfg, qz = self.cfg, self.backend.quantizer
-        ps = self.sched.page_size
         nk, nv = transformer._layer_bins(qz, cfg.num_layers)
         dt = jnp.dtype(cfg.compute_dtype)
         shard = self._shard
 
         def load(page_ids, pool_k, pool_v):
-            def take(pool_a):  # (L, P, ps, n_kv, X) -> (L, 1, n*ps, ...)
-                g = pool_a[:, page_ids]
-                return g.reshape(pool_a.shape[0], 1, n_pages * ps,
-                                 *pool_a.shape[3:])
+            def take(pool_a):  # (L, P, n_kv, ps, X) -> (L, 1, n*ps, ...)
+                return pages_lib.pages_to_tokens(pool_a[:, page_ids],
+                                                 axis=1)[:, None]
 
             kq = jax.tree.map(take, pool_k)
             vq = jax.tree.map(take, pool_v)
@@ -1775,16 +1800,16 @@ class PagedServingEngine:
             pfx_k, pfx_v = self._dispatch(
                 ("prefix_load", skip // ps),
                 self._prefix_load_fn(skip // ps),
-                jnp.asarray(shared_ids), self.pool.k, self.pool.v)
+                np.asarray(shared_ids), self.pool.k, self.pool.v)
         else:
             empty = (self.cfg.num_layers, 1, 0, self.cfg.num_kv_heads,
                      self.cfg.head_dim)
             pfx_k = pfx_v = jnp.zeros(empty, self.cfg.compute_dtype)
         tok, pk, pv = self._dispatch(
             ("prefill", width, skip), self._prefill_fn(width, skip),
-            self.params, jnp.asarray(pad.reshape(n_chunks, chunk)),
-            jnp.asarray(groups), jnp.asarray(last_chunk, jnp.int32),
-            jnp.asarray(last_off, jnp.int32), pfx_k, pfx_v, rng,
+            self.params, np.asarray(pad.reshape(n_chunks, chunk)),
+            np.asarray(groups), np.asarray(last_chunk, jnp.int32),
+            np.asarray(last_off, jnp.int32), pfx_k, pfx_v, rng,
             self.pool.k, self.pool.v)
         self.pool = self.pool._replace(k=pk, v=pv)
         self._m["prefill_chunks"].inc(n_chunks)
@@ -1839,15 +1864,15 @@ class PagedServingEngine:
             self.page_table[slot] = row
             self.lengths[slot] = 0
             tok, pk, pv, packed = self._dispatch(
-                key, fn, self.params, jnp.asarray(pad),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(plen, jnp.int32),
-                self.pool.k, self.pool.v, jnp.asarray(self.page_table),
-                jnp.asarray(self.lengths), self.states, rng)
+                key, fn, self.params, np.asarray(pad),
+                np.asarray(slot, jnp.int32), np.asarray(plen, jnp.int32),
+                self.pool.k, self.pool.v, np.asarray(self.page_table),
+                np.asarray(self.lengths), self.states, rng)
             self.pool = self.pool._replace(k=pk, v=pv)
         else:
             tok, packed = self._dispatch(
-                key, fn, self.params, jnp.asarray(pad),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(plen, jnp.int32),
+                key, fn, self.params, np.asarray(pad),
+                np.asarray(slot, jnp.int32), np.asarray(plen, jnp.int32),
                 self.states, rng)
         self.states = packed
         self._m["prefill_tokens"].inc(width)
@@ -2633,20 +2658,20 @@ class PagedServingEngine:
                     pk, pv, emitted, out, packed = self._dispatch(
                         ("decode", mp), self._decode_fn,
                         self.params, self.pool.k, self.pool.v,
-                        jnp.asarray(self.page_table[:, :mp]),
-                        jnp.asarray(self.lengths),
-                        jnp.asarray(self.active),
-                        jnp.asarray(self.next_tok),
-                        jnp.asarray(remaining),
-                        jnp.asarray(k, jnp.int32), sub, self.states)
+                        np.asarray(self.page_table[:, :mp]),
+                        np.asarray(self.lengths),
+                        np.asarray(self.active),
+                        np.asarray(self.next_tok),
+                        np.asarray(remaining),
+                        np.asarray(k, jnp.int32), sub, self.states)
                     self.pool = self.pool._replace(k=pk, v=pv)
                 else:
                     emitted, out, packed = self._dispatch(
                         ("decode", 0), self._decode_fn,
-                        self.params, jnp.asarray(self.active),
-                        jnp.asarray(self.next_tok),
-                        jnp.asarray(remaining),
-                        jnp.asarray(k, jnp.int32), sub, self.states)
+                        self.params, np.asarray(self.active),
+                        np.asarray(self.next_tok),
+                        np.asarray(remaining),
+                        np.asarray(k, jnp.int32), sub, self.states)
                 self.states = packed
             elif self.backend2 is not None:
                 # tiered dispatch: both pools ride the burst; a slot's
@@ -2655,13 +2680,13 @@ class PagedServingEngine:
                     ("decode", mp), self._decode_fn,
                     self.params, self.pool.k, self.pool.v,
                     self.pool2.k, self.pool2.v,
-                    jnp.asarray(self.page_table[:, :mp]),
-                    jnp.asarray(self.page_table2[:, :mp]),
-                    jnp.asarray(self.tier2),
-                    jnp.asarray(self.lengths),
-                    jnp.asarray(self.active), jnp.asarray(owned),
-                    jnp.asarray(self.next_tok),
-                    jnp.asarray(remaining), jnp.asarray(k, jnp.int32),
+                    np.asarray(self.page_table[:, :mp]),
+                    np.asarray(self.page_table2[:, :mp]),
+                    np.asarray(self.tier2),
+                    np.asarray(self.lengths),
+                    np.asarray(self.active), np.asarray(owned),
+                    np.asarray(self.next_tok),
+                    np.asarray(remaining), np.asarray(k, jnp.int32),
                     sub)
                 self.pool2 = self.pool2._replace(k=pk2, v=pv2)
                 self.pool = self.pool._replace(k=pk, v=pv)
@@ -2669,11 +2694,11 @@ class PagedServingEngine:
                 pk, pv, emitted, out = self._dispatch(
                     ("decode", mp), self._decode_fn,
                     self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(self.page_table[:, :mp]),
-                    jnp.asarray(self.lengths),
-                    jnp.asarray(self.active), jnp.asarray(owned),
-                    jnp.asarray(self.next_tok),
-                    jnp.asarray(remaining), jnp.asarray(k, jnp.int32),
+                    np.asarray(self.page_table[:, :mp]),
+                    np.asarray(self.lengths),
+                    np.asarray(self.active), np.asarray(owned),
+                    np.asarray(self.next_tok),
+                    np.asarray(remaining), np.asarray(k, jnp.int32),
                     sub)
                 self.pool = self.pool._replace(k=pk, v=pv)
             emitted = np.asarray(emitted)

@@ -55,7 +55,7 @@ class SpilledPages:
     """Host-side copy of one request's packed pages (all layers, K + V).
 
     `k`/`v` are QuantizedKV trees of numpy arrays shaped
-    (L, n_pages, page_size, n_kv, ...) — the exact pool slices, bytes
+    (L, n_pages, n_kv, page_size, ...) — the exact pool slices, bytes
     untouched. `n_pages` is the REAL page count (the arrays may be padded
     to a power of two; padded rows are trash-page garbage)."""
 
@@ -72,7 +72,7 @@ def spill_pages(pool, page_ids: np.ndarray, tracer=None) -> SpilledPages:
     """Device -> host copy of `page_ids` out of a paged pool.
 
     `pool` is any object with QuantizedKV `.k`/`.v` pool trees of arrays
-    (L, P, page_size, n_kv, X). Returns the packed payload; the caller
+    (L, P, n_kv, page_size, X). Returns the packed payload; the caller
     releases the page references afterwards (the bytes here are a copy,
     not a view). `tracer` (a telemetry.Tracer) gets a "spill-copy" span
     covering the device->host transfer."""
@@ -196,7 +196,7 @@ def make_migrate_fn(qz1, qz2):
     (new pool2_k, pool2_v): the dequant -> requant tier migration.
 
     Layer codebook sizes broadcast as (L, 1, 1, 1, 1) against the gathered
-    (L, n, page_size, n_kv, ...) pool slices — one executable serves every
+    (L, n, n_kv, page_size, ...) pool slices — one executable serves every
     layer, the same broadcast `fake_quant_layers` uses. One compile per
     pow-2 page-count bucket (the ids' static shape)."""
     nk1, nv1 = qz1.config.schedule.as_arrays()

@@ -131,9 +131,17 @@ def test_backend_selection():
     assert backends_lib.from_run(run, None).name == "raw"
     run_p = dataclasses.replace(
         run, model=dataclasses.replace(cfg, use_pallas=True))
-    assert backends_lib.from_run(run_p, qz).name == "quant-pallas"
+    assert backends_lib.from_run(run_p, qz,
+                                 interpret=True).name == "quant-pallas"
     run_exp = dataclasses.replace(run, backend="quant-pallas")
-    assert backends_lib.from_run(run_exp, qz).name == "quant-pallas"
+    assert backends_lib.from_run(run_exp, qz,
+                                 interpret=True).name == "quant-pallas"
+    # quant-pallas never guesses between interpreter and compiled kernels
+    with pytest.raises(ValueError, match="interpret"):
+        backends_lib.from_run(run_exp, qz)
+    if jax.default_backend() != "tpu":  # compiled kernels need a TPU
+        with pytest.raises(RuntimeError, match="need a TPU"):
+            backends_lib.QuantPallasBackend(cfg, qz, interpret=False)
     with pytest.raises(ValueError):
         backends_lib.from_run(dataclasses.replace(run, backend="quant-xla"),
                               None)
@@ -323,7 +331,9 @@ def test_engine_quant_backends_end_to_end():
     prompts = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 10)), jnp.int32)
 
     outs = {}
-    for be in (backends_lib.QuantXLABackend(cfg, qz),
+    # quant-xla at f32 y_dtype: the kernel dequantizes in f32, so this is
+    # the setting at which the two backends compute the same attention
+    for be in (backends_lib.QuantXLABackend(cfg, qz, y_dtype=jnp.float32),
                backends_lib.QuantPallasBackend(cfg, qz, interpret=True)):
         res = engine.generate(params, cfg, be, prompts, lens,
                               max_new_tokens=4)

@@ -137,13 +137,16 @@ def test_pack_density():
 )
 def test_bitpack_roundtrip_with_tail_padding(bits, m, seed):
     """Round-trip for every angle width incl. streams that straddle and
-    tail-pad the last uint32 word."""
+    tail-pad the last uint32 word, through both the natural and the
+    kernel's transposed unpack."""
     rng = np.random.default_rng(seed)
     codes = jnp.asarray(rng.integers(0, 2**bits, size=(3, m)), jnp.int32)
     words = packing.pack_bits(codes, bits)
     assert words.shape == (3, packing.packed_words(m, bits))
     out = packing.unpack_bits(words, bits, m)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(codes))
+    out_t = packing.unpack_bits_T(words, bits, m)
+    np.testing.assert_array_equal(np.asarray(out_t), np.asarray(codes).T)
 
 
 @settings(max_examples=10, deadline=None)

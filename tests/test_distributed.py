@@ -23,8 +23,8 @@ from repro.launch import mesh as mesh_lib
 
 def _prod_mesh(multi_pod=False):
     if multi_pod:
-        return AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-    return AbstractMesh((("data", 16), ("model", 16)))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 # -------------------------------------------------------- spec_for ---------
@@ -55,7 +55,7 @@ def test_spec_for_never_reuses_a_mesh_axis():
 
 def test_spec_for_skips_axes_absent_from_mesh():
     rules = sharding.ShardingRules()
-    model_only = AbstractMesh((("model", 16),))
+    model_only = AbstractMesh((16,), ("model",))
     # "embed" prefers "data", which this mesh lacks -> replicated
     spec = rules.spec_for((4096, 32000), ("embed", "vocab"), model_only)
     assert spec == P(None, "model")
@@ -81,17 +81,17 @@ def test_unknown_logical_axis_replicates():
 
 # ------------------------------------------------- paged-pool helpers ------
 def test_paged_pool_pspec_shape():
-    assert sharding.paged_pool_pspec() == P(None, None, None, "model")
+    assert sharding.paged_pool_pspec() == P(None, None, "model")
 
 
 def test_kv_shard_count_validates():
     cfg = ModelConfig(name="t", family="decoder", num_layers=1, d_model=64,
                       num_heads=8, num_kv_heads=8, d_ff=64, vocab_size=64,
                       head_dim=8)
-    mesh = AbstractMesh((("data", 1), ("model", 4)))
+    mesh = AbstractMesh((1, 4), ("data", "model"))
     assert sharding.kv_shard_count(cfg, mesh) == 4
     with pytest.raises(ValueError, match="no 'model' axis"):
-        sharding.kv_shard_count(cfg, AbstractMesh((("data", 4),)))
+        sharding.kv_shard_count(cfg, AbstractMesh((4,), ("data",)))
     import dataclasses as dc
     gqa = dc.replace(cfg, num_kv_heads=3, num_heads=6)
     with pytest.raises(ValueError, match="cannot shard"):
@@ -103,16 +103,16 @@ def test_kv_shard_count_validates():
 
 def test_shard_paged_pool_splits_head_axis(sim_mesh_devices):
     mesh = mesh_lib.make_sim_mesh(2, sim_mesh_devices)
-    leaf = np.arange(2 * 4 * 8 * 4 * 3, dtype=np.float32).reshape(
-        2, 4, 8, 4, 3)
+    leaf = np.arange(2 * 4 * 4 * 8 * 3, dtype=np.float32).reshape(
+        2, 4, 4, 8, 3)
     tree = {"k": leaf, "v": leaf + 1.0}
     out = sharding.shard_paged_pool(tree, mesh)
     for name, arr in out.items():
         np.testing.assert_array_equal(np.asarray(arr), tree[name])
         shards = arr.addressable_shards
         assert len(shards) == 2
-        # head axis (3) is halved per device, all other dims intact
-        assert all(s.data.shape == (2, 4, 8, 2, 3) for s in shards)
+        # head axis (2) is halved per device, all other dims intact
+        assert all(s.data.shape == (2, 4, 2, 8, 3) for s in shards)
 
 
 def test_replicate_keeps_full_copies(sim_mesh_devices):

@@ -141,7 +141,7 @@ def test_qattn_kernel_matches_ref(g, d, norm):
     got = qattn_k.qattn(
         q_rot, *[jnp.asarray(a) for a in kc], *[jnp.asarray(a) for a in vc],
         length, n_bins_k=n_k, n_bins_v=n_v, k_bits=kb, k_log=klog,
-        v_bits=vb, v_log=vlog, block_t=64)
+        v_bits=vb, v_log=vlog, block_t=64, interpret=True)
     want = qattn_ref.qattn_ref(
         q_rot, *kc, *vc, length, n_bins_k=n_k, n_bins_v=n_v,
         k_norm_bits=kb, k_norm_log=klog, v_norm_bits=vb, v_norm_log=vlog)
@@ -171,6 +171,6 @@ def test_qattn_ops_matches_xla_cache_path():
     want = kvcache.attend_quant_cache(
         q, kq, vq, jnp.asarray(128), jnp.asarray(64), n_valid, cfg, qz)
     got = qattn_ops.attend_quant_cache_op(
-        q, kq, vq, 128, 64, n_valid, cfg, qz)
+        q, kq, vq, 128, 64, n_valid, cfg, qz, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)

@@ -127,7 +127,7 @@ def _scatter_rows(pool_q, codes_q, pt, ps):
     b, mp = pt.shape
 
     def put(pool_a, codes_a):
-        resh = codes_a.reshape(b, mp, ps, *codes_a.shape[2:])
+        resh = pages.tokens_to_pages(codes_a, ps, axis=1)
         return pool_a.at[jnp.asarray(pt)].set(resh.astype(pool_a.dtype))
 
     return jax.tree.map(put, pool_q, codes_q)
@@ -147,7 +147,7 @@ def test_write_prompt_pages_roundtrips_through_gather():
     written = pages.write_prompt_pages(pool.k, codes, jnp.asarray(ids), ps)
     table = jnp.asarray(ids[None])  # (1, 3)
     layer0 = jax.tree.map(lambda a: a[0], written)
-    dense = pages.gather_pages(layer0, table, ps)
+    dense = pages.gather_pages(layer0, table)
     for got, want in zip(jax.tree.leaves(dense), jax.tree.leaves(codes)):
         np.testing.assert_array_equal(np.asarray(got[0]),
                                       np.asarray(want[0]))
@@ -168,11 +168,11 @@ def test_append_token_pages_offsets_and_trash_redirect():
     active = jnp.asarray([True, False])
     out = pages.append_token_pages(layer, new, pt, lengths, active, ps)
     # active row 0 landed at physical page 6, offset 1
-    np.testing.assert_array_equal(np.asarray(out.indices[6, 1]),
+    np.testing.assert_array_equal(np.asarray(out.indices[6, :, 1]),
                                   np.asarray(new.indices[0, 0]))
     # inactive row 1 went to the trash page 0, NOT its table page 5
     assert (np.asarray(out.indices[5]) == 0).all()
-    assert (np.asarray(out.indices[0, 0]) ==
+    assert (np.asarray(out.indices[0, :, 0]) ==
             np.asarray(new.indices[1, 0])).all()
 
 

@@ -30,6 +30,7 @@ jax = pytest.importorskip("jax")
 from repro.configs.base import ModelConfig
 from repro.core import mixedkv, rates
 from repro.core.quantizer import KVQuantizer, QuantizerConfig
+from repro.distributed import sharding
 from repro.launch import mesh as mesh_lib
 from repro.models import transformer
 from repro.serving import backends as backends_lib
@@ -125,6 +126,32 @@ def test_token_parity_vs_single_device(setup, sim_mesh_devices,
                              n_shards, sim_mesh_devices))
     assert got == ref
     eng.allocator.check_conservation()
+
+
+def test_mesh_engine_commits_step_inputs_to_the_mesh(setup,
+                                                     sim_mesh_devices):
+    """With a mesh, nothing a step consumes is left on device 0: params
+    are replicated over the mesh, the pool is split by kv head, and host
+    (numpy) tables and device rng keys are placed replicated before each
+    dispatch."""
+    cfg, params = setup
+    mesh = mesh_lib.make_sim_mesh(2, sim_mesh_devices)
+    sc = sched_lib.SchedulerConfig(num_slots=2, page_size=8, num_pages=16,
+                                   max_context=64, prefill_chunk=8,
+                                   mesh=mesh)
+    eng = sched_lib.PagedServingEngine(
+        params, cfg, _backend("quant-xla", cfg, _qz(cfg)), sc)
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    split = jax.sharding.NamedSharding(mesh, sharding.paged_pool_pspec())
+    assert all(a.sharding.is_equivalent_to(rep, a.ndim)
+               for a in jax.tree.leaves(eng.params))
+    assert all(a.sharding.is_equivalent_to(split, a.ndim)
+               for a in jax.tree.leaves((eng.pool.k, eng.pool.v)))
+    table, key = eng._on_mesh((eng.page_table, jax.random.PRNGKey(0)))
+    for a in (table, key):
+        assert a.sharding.is_equivalent_to(rep, a.ndim)
+    assert {s.device for s in table.addressable_shards} == set(
+        mesh.devices.flat)
 
 
 def test_token_parity_8way_and_1way(setup, sim_mesh_devices):

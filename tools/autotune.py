@@ -1,17 +1,17 @@
 """CLI for the quantized-attention kernel autotuner.
 
-Measures the (block_t, unpack) candidate grid on the benchmark geometry
+Measures the block_t candidates on the benchmark geometry
 (the paper-scale head group `benchmarks/decode_bandwidth.py` times) and
 caches the winners per (geometry, backend, platform) — see
 `repro.kernels.qattn.autotune` for what is tuned and why. The cache is a
 JSON file ($REPRO_AUTOTUNE_CACHE or ~/.cache/repro/qattn_autotune.json);
-serving code applies it via `autotune.tuned_backend` without
-re-measuring.
+`autotune.tuned_backend` applies it without re-measuring. The serving
+path never reads it.
 
 Usage:
     PYTHONPATH=src python tools/autotune.py --print     # show the cache
-    PYTHONPATH=src python tools/autotune.py --refresh   # (re-)measure
-    PYTHONPATH=src python tools/autotune.py --smoke --refresh  # CI-sized
+    PYTHONPATH=src python tools/autotune.py --refresh   # (re-)measure, TPU
+    PYTHONPATH=src python tools/autotune.py --smoke --interpret  # CPU, CI
 """
 from __future__ import annotations
 
@@ -49,7 +49,7 @@ def show(cache_path: Path | None) -> None:
     print(f"autotune cache {path}: {len(entries)} entries")
     for key, e in sorted(entries.items()):
         print(f"  {key}")
-        print(f"    best: block_t={e['block_t']} unpack={e['unpack']} "
+        print(f"    best: block_t={e['block_t']} "
               f"page_size={e['page_size']} ({e['attend_ms']:.2f} ms @ "
               f"T={e['t']})")
         for cand, ms in sorted(e.get("measured", {}).items(),
@@ -69,6 +69,10 @@ def main(argv=None) -> int:
                     help="context length to measure at (0 -> auto)")
     ap.add_argument("--reps", type=int, default=0,
                     help="timing reps per candidate (0 -> auto)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="time the kernel in the Pallas interpreter (on "
+                         "CPU); without it the kernel is compiled, which "
+                         "needs a TPU")
     ap.add_argument("--cache", type=Path, default=None,
                     help="cache file (default: $REPRO_AUTOTUNE_CACHE)")
     args = ap.parse_args(argv)
@@ -82,9 +86,9 @@ def main(argv=None) -> int:
         qz = _qz(storage)
         entry = at.autotune(TUNE_CFG, qz, t=t, reps=reps,
                             block_ts=block_ts, cache_path=args.cache,
-                            refresh=args.refresh)
+                            refresh=args.refresh, interpret=args.interpret)
         print(f"storage={storage}: block_t={entry['block_t']} "
-              f"unpack={entry['unpack']} page_size={entry['page_size']} "
+              f"page_size={entry['page_size']} "
               f"({entry['attend_ms']:.2f} ms @ T={entry['t']})")
     show(args.cache)
     return 0

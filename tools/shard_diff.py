@@ -17,6 +17,10 @@ it reports WHICH surface diverged, field by field:
 Exit status: 0 when every mesh size matches the mesh=None reference,
 1 on any divergence.
 
+A CPU rehearsal tool: it forces the CPU platform and simulated host
+devices, and runs the Pallas kernels in the interpreter. The mesh path on
+real chips is run by `python chip_smoke.py --chips 4`.
+
 Needs >= 4 simulated devices; run as
 
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
