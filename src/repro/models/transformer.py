@@ -138,6 +138,7 @@ def embed_inputs(params, cfg: ModelConfig, batch: dict) -> jax.Array:
     return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
 
 
+@jax.named_scope("lm_head")
 def lm_logits(params, cfg: ModelConfig, x: jax.Array, cstr=None) -> jax.Array:
     cstr = cstr if cstr is not None else (lambda t, kind="residual": t)
     x = common.rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -244,30 +244,38 @@ def decode_step_paged(
     def body(carry, xs):
         layer_params, ck, cv, lnk, lnv = xs
         b = carry.shape[0]
-        q, k, v = attention.project_qkv(
-            layer_params["attn"],
-            common.rms_norm(carry, layer_params["norm1"], cfg.norm_eps),
-            positions, cfg)
-        if shard is not None:
-            # projection is replicated; each shard keeps its contiguous
-            # head slice (q follows its GQA group) and touches only its
-            # local pool shard
-            q = jax.lax.dynamic_slice_in_dim(q, sidx * nq_l, nq_l, axis=2)
-            k = jax.lax.dynamic_slice_in_dim(k, sidx * nkv_l, nkv_l, axis=2)
-            v = jax.lax.dynamic_slice_in_dim(v, sidx * nkv_l, nkv_l, axis=2)
-        new_c = be.paged_append(
-            (ck, cv), k, v, lnk, lnv, page_table, lengths, may_write)
-        out = be.paged_attend(
-            q, new_c, lnk, lnv, page_table, lengths + 1)
-        if shard is not None:
-            # device order == head order, so the gathered tensor is
-            # bitwise the unsharded attend's output
-            out = jax.lax.all_gather(out, shard.axis, axis=2, tiled=True)
-        out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim
-                          ).astype(carry.dtype)
-        h = jnp.einsum("bsk,kd->bsd", out, layer_params["attn"]["wo"])
-        xx = transformer.ffn_residual(layer_params, common.radd(carry, h),
-                                      cfg, shard=shard)
+        with jax.named_scope("qkv"):
+            q, k, v = attention.project_qkv(
+                layer_params["attn"],
+                common.rms_norm(carry, layer_params["norm1"], cfg.norm_eps),
+                positions, cfg)
+            if shard is not None:
+                # projection is replicated; each shard keeps its
+                # contiguous head slice (q follows its GQA group) and
+                # touches only its local pool shard
+                q = jax.lax.dynamic_slice_in_dim(q, sidx * nq_l, nq_l,
+                                                 axis=2)
+                k = jax.lax.dynamic_slice_in_dim(k, sidx * nkv_l, nkv_l,
+                                                 axis=2)
+                v = jax.lax.dynamic_slice_in_dim(v, sidx * nkv_l, nkv_l,
+                                                 axis=2)
+        with jax.named_scope("kv_append"):
+            new_c = be.paged_append(
+                (ck, cv), k, v, lnk, lnv, page_table, lengths, may_write)
+        with jax.named_scope("attend"):
+            out = be.paged_attend(
+                q, new_c, lnk, lnv, page_table, lengths + 1)
+            if shard is not None:
+                # device order == head order, so the gathered tensor is
+                # bitwise the unsharded attend's output
+                out = jax.lax.all_gather(out, shard.axis, axis=2,
+                                         tiled=True)
+            out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim
+                              ).astype(carry.dtype)
+        with jax.named_scope("mlp"):
+            h = jnp.einsum("bsk,kd->bsd", out, layer_params["attn"]["wo"])
+            xx = transformer.ffn_residual(
+                layer_params, common.radd(carry, h), cfg, shard=shard)
         return xx, new_c
 
     x, new_kv = common.uscan(
@@ -367,17 +375,21 @@ def decode_step_paged_hybrid(
         h, new_states = common.uscan(mamba_body, carry,
                                      (group_params, gstates))
         b = h.shape[0]
-        q, k, v = attention.project_qkv(
-            shared["attn"],
-            common.rms_norm(h, shared["norm"], cfg.norm_eps),
-            positions, cfg)
-        new_c = backend.paged_append(
-            (ck, cv), k, v, lnk, lnv, page_table, lengths, may_write)
-        out = backend.paged_attend(
-            q, new_c, lnk, lnv, page_table, lengths + 1)
-        out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim
-                          ).astype(h.dtype)
-        a = jnp.einsum("bsk,kd->bsd", out, shared["attn"]["wo"])
+        with jax.named_scope("qkv"):
+            q, k, v = attention.project_qkv(
+                shared["attn"],
+                common.rms_norm(h, shared["norm"], cfg.norm_eps),
+                positions, cfg)
+        with jax.named_scope("kv_append"):
+            new_c = backend.paged_append(
+                (ck, cv), k, v, lnk, lnv, page_table, lengths, may_write)
+        with jax.named_scope("attend"):
+            out = backend.paged_attend(
+                q, new_c, lnk, lnv, page_table, lengths + 1)
+            out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim
+                              ).astype(h.dtype)
+        with jax.named_scope("mlp"):
+            a = jnp.einsum("bsk,kd->bsd", out, shared["attn"]["wo"])
         return common.radd(h, a), (new_c, new_states)
 
     x, (new_kv, new_states) = common.uscan(
@@ -442,31 +454,41 @@ def decode_step_paged_tiered(
     def body(carry, xs):
         (layer_params, ck1, cv1, lnk1, lnv1, ck2, cv2, lnk2, lnv2) = xs
         b = carry.shape[0]
-        q, k, v = attention.project_qkv(
-            layer_params["attn"],
-            common.rms_norm(carry, layer_params["norm1"], cfg.norm_eps),
-            positions, cfg)
-        if shard is not None:
-            q = jax.lax.dynamic_slice_in_dim(q, sidx * nq_l, nq_l, axis=2)
-            k = jax.lax.dynamic_slice_in_dim(k, sidx * nkv_l, nkv_l, axis=2)
-            v = jax.lax.dynamic_slice_in_dim(v, sidx * nkv_l, nkv_l, axis=2)
-        new_c1 = be1.paged_append(
-            (ck1, cv1), k, v, lnk1, lnv1, cache1.page_table, lengths, w1)
-        new_c2 = be2.paged_append(
-            (ck2, cv2), k, v, lnk2, lnv2, cache2.page_table, lengths, w2)
-        out1 = be1.paged_attend(
-            q, new_c1, lnk1, lnv1, cache1.page_table, lengths + 1)
-        out2 = be2.paged_attend(
-            q, new_c2, lnk2, lnv2, cache2.page_table, lengths + 1)
-        # select per slot locally, then gather heads once
-        out = jnp.where(tier2[:, None, None, None], out2, out1)
-        if shard is not None:
-            out = jax.lax.all_gather(out, shard.axis, axis=2, tiled=True)
-        out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim
-                          ).astype(carry.dtype)
-        h = jnp.einsum("bsk,kd->bsd", out, layer_params["attn"]["wo"])
-        xx = transformer.ffn_residual(layer_params, common.radd(carry, h),
-                                      cfg, shard=shard)
+        with jax.named_scope("qkv"):
+            q, k, v = attention.project_qkv(
+                layer_params["attn"],
+                common.rms_norm(carry, layer_params["norm1"], cfg.norm_eps),
+                positions, cfg)
+            if shard is not None:
+                q = jax.lax.dynamic_slice_in_dim(q, sidx * nq_l, nq_l,
+                                                 axis=2)
+                k = jax.lax.dynamic_slice_in_dim(k, sidx * nkv_l, nkv_l,
+                                                 axis=2)
+                v = jax.lax.dynamic_slice_in_dim(v, sidx * nkv_l, nkv_l,
+                                                 axis=2)
+        with jax.named_scope("kv_append"):
+            new_c1 = be1.paged_append(
+                (ck1, cv1), k, v, lnk1, lnv1, cache1.page_table, lengths,
+                w1)
+            new_c2 = be2.paged_append(
+                (ck2, cv2), k, v, lnk2, lnv2, cache2.page_table, lengths,
+                w2)
+        with jax.named_scope("attend"):
+            out1 = be1.paged_attend(
+                q, new_c1, lnk1, lnv1, cache1.page_table, lengths + 1)
+            out2 = be2.paged_attend(
+                q, new_c2, lnk2, lnv2, cache2.page_table, lengths + 1)
+            # select per slot locally, then gather heads once
+            out = jnp.where(tier2[:, None, None, None], out2, out1)
+            if shard is not None:
+                out = jax.lax.all_gather(out, shard.axis, axis=2,
+                                         tiled=True)
+            out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim
+                              ).astype(carry.dtype)
+        with jax.named_scope("mlp"):
+            h = jnp.einsum("bsk,kd->bsd", out, layer_params["attn"]["wo"])
+            xx = transformer.ffn_residual(
+                layer_params, common.radd(carry, h), cfg, shard=shard)
         return xx, (new_c1, new_c2)
 
     x, (new_kv1, new_kv2) = common.uscan(
@@ -541,25 +563,33 @@ def verify_step_paged(
 
     def body(carry, xs):
         layer_params, ck, cv, lnk, lnv = xs
-        q, k, v = attention.project_qkv(
-            layer_params["attn"],
-            common.rms_norm(carry, layer_params["norm1"], cfg.norm_eps),
-            positions, cfg)
-        if shard is not None:
-            q = jax.lax.dynamic_slice_in_dim(q, sidx * nq_l, nq_l, axis=2)
-            k = jax.lax.dynamic_slice_in_dim(k, sidx * nkv_l, nkv_l, axis=2)
-            v = jax.lax.dynamic_slice_in_dim(v, sidx * nkv_l, nkv_l, axis=2)
-        new_c = be.paged_append_multi(
-            (ck, cv), k, v, lnk, lnv, page_table, lengths, valid)
-        out = be.paged_attend_multi(
-            q, new_c, lnk, lnv, page_table, lengths)
-        if shard is not None:
-            out = jax.lax.all_gather(out, shard.axis, axis=2, tiled=True)
-        out = out.reshape(b, q_len, cfg.num_heads * cfg.head_dim
-                          ).astype(carry.dtype)
-        h = jnp.einsum("bsk,kd->bsd", out, layer_params["attn"]["wo"])
-        xx = transformer.ffn_residual(layer_params, common.radd(carry, h),
-                                      cfg, shard=shard)
+        with jax.named_scope("qkv"):
+            q, k, v = attention.project_qkv(
+                layer_params["attn"],
+                common.rms_norm(carry, layer_params["norm1"], cfg.norm_eps),
+                positions, cfg)
+            if shard is not None:
+                q = jax.lax.dynamic_slice_in_dim(q, sidx * nq_l, nq_l,
+                                                 axis=2)
+                k = jax.lax.dynamic_slice_in_dim(k, sidx * nkv_l, nkv_l,
+                                                 axis=2)
+                v = jax.lax.dynamic_slice_in_dim(v, sidx * nkv_l, nkv_l,
+                                                 axis=2)
+        with jax.named_scope("kv_append"):
+            new_c = be.paged_append_multi(
+                (ck, cv), k, v, lnk, lnv, page_table, lengths, valid)
+        with jax.named_scope("attend"):
+            out = be.paged_attend_multi(
+                q, new_c, lnk, lnv, page_table, lengths)
+            if shard is not None:
+                out = jax.lax.all_gather(out, shard.axis, axis=2,
+                                         tiled=True)
+            out = out.reshape(b, q_len, cfg.num_heads * cfg.head_dim
+                              ).astype(carry.dtype)
+        with jax.named_scope("mlp"):
+            h = jnp.einsum("bsk,kd->bsd", out, layer_params["attn"]["wo"])
+            xx = transformer.ffn_residual(
+                layer_params, common.radd(carry, h), cfg, shard=shard)
         return xx, new_c
 
     x, new_kv = common.uscan(

@@ -30,6 +30,7 @@ from repro.configs.base import ModelConfig
 from repro.models import transformer
 from repro.serving import decode as decoding
 from repro.serving.backends import AttentionBackend
+from repro.serving.telemetry import NULL_TRACER
 
 NEG_INF = -1e30
 
@@ -56,6 +57,7 @@ class GenerationResult(NamedTuple):
     cache: object  # final cache (compression reporting)
 
 
+@jax.named_scope("sample")
 def sample_tokens(rng: jax.Array, logits: jax.Array,
                   sc: SamplingConfig) -> jax.Array:
     """(B, V) logits -> (B,) sampled token ids."""
@@ -101,7 +103,7 @@ def _build_generate(cfg: ModelConfig, backend: AttentionBackend,
     if cfg.family == "encoder":
         raise ValueError("encoder-only families do not generate")
 
-    def run(params, prompts, prompt_lengths, rng):
+    def generate_batch(params, prompts, prompt_lengths, rng):
         b, s_max = prompts.shape
         total = s_max + max_new_tokens
         pre = transformer.forward_prefill(
@@ -148,7 +150,7 @@ def _build_generate(cfg: ModelConfig, backend: AttentionBackend,
                             jnp.minimum(final.step, max_new_tokens))
         return final.out, num, final.step, final.state.cache
 
-    return jax.jit(run)
+    return jax.jit(generate_batch)
 
 
 def generate(
@@ -229,16 +231,16 @@ def generate(
     fn = _build_generate(cfg, backend, sampling, int(max_new_tokens),
                          None if eos_id is None else int(eos_id),
                          int(pad_id))
-    t0 = telemetry.tracer.now() if telemetry is not None else 0.0
-    tokens, num, steps, cache = fn(params, prompts, prompt_lengths, rng)
-    if telemetry is not None:
-        n_new = int(jnp.sum(num))
-        telemetry.registry.counter(
-            "generate_calls", help="static-batch generate() calls").inc()
-        telemetry.registry.counter(
-            "generate_tokens", help="tokens emitted by generate()"
-        ).inc(n_new)
-        telemetry.tracer.span("generate", t0, batch=b, width=s_max,
-                              steps=int(steps), tokens=n_new)
+    tracer = telemetry.tracer if telemetry is not None else NULL_TRACER
+    with tracer.region("generate", batch=b, width=s_max) as span:
+        tokens, num, steps, cache = fn(params, prompts, prompt_lengths, rng)
+        if telemetry is not None:
+            n_new = int(jnp.sum(num))
+            telemetry.registry.counter(
+                "generate_calls", help="static-batch generate() calls").inc()
+            telemetry.registry.counter(
+                "generate_tokens", help="tokens emitted by generate()"
+            ).inc(n_new)
+            span.set(steps=int(steps), tokens=n_new)
     return GenerationResult(tokens=tokens, num_generated=num, steps=steps,
                             cache=cache)

@@ -274,7 +274,8 @@ class SchedulerConfig:
     debug_conservation: bool = False
     max_wall_s: Optional[float] = None
     # --- observability (ISSUE 8) ----------------------------------------
-    # telemetry: gates the structured event TRACER (serving/telemetry.py).
+    # telemetry: gates the tracer's event RING (serving/telemetry.py).
+    #           Spans reach a running jax.profiler session either way.
     #           Metrics (counters/gauges/histograms) stay on either way —
     #           they are the same host-side arithmetic the stats dicts
     #           always did and never touch device state or rng, so a
@@ -584,8 +585,8 @@ class PagedServingEngine:
         self._exec: dict = {}  # variant key -> AOT-compiled executable
         self._warmed = False
         self._perf = dict(jit_variants_compiled=0, compile_wall_s=0.0,
-                          warmup_wall_s=0.0, host_sync_count=0,
-                          post_warmup_variants=0)
+                          warmup_wall_s=0.0, post_warmup_variants=0)
+        self._wait_s = 0.0  # readback + idle-wait seconds of this tick
 
     # ------------------------------------------------------------ sharding --
     def _make_allocator(self, num_pages: int):
@@ -646,6 +647,11 @@ class PagedServingEngine:
                             "RequestResults"),
             "host_syncs": c("host_syncs", "device->host readbacks on the "
                             "serving hot path"),
+            "decode_bursts": c("decode_bursts", "plain decode-burst "
+                               "dispatches"),
+            "sched_host_s": c("sched_host_seconds", "seconds the host spent "
+                              "in scheduler ticks outside device readbacks "
+                              "and idle waits"),
             # speculative decoding (stats["spec"] views)
             "draft_proposed": c("spec_draft_proposed", "draft tokens fed "
                                 "to verify steps"),
@@ -768,9 +774,9 @@ class PagedServingEngine:
             # selects attention outputs). Signature grows the tier-2 pool
             # / table / mask; everything else (burst while_loop, EOS,
             # sampling) is identical to the single-pool variant below.
-            def run2(params, pk1, pv1, pk2, pv2, table1, table2, tier2,
-                     lengths, active, owned, tokens, remaining, k_steps,
-                     rng):
+            def decode_burst_tiered(params, pk1, pv1, pk2, pv2, table1,
+                                    table2, tier2, lengths, active, owned,
+                                    tokens, remaining, k_steps, rng):
                 out0 = jnp.full((s, max_burst), -1, jnp.int32)
                 emitted0 = jnp.zeros((s,), jnp.int32)
 
@@ -804,12 +810,12 @@ class PagedServingEngine:
                 # pools (both tiers), emitted, out
                 return fin[1], fin[2], fin[3], fin[4], fin[8], fin[9]
 
-            return self._mesh_jit(run2, n_in=15, pool_in={1, 2, 3, 4},
-                                  n_out=6, pool_out={0, 1, 2, 3},
-                                  donate=(1, 2, 3, 4))
+            return self._mesh_jit(decode_burst_tiered, n_in=15,
+                                  pool_in={1, 2, 3, 4}, n_out=6,
+                                  pool_out={0, 1, 2, 3}, donate=(1, 2, 3, 4))
 
-        def run(params, pool_k, pool_v, page_table, lengths, active, owned,
-                tokens, remaining, k_steps, rng):
+        def decode_burst(params, pool_k, pool_v, page_table, lengths, active,
+                         owned, tokens, remaining, k_steps, rng):
             out0 = jnp.full((s, max_burst), -1, jnp.int32)
             emitted0 = jnp.zeros((s,), jnp.int32)
 
@@ -840,7 +846,7 @@ class PagedServingEngine:
             fin = jax.lax.while_loop(cond, body, init)
             return fin[1], fin[2], fin[6], fin[7]  # pool_k, pool_v, emitted, out
 
-        return self._mesh_jit(run, n_in=11, pool_in={1, 2}, n_out=4,
+        return self._mesh_jit(decode_burst, n_in=11, pool_in={1, 2}, n_out=4,
                               pool_out={0, 1}, donate=(1, 2))
 
     def _build_decode_state(self):
@@ -865,8 +871,9 @@ class PagedServingEngine:
         store = self.store
 
         if self.family.paged_kv:  # hybrid: pages + state slots per tick
-            def run(params, pool_k, pool_v, page_table, lengths, active,
-                    tokens, remaining, k_steps, rng, packed):
+            def state_decode_burst(params, pool_k, pool_v, page_table,
+                                   lengths, active, tokens, remaining,
+                                   k_steps, rng, packed):
                 states0 = store.decode(packed)
                 out0 = jnp.full((s, max_burst), -1, jnp.int32)
                 emitted0 = jnp.zeros((s,), jnp.int32)
@@ -905,9 +912,10 @@ class PagedServingEngine:
                 # pool_k, pool_v, emitted, out, packed
                 return fin[1], fin[2], fin[7], fin[8], new_packed
 
-            return jax.jit(run, donate_argnums=(1, 2, 10))
+            return jax.jit(state_decode_burst, donate_argnums=(1, 2, 10))
 
-        def run(params, active, tokens, remaining, k_steps, rng, packed):
+        def state_decode_burst(params, active, tokens, remaining, k_steps,
+                               rng, packed):
             states0 = store.decode(packed)
             out0 = jnp.full((s, max_burst), -1, jnp.int32)
             emitted0 = jnp.zeros((s,), jnp.int32)
@@ -941,7 +949,7 @@ class PagedServingEngine:
             new_packed = store.merge(store.encode(fin[2]), packed, active)
             return fin[4], fin[5], new_packed  # emitted, out, packed
 
-        return jax.jit(run, donate_argnums=(6,))
+        return jax.jit(state_decode_burst, donate_argnums=(6,))
 
     def _state_width(self, plen: int) -> int:
         """Pow-2 prompt-width bucket for a state-prefill dispatch."""
@@ -975,8 +983,8 @@ class PagedServingEngine:
         store = self.store
 
         if self.family.paged_kv:  # hybrid
-            def run(params, tokens, slot, plen, pool_k, pool_v,
-                    page_table, lengths, packed, rng):
+            def state_prefill(params, tokens, slot, plen, pool_k, pool_v,
+                              page_table, lengths, packed, rng):
                 onehot = jnp.arange(s) == slot
                 # slot reuse: the packed bytes still hold the PREVIOUS
                 # owner's final state — select the initial state for the
@@ -1011,11 +1019,11 @@ class PagedServingEngine:
                                          onehot)
                 return first, pk, pv, new_packed
 
-            fn = jax.jit(run, donate_argnums=(4, 5, 8))
+            fn = jax.jit(state_prefill, donate_argnums=(4, 5, 8))
         else:
-            def run(params, tokens, slot, plen, packed, rng):
+            def state_prefill(params, tokens, slot, plen, packed, rng):
                 onehot = jnp.arange(s) == slot
-                # reused slot: reset to the initial state (see hybrid run)
+                # reused slot: reset to the initial state (see the hybrid one)
                 states0 = decoding.mask_states(
                     cfg, onehot, store.init_states(), store.decode(packed))
                 last0 = jnp.zeros((cfg.vocab_size,), jnp.float32)
@@ -1044,7 +1052,7 @@ class PagedServingEngine:
                                          onehot)
                 return first, new_packed
 
-            fn = jax.jit(run, donate_argnums=(4,))
+            fn = jax.jit(state_prefill, donate_argnums=(4,))
         self._sprefill_fns[width] = fn
         return key, fn
 
@@ -1067,8 +1075,8 @@ class PagedServingEngine:
         eos = self.sched.eos_id
         shard = self._shard
 
-        def run(params, pool_k, pool_v, page_table, lengths, active, owned,
-                fed, n_fed):
+        def verify(params, pool_k, pool_v, page_table, lengths, active, owned,
+                   fed, n_fed):
             cache = pages_lib.PagedKVCache(pool_k, pool_v, page_table,
                                            lengths)
             logits, new_cache = decoding.verify_step_paged(
@@ -1080,7 +1088,7 @@ class PagedServingEngine:
             emit = jnp.where(active, jnp.minimum(emit, n_fed), 0)
             return new_cache.k, new_cache.v, targets, emit
 
-        return self._mesh_jit(run, n_in=9, pool_in={1, 2}, n_out=4,
+        return self._mesh_jit(verify, n_in=9, pool_in={1, 2}, n_out=4,
                               pool_out={0, 1}, donate=(1, 2))
 
     def _build_spec(self):
@@ -1124,8 +1132,8 @@ class PagedServingEngine:
         rows = jnp.arange(s)
         shard = self._shard
 
-        def run(params, pool_k, pool_v, page_table, lengths, active, owned,
-                ctx, ctx_len, remaining, k_rounds):
+        def spec_burst(params, pool_k, pool_v, page_table, lengths, active,
+                       owned, ctx, ctx_len, remaining, k_rounds):
             out0 = jnp.full((s, out_w), -1, jnp.int32)
             zeros = jnp.zeros((s,), jnp.int32)
 
@@ -1223,7 +1231,7 @@ class PagedServingEngine:
             return (fin[1], fin[2], fin[8], fin[9], fin[10], fin[11],
                     fin[12])
 
-        return self._mesh_jit(run, n_in=11, pool_in={1, 2}, n_out=7,
+        return self._mesh_jit(spec_burst, n_in=11, pool_in={1, 2}, n_out=7,
                               pool_out={0, 1}, donate=(1, 2))
 
     def warmup(self, skips=(0,)) -> dict:
@@ -1241,10 +1249,11 @@ class PagedServingEngine:
         was not warmed (mesh engines warm their lazy jit cache instead)."""
         return self._exec[key]
 
-    def _dispatch(self, key: tuple, fn, *args):
+    def _dispatch(self, key: tuple, fn, *args, tid: int = 0):
         """Route a device dispatch through the jit-variant table.
 
         `key` names the compiled variant ((kind, *static bucket values));
+        its kind names the `dispatch` span (on slot lane `tid`).
         AOT-warmed executables (`serving/compile_cache.py`) are preferred
         over the lazy jit path, first-seen keys are counted into
         stats["perf"]["jit_variants_compiled"], and keys first seen after
@@ -1252,14 +1261,32 @@ class PagedServingEngine:
         perf-smoke CI job asserts stays zero.
         """
         self._last_dispatch_key = key  # watchdog diagnostic breadcrumb
-        args = self._on_mesh(args)
-        if key not in self._compiled_keys:
-            self._compiled_keys.add(key)
-            self._perf["jit_variants_compiled"] += 1
-            if self._warmed:
-                self._perf["post_warmup_variants"] += 1
-        ex = self._exec.get(key)
-        return fn(*args) if ex is None else ex(*args)
+        with self._tracer.region("dispatch", tid, kind=key[0]):
+            args = self._on_mesh(args)
+            if key not in self._compiled_keys:
+                self._compiled_keys.add(key)
+                self._perf["jit_variants_compiled"] += 1
+                if self._warmed:
+                    self._perf["post_warmup_variants"] += 1
+            ex = self._exec.get(key)
+            return fn(*args) if ex is None else ex(*args)
+
+    def _split(self, rng: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """(rng, sub): one split of the engine's key, a device dispatch of
+        its own."""
+        with self._tracer.region("dispatch", kind="rng_split"):
+            rng, sub = jax.random.split(rng)
+        return rng, sub
+
+    def _readback(self, *arrays, tid: int = 0, **args) -> list:
+        """Copy a dispatch's outputs to the host: where the host waits for
+        the device, and the one host sync a dispatch costs (counted
+        here)."""
+        with self._tracer.region("readback", tid, **args) as r:
+            out = [np.asarray(a) for a in arrays]
+        self._m["host_syncs"].inc()
+        self._wait_s += r.elapsed
+        return out
 
     def _on_mesh(self, args):
         """With a mesh, commit every array argument that is not already
@@ -1348,47 +1375,58 @@ class PagedServingEngine:
         s = self.sched.num_slots
         ps = self.sched.page_size
         q_len = self.sched.draft_len + 1
-        t_span = self._tracer.now()
-        fed = np.zeros((s, q_len), np.int32)
-        n_fed = np.ones((s,), np.int32)
-        for i in range(s):
-            if not self.active[i]:
-                continue
-            st = self.slots[i]
-            ctx = np.concatenate([st.req.tokens,
-                                  np.asarray(st.generated, np.int32)])
-            draft = speculate_lib.propose_draft(
-                ctx, min(self.sched.draft_len, int(remaining[i]) - 1),
-                self.sched.draft_max_ngram, tracer=self._tracer)
-            m = 1 + len(draft)
-            fed[i, 0] = self.next_tok[i]
-            fed[i, 1:m] = draft
-            n_fed[i] = m
-            st.draft_proposed += m - 1
-            st.verify_steps += 1
-            self._m["draft_proposed"].inc(m - 1)
-            self._m["verify_steps"].inc()
-        # jit-variant discipline (see kernels/qattn: verify_rows): the
-        # dispatch shape is the STATIC q_len — acceptance counts and short
-        # drafts ride in n_fed — and the page table is sliced to the same
-        # pow-2 live-width buckets plain bursts use, so verify compiles
-        # O(log max_pages) variants total, never one per acceptance count.
-        assert fed.shape == (s, q_len)
-        mp = self._live_table_width(q_len)
-        assert mp & (mp - 1) == 0 or mp == self.sched.max_pages
-        owned = self._owned_write_mask(n_fed)
-        pk, pv, targets, emit = self._dispatch(
-            ("verify", mp), self._verify_fn,
-            self.params, self.pool.k, self.pool.v,
-            np.asarray(self.page_table[:, :mp]),
-            np.asarray(self.lengths), np.asarray(self.active),
-            np.asarray(owned), np.asarray(fed), np.asarray(n_fed))
-        self.pool = self.pool._replace(k=pk, v=pv)
-        targets = np.asarray(targets)
-        emit = np.asarray(emit)
-        self._perf["host_sync_count"] += 1
-        self._m["host_syncs"].inc()
-        t_now = time.perf_counter() - self._t0
+        with self._tracer.region("spec-round", tick=self._tick,
+                                 rounds=1) as span:
+            fed = np.zeros((s, q_len), np.int32)
+            n_fed = np.ones((s,), np.int32)
+            for i in range(s):
+                if not self.active[i]:
+                    continue
+                st = self.slots[i]
+                ctx = np.concatenate([st.req.tokens,
+                                      np.asarray(st.generated, np.int32)])
+                draft = speculate_lib.propose_draft(
+                    ctx, min(self.sched.draft_len, int(remaining[i]) - 1),
+                    self.sched.draft_max_ngram, tracer=self._tracer)
+                m = 1 + len(draft)
+                fed[i, 0] = self.next_tok[i]
+                fed[i, 1:m] = draft
+                n_fed[i] = m
+                st.draft_proposed += m - 1
+                st.verify_steps += 1
+                self._m["draft_proposed"].inc(m - 1)
+                self._m["verify_steps"].inc()
+            # jit-variant discipline (see kernels/qattn: verify_rows): the
+            # dispatch shape is the STATIC q_len — acceptance counts and
+            # short drafts ride in n_fed — and the page table is sliced to
+            # the same pow-2 live-width buckets plain bursts use, so verify
+            # compiles O(log max_pages) variants total, never one per
+            # acceptance count.
+            assert fed.shape == (s, q_len)
+            mp = self._live_table_width(q_len)
+            assert mp & (mp - 1) == 0 or mp == self.sched.max_pages
+            owned = self._owned_write_mask(n_fed)
+            pk, pv, targets, emit = self._dispatch(
+                ("verify", mp), self._verify_fn,
+                self.params, self.pool.k, self.pool.v,
+                np.asarray(self.page_table[:, :mp]),
+                np.asarray(self.lengths), np.asarray(self.active),
+                np.asarray(owned), np.asarray(fed), np.asarray(n_fed))
+            self.pool = self.pool._replace(k=pk, v=pv)
+            targets, emit = self._readback(targets, emit)
+            t_now = time.perf_counter() - self._t0
+            with self._tracer.region("emit"):
+                self._commit_verify(targets, emit, n_fed, results, t_now)
+            span.set(proposed=int(n_fed.sum() - s),
+                     accepted=int(emit.sum()))
+
+    def _commit_verify(self, targets: np.ndarray, emit: np.ndarray,
+                       n_fed: np.ndarray, results: list,
+                       t_now: float) -> None:
+        """Host commit of one verify round: each slot's accepted tokens go
+        to its stream and the rejected suffix is popped."""
+        s = self.sched.num_slots
+        ps = self.sched.page_size
         # mid-verify cancellation window: cancels injected between the
         # verify dispatch and this host commit land HERE — the cancelled
         # slot's speculative tail is popped through the validated
@@ -1431,9 +1469,6 @@ class PagedServingEngine:
                 self._evict(i, results, t_now)
             elif cancelled:
                 self._evict(i, results, t_now, status="cancelled")
-        self._tracer.span(
-            "spec-round", t_span, tick=self._tick, rounds=1,
-            proposed=int(n_fed.sum() - s), accepted=int(emit.sum()))
 
     def _spec_burst(self, remaining: np.ndarray, results: list,
                     queued: bool = False) -> int:
@@ -1450,80 +1485,76 @@ class PagedServingEngine:
         """
         s = self.sched.num_slots
         q_len = self.sched.draft_len + 1
-        t_span = self._tracer.now()
-        rem_act = remaining[self.active]
-        rem_max = int(rem_act.max())
-        mp = self._live_table_width(rem_max + q_len)
-        owned = self._owned_write_mask(remaining)
-        if queued:
-            # requests are waiting for a slot: burst only as far as the
-            # fastest any active slot can finish (a round emits at most
-            # q_len tokens, so that is ceil(remaining / q_len) rounds) —
-            # past that, a fully-accepting slot would sit frozen in-burst
-            # while the queue waits at the host. The floor of 4 keeps the
-            # per-dispatch launch overhead amortized over >= 4 rounds.
-            k_rounds = max(4, min(self.sched.max_burst,
-                                  int((-(-rem_act // q_len)).min())))
-        else:
-            # empty queue: a freed slot has nothing to take anyway, and
-            # the device loop exits early once every slot is done — so
-            # burst long and amortize the dispatch launch cost
-            k_rounds = min(self.sched.max_burst, rem_max)
-        pk, pv, emitted, out, n_prop, n_acc, n_steps = self._dispatch(
-            ("spec", mp), self._spec_fn,
-            self.params, self.pool.k, self.pool.v,
-            np.asarray(self.page_table[:, :mp]), np.asarray(self.lengths),
-            np.asarray(self.active), np.asarray(owned),
-            np.asarray(self.ctx_buf), np.asarray(self.ctx_len),
-            np.asarray(remaining), np.asarray(k_rounds, jnp.int32))
-        self.pool = self.pool._replace(k=pk, v=pv)
-        emitted = np.asarray(emitted)
-        out = np.asarray(out)
-        n_prop, n_acc, n_steps = (np.asarray(a) for a in
-                                  (n_prop, n_acc, n_steps))
-        self._perf["host_sync_count"] += 1
-        self._m["host_syncs"].inc()
-        t_now = time.perf_counter() - self._t0
-        for i in range(s):
-            if not self.active[i] or emitted[i] == 0:
-                continue
-            st = self.slots[i]
-            n = int(emitted[i])
-            toks = out[i, :n]
-            st.generated.extend(int(t) for t in toks)
-            st.draft_proposed += int(n_prop[i])
-            st.draft_accepted += int(n_acc[i])
-            st.verify_steps += int(n_steps[i])
-            st.host_syncs += 1
-            self._m["draft_proposed"].inc(int(n_prop[i]))
-            self._m["draft_accepted"].inc(int(n_acc[i]))
-            self._m["verify_steps"].inc(int(n_steps[i]))
-            if self.on_tokens is not None:
-                self.on_tokens(st.req.rid, [int(t) for t in toks])
-            self.next_tok[i] = int(toks[-1])
-            self.lengths[i] += n
-            cl = int(self.ctx_len[i])
-            self.ctx_buf[i, cl:cl + n] = toks
-            self.ctx_len[i] = cl + n
-            if self._finished(st):
-                self._evict(i, results, t_now)
-        # mid-verify cancellation window: cancels injected while the fused
-        # burst ran on device land here. No pop dispatch is needed — the
-        # device committed only accepted tokens; eviction reconciles the
-        # page references wholesale, same tick.
-        if self._faults is not None:
-            for rid in self._faults.mid_burst_cancels():
-                self.cancel(rid)
-        if self._cancel_req:
-            for i in range(s):
-                if (self.active[i]
-                        and self.slots[i].req.rid in self._cancel_req):
-                    self._evict(i, results, t_now, status="cancelled")
-        rounds = int(n_steps.max(initial=0))
-        self._tracer.span(
-            "spec-round", t_span, tick=self._tick, rounds=rounds,
-            width=mp, proposed=int(n_prop.sum()),
-            accepted=int(n_acc.sum()), emitted=int(emitted.sum()))
+        with self._tracer.region("spec-round", tick=self._tick) as span:
+            rem_act = remaining[self.active]
+            rem_max = int(rem_act.max())
+            mp = self._live_table_width(rem_max + q_len)
+            owned = self._owned_write_mask(remaining)
+            if queued:
+                # requests are waiting for a slot: burst only as far as the
+                # fastest any active slot can finish (a round emits at most
+                # q_len tokens, so that is ceil(remaining / q_len) rounds) —
+                # past that, a fully-accepting slot would sit frozen in-burst
+                # while the queue waits at the host. The floor of 4 keeps the
+                # per-dispatch launch overhead amortized over >= 4 rounds.
+                k_rounds = max(4, min(self.sched.max_burst,
+                                      int((-(-rem_act // q_len)).min())))
+            else:
+                # empty queue: a freed slot has nothing to take anyway, and
+                # the device loop exits early once every slot is done — so
+                # burst long and amortize the dispatch launch cost
+                k_rounds = min(self.sched.max_burst, rem_max)
+            pk, pv, emitted, out, n_prop, n_acc, n_steps = self._dispatch(
+                ("spec", mp), self._spec_fn,
+                self.params, self.pool.k, self.pool.v,
+                np.asarray(self.page_table[:, :mp]), np.asarray(self.lengths),
+                np.asarray(self.active), np.asarray(owned),
+                np.asarray(self.ctx_buf), np.asarray(self.ctx_len),
+                np.asarray(remaining), np.asarray(k_rounds, jnp.int32))
+            self.pool = self.pool._replace(k=pk, v=pv)
+            emitted, out, n_prop, n_acc, n_steps = self._readback(
+                emitted, out, n_prop, n_acc, n_steps)
+            t_now = time.perf_counter() - self._t0
+            with self._tracer.region("emit"):
+                for i in range(s):
+                    if not self.active[i] or emitted[i] == 0:
+                        continue
+                    st = self.slots[i]
+                    n = int(emitted[i])
+                    toks = out[i, :n]
+                    st.generated.extend(int(t) for t in toks)
+                    st.draft_proposed += int(n_prop[i])
+                    st.draft_accepted += int(n_acc[i])
+                    st.verify_steps += int(n_steps[i])
+                    st.host_syncs += 1
+                    self._m["draft_proposed"].inc(int(n_prop[i]))
+                    self._m["draft_accepted"].inc(int(n_acc[i]))
+                    self._m["verify_steps"].inc(int(n_steps[i]))
+                    if self.on_tokens is not None:
+                        self.on_tokens(st.req.rid, [int(t) for t in toks])
+                    self.next_tok[i] = int(toks[-1])
+                    self.lengths[i] += n
+                    cl = int(self.ctx_len[i])
+                    self.ctx_buf[i, cl:cl + n] = toks
+                    self.ctx_len[i] = cl + n
+                    if self._finished(st):
+                        self._evict(i, results, t_now)
+                # mid-verify cancellation window: cancels injected while
+                # the fused burst ran on device land here. No pop dispatch
+                # is needed — the device committed only accepted tokens;
+                # eviction reconciles the page references wholesale, same
+                # tick.
+                if self._faults is not None:
+                    for rid in self._faults.mid_burst_cancels():
+                        self.cancel(rid)
+                if self._cancel_req:
+                    for i in range(s):
+                        if (self.active[i]
+                                and self.slots[i].req.rid in self._cancel_req):
+                            self._evict(i, results, t_now, status="cancelled")
+            rounds = int(n_steps.max(initial=0))
+            span.set(rounds=rounds, width=mp, proposed=int(n_prop.sum()),
+                     accepted=int(n_acc.sum()), emitted=int(emitted.sum()))
         return rounds
 
     def _prefill_fn(self, width: int, skip: int):
@@ -1570,42 +1601,50 @@ class PagedServingEngine:
 
             def body(carry, xs):
                 layer_params, bk, bv, lnk, lnv = xs
-                q, k, v = attention.project_qkv(
-                    layer_params["attn"],
-                    common.rms_norm(carry, layer_params["norm1"],
-                                    cfg.norm_eps),
-                    positions, cfg)
-                bk = jax.lax.dynamic_update_slice_in_dim(
-                    bk, k.astype(bk.dtype), offset, axis=1)
-                bv = jax.lax.dynamic_update_slice_in_dim(
-                    bv, v.astype(bv.dtype), offset, axis=1)
-                out = attention.blockwise_attention(
-                    q, bk, bv, causal=True, q_offset=offset)
-                out = out.reshape(1, chunk, cfg.num_heads * cfg.head_dim)
-                h = jnp.einsum("bsk,kd->bsd", out,
-                               layer_params["attn"]["wo"])
-                xx = transformer.ffn_residual(
-                    layer_params, common.radd(carry, h), cfg)
-                ck = qz.encode(k, lnk, qz.config.k_norm)
-                cv = qz.encode(v, lnv, qz.config.v_norm)
-                if requant:
-                    # cross-chunk attention must see decode(codes), the
-                    # same bits a prefix-sharing run reconstructs from the
-                    # pool — overwrite AFTER this chunk's own attention
+                with jax.named_scope("qkv"):
+                    q, k, v = attention.project_qkv(
+                        layer_params["attn"],
+                        common.rms_norm(carry, layer_params["norm1"],
+                                        cfg.norm_eps),
+                        positions, cfg)
+                with jax.named_scope("attend"):
                     bk = jax.lax.dynamic_update_slice_in_dim(
-                        bk, qz.decode(ck, lnk, qz.config.k_norm
-                                      ).astype(bk.dtype), offset, axis=1)
+                        bk, k.astype(bk.dtype), offset, axis=1)
                     bv = jax.lax.dynamic_update_slice_in_dim(
-                        bv, qz.decode(cv, lnv, qz.config.v_norm
-                                      ).astype(bv.dtype), offset, axis=1)
+                        bv, v.astype(bv.dtype), offset, axis=1)
+                    out = attention.blockwise_attention(
+                        q, bk, bv, causal=True, q_offset=offset)
+                    out = out.reshape(1, chunk,
+                                      cfg.num_heads * cfg.head_dim)
+                with jax.named_scope("mlp"):
+                    h = jnp.einsum("bsk,kd->bsd", out,
+                                   layer_params["attn"]["wo"])
+                    xx = transformer.ffn_residual(
+                        layer_params, common.radd(carry, h), cfg)
+                with jax.named_scope("kv_append"):
+                    ck = qz.encode(k, lnk, qz.config.k_norm)
+                    cv = qz.encode(v, lnv, qz.config.v_norm)
+                    if requant:
+                        # cross-chunk attention must see decode(codes),
+                        # the same bits a prefix-sharing run reconstructs
+                        # from the pool — overwrite AFTER this chunk's own
+                        # attention
+                        bk = jax.lax.dynamic_update_slice_in_dim(
+                            bk, qz.decode(ck, lnk, qz.config.k_norm
+                                          ).astype(bk.dtype), offset,
+                            axis=1)
+                        bv = jax.lax.dynamic_update_slice_in_dim(
+                            bv, qz.decode(cv, lnv, qz.config.v_norm
+                                          ).astype(bv.dtype), offset,
+                            axis=1)
                 return xx, (bk, bv, ck, cv)
 
             x, (nbk, nbv, ck, cv) = common.uscan(
                 body, x, (params["layers"], buf_k, buf_v, nk, nv))
             return x, nbk, nbv, ck, cv
 
-        def run(params, tokens, page_groups, last_chunk, last_off,
-                prefix_k, prefix_v, rng, pool_k, pool_v):
+        def prefill(params, tokens, page_groups, last_chunk, last_off,
+                    prefix_k, prefix_v, rng, pool_k, pool_v):
             # tokens (n_chunks, C) suffix; page_groups (n_chunks, C/ps)
             # SUFFIX page ids (trash page for pow-2 padding chunks);
             # last_chunk: index of the last REAL chunk (the bucket may pad
@@ -1626,21 +1665,22 @@ class PagedServingEngine:
                 tok_c, cidx, ids = xs
                 x, bk, bv, ck, cv = one_chunk(params, tok_c[None], cidx,
                                               bk, bv)
-                ck = jax.tree.map(lambda a: a[:, 0], ck)  # drop batch=1
-                cv = jax.tree.map(lambda a: a[:, 0], cv)
-                if shard is not None:
-                    # prefill compute is replicated; only the pool write
-                    # is sharded — each device scatters its own kv-head
-                    # slice of the chunk codes ((L, C, n_kv, X), head
-                    # axis 2) into its pool shard
-                    nkv_l = cfg.num_kv_heads // shard.size
-                    sidx = jax.lax.axis_index(shard.axis)
-                    cut = lambda a: jax.lax.dynamic_slice_in_dim(
-                        a, sidx * nkv_l, nkv_l, axis=2)
-                    ck = jax.tree.map(cut, ck)
-                    cv = jax.tree.map(cut, cv)
-                pk = pages_lib.write_prompt_pages(pk, ck, ids, ps)
-                pv = pages_lib.write_prompt_pages(pv, cv, ids, ps)
+                with jax.named_scope("kv_append"):
+                    ck = jax.tree.map(lambda a: a[:, 0], ck)  # batch=1
+                    cv = jax.tree.map(lambda a: a[:, 0], cv)
+                    if shard is not None:
+                        # prefill compute is replicated; only the pool
+                        # write is sharded — each device scatters its own
+                        # kv-head slice of the chunk codes ((L, C, n_kv,
+                        # X), head axis 2) into its pool shard
+                        nkv_l = cfg.num_kv_heads // shard.size
+                        sidx = jax.lax.axis_index(shard.axis)
+                        cut = lambda a: jax.lax.dynamic_slice_in_dim(
+                            a, sidx * nkv_l, nkv_l, axis=2)
+                        ck = jax.tree.map(cut, ck)
+                        cv = jax.tree.map(cut, cv)
+                    pk = pages_lib.write_prompt_pages(pk, ck, ids, ps)
+                    pv = pages_lib.write_prompt_pages(pv, cv, ids, ps)
                 return (bk, bv, pk, pv), x
 
             (_, _, pool_k, pool_v), xs = jax.lax.scan(
@@ -1658,7 +1698,7 @@ class PagedServingEngine:
             tok = engine_lib.sample_tokens(rng, logits, sc)
             return tok, pool_k, pool_v
 
-        fn = self._mesh_jit(run, n_in=10, pool_in={8, 9}, n_out=3,
+        fn = self._mesh_jit(prefill, n_in=10, pool_in={8, 9}, n_out=3,
                             pool_out={1, 2}, donate=(8, 9))
         self._prefill_fns[key] = fn
         return fn
@@ -1681,7 +1721,7 @@ class PagedServingEngine:
         dt = jnp.dtype(cfg.compute_dtype)
         shard = self._shard
 
-        def load(page_ids, pool_k, pool_v):
+        def prefix_load(page_ids, pool_k, pool_v):
             def take(pool_a):  # (L, P, n_kv, ps, X) -> (L, 1, n*ps, ...)
                 return pages_lib.pages_to_tokens(pool_a[:, page_ids],
                                                  axis=1)[:, None]
@@ -1704,7 +1744,7 @@ class PagedServingEngine:
                 bv = jax.lax.all_gather(bv, shard.axis, axis=3, tiled=True)
             return bk, bv
 
-        fn = self._mesh_jit(load, n_in=3, pool_in={1, 2}, n_out=2,
+        fn = self._mesh_jit(prefix_load, n_in=3, pool_in={1, 2}, n_out=2,
                             pool_out=set(), donate=())
         self._prefix_load_fns[n_pages] = fn
         return fn
@@ -1795,31 +1835,35 @@ class PagedServingEngine:
         groups = np.zeros((n_chunks, pages_per_chunk), np.int32)
         groups[:n_real] = fresh_ids[:n_real * pages_per_chunk].reshape(
             n_real, pages_per_chunk)
-        t_pfc = self._tracer.now()
+        tid = slot + 1
         if skip:
-            pfx_k, pfx_v = self._dispatch(
-                ("prefix_load", skip // ps),
-                self._prefix_load_fn(skip // ps),
-                np.asarray(shared_ids), self.pool.k, self.pool.v)
-        else:
-            empty = (self.cfg.num_layers, 1, 0, self.cfg.num_kv_heads,
-                     self.cfg.head_dim)
-            pfx_k = pfx_v = jnp.zeros(empty, self.cfg.compute_dtype)
-        tok, pk, pv = self._dispatch(
-            ("prefill", width, skip), self._prefill_fn(width, skip),
-            self.params, np.asarray(pad.reshape(n_chunks, chunk)),
-            np.asarray(groups), np.asarray(last_chunk, jnp.int32),
-            np.asarray(last_off, jnp.int32), pfx_k, pfx_v, rng,
-            self.pool.k, self.pool.v)
-        self.pool = self.pool._replace(k=pk, v=pv)
-        self._m["prefill_chunks"].inc(n_chunks)
-        self._m["prefill_tokens"].inc(width)
-        self._perf["host_sync_count"] += 1  # first-token readback
-        self._m["host_syncs"].inc()
-        first = int(tok[0])
-        self._tracer.span(
-            "prefill-chunk", t_pfc, tid=slot + 1, rid=req.rid,
-            tick=self._tick, chunks=n_chunks, width=width, skip=skip)
+            with self._tracer.region("prefix-load", tid, rid=req.rid,
+                                     pages=skip // ps):
+                pfx_k, pfx_v = self._dispatch(
+                    ("prefix_load", skip // ps),
+                    self._prefix_load_fn(skip // ps),
+                    np.asarray(shared_ids), self.pool.k, self.pool.v,
+                    tid=tid)
+        with self._tracer.region("prefill-chunk", tid, rid=req.rid,
+                                 tick=self._tick, chunks=n_chunks,
+                                 width=width, skip=skip):
+            if not skip:
+                empty = (self.cfg.num_layers, 1, 0, self.cfg.num_kv_heads,
+                         self.cfg.head_dim)
+                with self._tracer.region("dispatch", tid,
+                                         kind="empty_prefix"):
+                    pfx_k = pfx_v = jnp.zeros(empty, self.cfg.compute_dtype)
+            tok, pk, pv = self._dispatch(
+                ("prefill", width, skip), self._prefill_fn(width, skip),
+                self.params, np.asarray(pad.reshape(n_chunks, chunk)),
+                np.asarray(groups), np.asarray(last_chunk, jnp.int32),
+                np.asarray(last_off, jnp.int32), pfx_k, pfx_v, rng,
+                self.pool.k, self.pool.v, tid=tid)
+            self.pool = self.pool._replace(k=pk, v=pv)
+            self._m["prefill_chunks"].inc(n_chunks)
+            self._m["prefill_tokens"].inc(width)
+            (tok,) = self._readback(tok, tid=tid, rid=req.rid)  # 1st token
+            first = int(tok[0])
         page_ids = np.concatenate([shared_ids, fresh_ids]).astype(np.int32)
         row = np.zeros((self.sched.max_pages,), np.int32)
         row[:len(page_ids)] = page_ids
@@ -1854,35 +1898,33 @@ class PagedServingEngine:
         width = self._state_width(plen)
         pad = np.zeros((width,), np.int32)
         pad[:plen] = req.tokens
-        t_pfc = self._tracer.now()
-        t_wall = time.perf_counter()
-        key, fn = self._sprefill_fn(width)
-        if self.family.paged_kv:
-            # pages first: the prefill scan appends through the table
-            row = np.zeros((self.sched.max_pages,), np.int32)
-            row[:len(fresh_ids)] = fresh_ids.astype(np.int32)
-            self.page_table[slot] = row
-            self.lengths[slot] = 0
-            tok, pk, pv, packed = self._dispatch(
-                key, fn, self.params, np.asarray(pad),
-                np.asarray(slot, jnp.int32), np.asarray(plen, jnp.int32),
-                self.pool.k, self.pool.v, np.asarray(self.page_table),
-                np.asarray(self.lengths), self.states, rng)
-            self.pool = self.pool._replace(k=pk, v=pv)
-        else:
-            tok, packed = self._dispatch(
-                key, fn, self.params, np.asarray(pad),
-                np.asarray(slot, jnp.int32), np.asarray(plen, jnp.int32),
-                self.states, rng)
-        self.states = packed
-        self._m["prefill_tokens"].inc(width)
-        self._perf["host_sync_count"] += 1  # first-token readback
-        self._m["host_syncs"].inc()
-        first = int(tok)
-        self._m["state_encode_s"].inc(time.perf_counter() - t_wall)
-        self._tracer.span(
-            "state-prefill", t_pfc, tid=slot + 1, rid=req.rid,
-            tick=self._tick, width=width, plen=plen)
+        tid = slot + 1
+        with self._tracer.region("state-prefill", tid, rid=req.rid,
+                                 tick=self._tick, width=width,
+                                 plen=plen) as span:
+            key, fn = self._sprefill_fn(width)
+            if self.family.paged_kv:
+                # pages first: the prefill scan appends through the table
+                row = np.zeros((self.sched.max_pages,), np.int32)
+                row[:len(fresh_ids)] = fresh_ids.astype(np.int32)
+                self.page_table[slot] = row
+                self.lengths[slot] = 0
+                tok, pk, pv, packed = self._dispatch(
+                    key, fn, self.params, np.asarray(pad),
+                    np.asarray(slot, jnp.int32), np.asarray(plen, jnp.int32),
+                    self.pool.k, self.pool.v, np.asarray(self.page_table),
+                    np.asarray(self.lengths), self.states, rng, tid=tid)
+                self.pool = self.pool._replace(k=pk, v=pv)
+            else:
+                tok, packed = self._dispatch(
+                    key, fn, self.params, np.asarray(pad),
+                    np.asarray(slot, jnp.int32), np.asarray(plen, jnp.int32),
+                    self.states, rng, tid=tid)
+            self.states = packed
+            self._m["prefill_tokens"].inc(width)
+            (tok,) = self._readback(tok, tid=tid, rid=req.rid)  # 1st token
+            first = int(tok)
+        self._m["state_encode_s"].inc(span.elapsed)
         self.state_slots.claim(slot, req.rid)
         self.lengths[slot] = plen if self.family.paged_kv else 0
         self.active[slot] = True
@@ -2118,64 +2160,63 @@ class PagedServingEngine:
         until `_try_restore` resumes it bit-for-bit."""
         st = self.slots[slot]
         rid = st.req.rid
-        t_span = self._tracer.now()
-        tier2 = bool(self.tier2[slot]) if len(self.tier2) else False
-        alloc = self.allocator2 if tier2 else self.allocator
-        pool = self.pool2 if tier2 else self.pool
-        row = self.page_table2[slot] if tier2 else self.page_table[slot]
-        n_total = int(np.count_nonzero(row))
-        n_data = pages_lib.pages_for_tokens(int(self.lengths[slot]),
-                                            self.sched.page_size)
-        payload = None
-        if pool is not None:
-            payload = spill_lib.spill_pages(pool, row[:n_data],
-                                            tracer=self._tracer)
-        alloc.free(rid)
-        state = None
-        state_bytes = 0
-        if self.store is not None:
-            # the state-slot half of the preemption: snapshot the slot's
-            # PACKED bytes (already quantized — the spill is bit-exact
-            # over the stored representation) and release the slot
-            t_sspan = self._tracer.now()
-            state = self.store.snapshot_slot(self.states, slot)
-            state_bytes = _tree_nbytes(state)
-            self.state_slots.release(rid)
-            self._tracer.span(
-                "state-spill", t_sspan, tid=slot + 1, rid=rid,
-                tick=self._tick, bytes=state_bytes)
-        st.marks.append(("spill", time.perf_counter() - self._t0))
-        sp = spill_lib.SpilledRequest(
-            req=st.req, priority=st.priority, generated=st.generated,
-            next_tok=int(self.next_tok[slot]),
-            length=int(self.lengths[slot]),
-            ctx=self.ctx_buf[slot, :int(self.ctx_len[slot])].copy(),
-            payload=payload, n_pages=n_total, tier2=tier2, state=state,
-            t_admit=st.t_admit, t_first=st.t_first,
-            draft_proposed=st.draft_proposed,
-            draft_accepted=st.draft_accepted,
-            verify_steps=st.verify_steps, host_syncs=st.host_syncs,
-            preemptions=st.preemptions + 1,
-            spill_count=st.preemptions + 1,
-            restore_retries=st.restore_retries, degraded=st.degraded,
-            marks=st.marks)
-        self.page_table[slot] = 0
-        if self.allocator2 is not None:
-            self.page_table2[slot] = 0
-        self.tier2[slot] = False
-        self.lengths[slot] = 0
-        self.active[slot] = False
-        self.next_tok[slot] = 0
-        self.ctx_buf[slot] = 0
-        self.ctx_len[slot] = 0
-        self.slots[slot] = None
-        self._spilled[rid] = sp
-        self._m["spills"].inc()
-        page_bytes = payload.nbytes() if payload is not None else 0
-        self._m["spill_bytes"].inc(page_bytes + state_bytes)
-        self._tracer.span(
-            "spill", t_span, tid=slot + 1, rid=rid, tick=self._tick,
-            pages=n_total, bytes=page_bytes + state_bytes, tier2=tier2)
+        with self._tracer.region("spill", slot + 1, rid=rid,
+                                 tick=self._tick) as span:
+            tier2 = bool(self.tier2[slot]) if len(self.tier2) else False
+            alloc = self.allocator2 if tier2 else self.allocator
+            pool = self.pool2 if tier2 else self.pool
+            row = self.page_table2[slot] if tier2 else self.page_table[slot]
+            n_total = int(np.count_nonzero(row))
+            n_data = pages_lib.pages_for_tokens(int(self.lengths[slot]),
+                                                self.sched.page_size)
+            payload = None
+            if pool is not None:
+                payload = spill_lib.spill_pages(pool, row[:n_data],
+                                                tracer=self._tracer)
+            alloc.free(rid)
+            state = None
+            state_bytes = 0
+            if self.store is not None:
+                # the state-slot half of the preemption: snapshot the slot's
+                # PACKED bytes (already quantized — the spill is bit-exact
+                # over the stored representation) and release the slot
+                with self._tracer.region("state-spill", slot + 1, rid=rid,
+                                         tick=self._tick) as sspan:
+                    state = self.store.snapshot_slot(self.states, slot)
+                    state_bytes = _tree_nbytes(state)
+                    self.state_slots.release(rid)
+                    sspan.set(bytes=state_bytes)
+            st.marks.append(("spill", time.perf_counter() - self._t0))
+            sp = spill_lib.SpilledRequest(
+                req=st.req, priority=st.priority, generated=st.generated,
+                next_tok=int(self.next_tok[slot]),
+                length=int(self.lengths[slot]),
+                ctx=self.ctx_buf[slot, :int(self.ctx_len[slot])].copy(),
+                payload=payload, n_pages=n_total, tier2=tier2, state=state,
+                t_admit=st.t_admit, t_first=st.t_first,
+                draft_proposed=st.draft_proposed,
+                draft_accepted=st.draft_accepted,
+                verify_steps=st.verify_steps, host_syncs=st.host_syncs,
+                preemptions=st.preemptions + 1,
+                spill_count=st.preemptions + 1,
+                restore_retries=st.restore_retries, degraded=st.degraded,
+                marks=st.marks)
+            self.page_table[slot] = 0
+            if self.allocator2 is not None:
+                self.page_table2[slot] = 0
+            self.tier2[slot] = False
+            self.lengths[slot] = 0
+            self.active[slot] = False
+            self.next_tok[slot] = 0
+            self.ctx_buf[slot] = 0
+            self.ctx_len[slot] = 0
+            self.slots[slot] = None
+            self._spilled[rid] = sp
+            self._m["spills"].inc()
+            page_bytes = payload.nbytes() if payload is not None else 0
+            self._m["spill_bytes"].inc(page_bytes + state_bytes)
+            span.set(pages=n_total, bytes=page_bytes + state_bytes,
+                     tier2=tier2)
 
     def _try_restore(self, sp: "spill_lib.SpilledRequest",
                      now: float) -> str:
@@ -2191,9 +2232,22 @@ class PagedServingEngine:
                 if not self.active[i]]
         if not free:
             return "no_slot"
+        slot = free[0]
+        nbytes = (sp.payload.nbytes() if sp.payload is not None
+                  else _tree_nbytes(sp.state))
+        with self._tracer.region(
+                "restore", slot + 1, rid=sp.req.rid, tick=self._tick,
+                pages=sp.n_pages, bytes=nbytes, tier2=sp.tier2) as span:
+            why = self._restore_into(sp, slot, now)
+            span.set(retries=sp.restore_retries, outcome=why)
+        return why
+
+    def _restore_into(self, sp: "spill_lib.SpilledRequest", slot: int,
+                      now: float) -> str:
+        """The allocation retries and uploads of `_try_restore`, into free
+        slot `slot`."""
         alloc = self.allocator2 if sp.tier2 else self.allocator
         faults = self._faults
-        t_span = self._tracer.now()
         delay = faults.take_restore_delay() if faults is not None else 0.0
         if delay > 0:
             time.sleep(delay)
@@ -2229,18 +2283,15 @@ class PagedServingEngine:
                     self.pool = self._commit_pool(spill_lib.restore_pages(
                         self.pool, sp.payload, ids[:n_data],
                         tracer=self._tracer))
-            slot = free[0]
             if sp.state is not None:
                 # upload the slot's packed state bytes back — bit-exact
                 # (the snapshot WAS the stored representation)
-                t_sspan = self._tracer.now()
-                self.states = self.store.write_slot(self.states, slot,
-                                                    sp.state)
-                self.state_slots.claim(slot, sp.req.rid)
-                self._tracer.span(
-                    "state-restore", t_sspan, tid=slot + 1,
-                    rid=sp.req.rid, tick=self._tick,
-                    bytes=_tree_nbytes(sp.state))
+                with self._tracer.region(
+                        "state-restore", slot + 1, rid=sp.req.rid,
+                        tick=self._tick, bytes=_tree_nbytes(sp.state)):
+                    self.states = self.store.write_slot(self.states, slot,
+                                                        sp.state)
+                    self.state_slots.claim(slot, sp.req.rid)
             row = np.zeros((self.sched.max_pages,), np.int32)
             row[:sp.n_pages] = ids
             if sp.tier2:
@@ -2261,12 +2312,6 @@ class PagedServingEngine:
             self.slots[slot] = _Slot.from_spilled(sp)
             del self._spilled[sp.req.rid]
             self._m["restores"].inc()
-            self._tracer.span(
-                "restore", t_span, tid=slot + 1, rid=sp.req.rid,
-                tick=self._tick, pages=sp.n_pages,
-                bytes=(sp.payload.nbytes() if sp.payload is not None
-                       else _tree_nbytes(sp.state)),
-                retries=sp.restore_retries, tier2=sp.tier2)
             return "ok"
         # per-tick retry budget exhausted: re-queue with backoff so the
         # loop never blocks on one unlucky restore
@@ -2287,26 +2332,24 @@ class PagedServingEngine:
             return False
         if self._faults is not None and self._faults.take_alloc_fail():
             return False
-        t_span = self._tracer.now()
-        n_data = pages_lib.pages_for_tokens(int(self.lengths[slot]),
-                                            self.sched.page_size)
-        ids2 = self.allocator2.alloc(n_total, rid)
-        self.pool2 = self._commit_pool(spill_lib.migrate_pages(
-            self.pool, row[:n_data], self.backend.quantizer,
-            self.backend2.quantizer, self.pool2, ids2[:n_data],
-            migrate_fn=self._migrate_fn))
-        self.allocator.free(rid)
-        self.page_table[slot] = 0
-        row2 = np.zeros((self.sched.max_pages,), np.int32)
-        row2[:n_total] = ids2
-        self.page_table2[slot] = row2
-        self.tier2[slot] = True
-        st.degraded = True
-        st.marks.append(("degrade", time.perf_counter() - self._t0))
-        self._m["degraded"].inc()
-        self._tracer.span(
-            "degrade", t_span, tid=slot + 1, rid=rid, tick=self._tick,
-            pages=n_total)
+        with self._tracer.region("degrade", slot + 1, rid=rid,
+                                 tick=self._tick, pages=n_total):
+            n_data = pages_lib.pages_for_tokens(int(self.lengths[slot]),
+                                                self.sched.page_size)
+            ids2 = self.allocator2.alloc(n_total, rid)
+            self.pool2 = self._commit_pool(spill_lib.migrate_pages(
+                self.pool, row[:n_data], self.backend.quantizer,
+                self.backend2.quantizer, self.pool2, ids2[:n_data],
+                migrate_fn=self._migrate_fn))
+            self.allocator.free(rid)
+            self.page_table[slot] = 0
+            row2 = np.zeros((self.sched.max_pages,), np.int32)
+            row2[:n_total] = ids2
+            self.page_table2[slot] = row2
+            self.tier2[slot] = True
+            st.degraded = True
+            st.marks.append(("degrade", time.perf_counter() - self._t0))
+            self._m["degraded"].inc()
         return True
 
     def _pick_victim(self, priority: int,
@@ -2392,16 +2435,15 @@ class PagedServingEngine:
         if self.trie is not None:
             self.trie.record(skip)
         fresh = self.allocator.alloc(n_fresh, req.rid)
-        rng, sub = jax.random.split(rng)
+        rng, sub = self._split(rng)
         slot = free_slots[0]
-        t_pf = time.perf_counter()
-        t_span = self._tracer.now()
-        self._admit(req, slot, shared, fresh, skip, sub, now)
-        self._m["prefill_wall_s"].inc(time.perf_counter() - t_pf)
-        self._tracer.span(
-            "admit", t_span, tid=slot + 1, rid=req.rid, tick=self._tick,
-            prompt_len=len(req.tokens), pages=need,
-            shared_pages=len(shared), skip=skip, priority=req.priority)
+        with self._tracer.region(
+                "admit", slot + 1, rid=req.rid, tick=self._tick,
+                prompt_len=len(req.tokens), pages=need,
+                shared_pages=len(shared), skip=skip,
+                priority=req.priority) as span:
+            self._admit(req, slot, shared, fresh, skip, sub, now)
+        self._m["prefill_wall_s"].inc(span.elapsed)
         st = self.slots[slot]
         if self._finished(st):  # budget 1 or instant EOS
             self._evict(slot, results, time.perf_counter() - self._t0)
@@ -2490,6 +2532,198 @@ class PagedServingEngine:
                 f"{self.sched.max_context} ({self.sched.max_pages} "
                 f"pages)")
 
+    def _serve_tick(self, tick: int, pending: list, results: list,
+                    rng: jax.Array, intake, stop) -> jax.Array:
+        """One tick of `run`: intake, admission and the pressure ladder,
+        then one decode burst or an idle wait. Returns the advanced rng."""
+        faults = self._faults
+        now = time.perf_counter() - self._t0
+        if intake is not None:
+            with self._tracer.region("intake"):
+                fresh = intake()
+                if fresh:
+                    for r in fresh:
+                        try:
+                            self.validate_request(r)
+                        except ValueError:
+                            # an unservable mid-flight submission must
+                            # not kill the serve loop: retire it typed
+                            # (the front-end 400s these before intake,
+                            # so this is defense in depth)
+                            self._emit_unserved(r, results, now, "shed")
+                            self._m["shed"].inc()
+                            continue
+                        # stamp arrival trace-relative: queueing delay
+                        # runs from the tick the scheduler saw it
+                        pending.append(
+                            dataclasses.replace(r, arrival=now))
+                    pending.sort(key=lambda r: (r.arrival, r.rid))
+        self._watchdog(tick, pending)
+        self._refresh_gauges(len(pending))
+        if faults is not None:
+            faults.on_tick(self, tick)
+        if self._cancel_req:
+            self._process_cancels(pending, results, now)
+        # --- admission: priority-ordered + pressure ladder in preempt
+        # mode, legacy FCFS (identical rng order) otherwise
+        if self.sched.preempt:
+            rng = self._admission_preempt(pending, results, now, rng)
+        else:
+            while pending and pending[0].arrival <= now:
+                why, rng = self._try_admit_one(pending[0], pending,
+                                               results, now, rng)
+                if why != "ok":
+                    break  # FCFS head-of-line: wait for an eviction
+        self._shed_expired(pending, results, now)
+        if self.sched.debug_conservation:
+            self._check_conservation()
+        if not self.active.any():
+            self._idle(pending, stop)
+            return rng
+        remaining = np.ones((self.sched.num_slots,), np.int32)
+        for i in range(self.sched.num_slots):
+            if self.active[i]:
+                st = self.slots[i]
+                remaining[i] = (st.req.max_new_tokens
+                                - len(st.generated))
+        if self.sched.speculate:
+            if self.sched.spec_device:
+                # --- fused burst: up to max_burst draft->verify->
+                # accept rounds, ONE dispatch, one host sync
+                self._m["decode_steps"].inc(self._spec_burst(
+                    remaining, results,
+                    queued=bool(pending or self._spilled)))
+            else:
+                # --- host-driven oracle: one round per dispatch
+                self._spec_step(remaining, results)
+                self._m["decode_steps"].inc()
+        else:
+            # --- one decode burst: k fused steps, k = min remaining budget
+            k = int(min(self.sched.max_burst,
+                        remaining[self.active].min()))
+            mp = self._live_table_width(k) if self.family.paged_kv else 0
+            with self._tracer.region("decode-burst", tick=tick, k=k,
+                                     width=mp) as span:
+                rng, emitted = self._decode_burst(k, mp, remaining,
+                                                  results, rng)
+                span.set(emitted=emitted)
+        if self.sched.debug_conservation:
+            self._check_conservation()
+        return rng
+
+    def _idle(self, pending: list, stop) -> None:
+        """Nothing is live: sleep until the next arrival, a restore's
+        backoff, or a streaming server's next intake."""
+        nap = 0.0
+        if pending:  # idle until the next arrival
+            nap = min(pending[0].arrival - (time.perf_counter() - self._t0),
+                      0.01)
+        elif self._spilled:
+            # every live request is spilled and restores are backing off
+            # — yield briefly, then retry
+            nap = 0.001
+        elif stop is not None:
+            # streaming server, nothing to do: idle cheaply until the
+            # next intake or the stop signal
+            nap = 0.002
+        if nap > 0:
+            with self._tracer.region("idle-wait") as span:
+                time.sleep(nap)
+            self._wait_s += span.elapsed
+
+    def _decode_burst(self, k: int, mp: int, remaining: np.ndarray,
+                      results: list, rng: jax.Array) -> tuple[jax.Array,
+                                                             int]:
+        """Dispatch one k-step decode burst over page-table width `mp`,
+        read its tokens back and hand them out. Returns (rng, tokens
+        emitted)."""
+        owned = self._owned_write_mask(k)
+        rng, sub = self._split(rng)
+        if self.family.state_slots:
+            # state-family burst: the packed recurrent-state store
+            # rides the dispatch (decoded once at entry, merged back
+            # at exit); hybrids advance their shared-attention pages
+            # and their state slots in the SAME tick
+            if self.family.paged_kv:
+                pk, pv, emitted, out, packed = self._dispatch(
+                    ("decode", mp), self._decode_fn,
+                    self.params, self.pool.k, self.pool.v,
+                    np.asarray(self.page_table[:, :mp]),
+                    np.asarray(self.lengths),
+                    np.asarray(self.active),
+                    np.asarray(self.next_tok),
+                    np.asarray(remaining),
+                    np.asarray(k, jnp.int32), sub, self.states)
+                self.pool = self.pool._replace(k=pk, v=pv)
+            else:
+                emitted, out, packed = self._dispatch(
+                    ("decode", 0), self._decode_fn,
+                    self.params, np.asarray(self.active),
+                    np.asarray(self.next_tok),
+                    np.asarray(remaining),
+                    np.asarray(k, jnp.int32), sub, self.states)
+            self.states = packed
+        elif self.backend2 is not None:
+            # tiered dispatch: both pools ride the burst; a slot's
+            # pages live in exactly one (tier2 routes)
+            pk, pv, pk2, pv2, emitted, out = self._dispatch(
+                ("decode", mp), self._decode_fn,
+                self.params, self.pool.k, self.pool.v,
+                self.pool2.k, self.pool2.v,
+                np.asarray(self.page_table[:, :mp]),
+                np.asarray(self.page_table2[:, :mp]),
+                np.asarray(self.tier2),
+                np.asarray(self.lengths),
+                np.asarray(self.active), np.asarray(owned),
+                np.asarray(self.next_tok),
+                np.asarray(remaining), np.asarray(k, jnp.int32),
+                sub)
+            self.pool2 = self.pool2._replace(k=pk2, v=pv2)
+            self.pool = self.pool._replace(k=pk, v=pv)
+        else:
+            pk, pv, emitted, out = self._dispatch(
+                ("decode", mp), self._decode_fn,
+                self.params, self.pool.k, self.pool.v,
+                np.asarray(self.page_table[:, :mp]),
+                np.asarray(self.lengths),
+                np.asarray(self.active), np.asarray(owned),
+                np.asarray(self.next_tok),
+                np.asarray(remaining), np.asarray(k, jnp.int32),
+                sub)
+            self.pool = self.pool._replace(k=pk, v=pv)
+        emitted, out = self._readback(emitted, out)
+        self._m["decode_steps"].inc(int(emitted.max(initial=0)))
+        self._m["decode_bursts"].inc()
+        t_now = time.perf_counter() - self._t0
+        with self._tracer.region("emit"):
+            for i in range(self.sched.num_slots):
+                if not self.active[i] or emitted[i] == 0:
+                    continue
+                n = int(emitted[i])
+                self.lengths[i] += n  # each fed token's KV was appended
+                self.next_tok[i] = out[i, n - 1]
+                self.slots[i].generated.extend(int(t) for t in out[i, :n])
+                self.slots[i].host_syncs += 1
+                if self.on_tokens is not None:
+                    self.on_tokens(self.slots[i].req.rid,
+                                   [int(t) for t in out[i, :n]])
+                cl = int(self.ctx_len[i])
+                self.ctx_buf[i, cl:cl + n] = out[i, :n]
+                self.ctx_len[i] = cl + n
+                if self._finished(self.slots[i]):
+                    self._evict(i, results, t_now)
+            # mid-burst cancellation window (plain decode): cancels
+            # injected while the burst ran land here, same tick
+            if self._faults is not None:
+                for rid in self._faults.mid_burst_cancels():
+                    self.cancel(rid)
+            if self._cancel_req:
+                for i in range(self.sched.num_slots):
+                    if (self.active[i]
+                            and self.slots[i].req.rid in self._cancel_req):
+                        self._evict(i, results, t_now, status="cancelled")
+        return rng, int(emitted.sum())
+
     def run(self, requests: list[Request],
             rng: Optional[jax.Array] = None,
             faults=None, *, intake=None,
@@ -2518,7 +2752,9 @@ class PagedServingEngine:
         rid, and an aggregate dict with wall/throughput/latency
         percentiles (over COMPLETED requests), pool accounting, prefill
         work counters (`prefill_chunks`, `prefill_tokens_computed`,
-        `prefill_wall_s`), an `slo` sub-dict (shed/cancelled/spill/
+        `prefill_wall_s`), the plain `decode_bursts` and `sched_host_s`
+        (host seconds of the ticks outside device readbacks and idle
+        waits, from the `tick` spans), an `slo` sub-dict (shed/cancelled/spill/
         restore/degrade counters + per-priority-class latency), in
         prefix-cache "share" mode a `prefix` sub-dict with this run's
         trie hits/misses/hit_tokens/evictions, and with speculation on a
@@ -2568,176 +2804,14 @@ class PagedServingEngine:
                or (stop is not None and not stop())):
             tick += 1
             self._tick = tick
-            now = time.perf_counter() - self._t0
-            if intake is not None:
-                fresh = intake()
-                if fresh:
-                    for r in fresh:
-                        try:
-                            self.validate_request(r)
-                        except ValueError:
-                            # an unservable mid-flight submission must
-                            # not kill the serve loop: retire it typed
-                            # (the front-end 400s these before intake,
-                            # so this is defense in depth)
-                            self._emit_unserved(r, results, now, "shed")
-                            self._m["shed"].inc()
-                            continue
-                        # stamp arrival trace-relative: queueing delay
-                        # runs from the tick the scheduler saw it
-                        pending.append(
-                            dataclasses.replace(r, arrival=now))
-                    pending.sort(key=lambda r: (r.arrival, r.rid))
-            self._watchdog(tick, pending)
-            self._refresh_gauges(len(pending))
-            if faults is not None:
-                faults.on_tick(self, tick)
-            if self._cancel_req:
-                self._process_cancels(pending, results, now)
-            # --- admission: priority-ordered + pressure ladder in preempt
-            # mode, legacy FCFS (identical rng order) otherwise
-            if self.sched.preempt:
-                rng = self._admission_preempt(pending, results, now, rng)
-            else:
-                while pending and pending[0].arrival <= now:
-                    why, rng = self._try_admit_one(pending[0], pending,
-                                                   results, now, rng)
-                    if why != "ok":
-                        break  # FCFS head-of-line: wait for an eviction
-            self._shed_expired(pending, results, now)
-            if self.sched.debug_conservation:
-                self._check_conservation()
-            if not self.active.any():
-                if pending:  # idle until the next arrival
-                    wait = pending[0].arrival - (time.perf_counter()
-                                                 - self._t0)
-                    if wait > 0:
-                        time.sleep(min(wait, 0.01))
-                elif self._spilled:
-                    # every live request is spilled and restores are
-                    # backing off — yield briefly, then retry
-                    time.sleep(0.001)
-                elif stop is not None:
-                    # streaming server, nothing to do: idle cheaply
-                    # until the next intake or the stop signal
-                    time.sleep(0.002)
-                continue
-            remaining = np.ones((self.sched.num_slots,), np.int32)
-            for i in range(self.sched.num_slots):
-                if self.active[i]:
-                    st = self.slots[i]
-                    remaining[i] = (st.req.max_new_tokens
-                                    - len(st.generated))
-            if self.sched.speculate:
-                if self.sched.spec_device:
-                    # --- fused burst: up to max_burst draft->verify->
-                    # accept rounds, ONE dispatch, one host sync
-                    self._m["decode_steps"].inc(self._spec_burst(
-                        remaining, results,
-                        queued=bool(pending or self._spilled)))
-                else:
-                    # --- host-driven oracle: one round per dispatch
-                    self._spec_step(remaining, results)
-                    self._m["decode_steps"].inc()
-                if self.sched.debug_conservation:
-                    self._check_conservation()
-                continue
-            # --- one decode burst: k fused steps, k = min remaining budget
-            k = int(min(self.sched.max_burst,
-                        remaining[self.active].min()))
-            mp = self._live_table_width(k) if self.family.paged_kv else 0
-            owned = self._owned_write_mask(k)
-            t_burst = self._tracer.now()
-            rng, sub = jax.random.split(rng)
-            if self.family.state_slots:
-                # state-family burst: the packed recurrent-state store
-                # rides the dispatch (decoded once at entry, merged back
-                # at exit); hybrids advance their shared-attention pages
-                # and their state slots in the SAME tick
-                if self.family.paged_kv:
-                    pk, pv, emitted, out, packed = self._dispatch(
-                        ("decode", mp), self._decode_fn,
-                        self.params, self.pool.k, self.pool.v,
-                        np.asarray(self.page_table[:, :mp]),
-                        np.asarray(self.lengths),
-                        np.asarray(self.active),
-                        np.asarray(self.next_tok),
-                        np.asarray(remaining),
-                        np.asarray(k, jnp.int32), sub, self.states)
-                    self.pool = self.pool._replace(k=pk, v=pv)
-                else:
-                    emitted, out, packed = self._dispatch(
-                        ("decode", 0), self._decode_fn,
-                        self.params, np.asarray(self.active),
-                        np.asarray(self.next_tok),
-                        np.asarray(remaining),
-                        np.asarray(k, jnp.int32), sub, self.states)
-                self.states = packed
-            elif self.backend2 is not None:
-                # tiered dispatch: both pools ride the burst; a slot's
-                # pages live in exactly one (tier2 routes)
-                pk, pv, pk2, pv2, emitted, out = self._dispatch(
-                    ("decode", mp), self._decode_fn,
-                    self.params, self.pool.k, self.pool.v,
-                    self.pool2.k, self.pool2.v,
-                    np.asarray(self.page_table[:, :mp]),
-                    np.asarray(self.page_table2[:, :mp]),
-                    np.asarray(self.tier2),
-                    np.asarray(self.lengths),
-                    np.asarray(self.active), np.asarray(owned),
-                    np.asarray(self.next_tok),
-                    np.asarray(remaining), np.asarray(k, jnp.int32),
-                    sub)
-                self.pool2 = self.pool2._replace(k=pk2, v=pv2)
-                self.pool = self.pool._replace(k=pk, v=pv)
-            else:
-                pk, pv, emitted, out = self._dispatch(
-                    ("decode", mp), self._decode_fn,
-                    self.params, self.pool.k, self.pool.v,
-                    np.asarray(self.page_table[:, :mp]),
-                    np.asarray(self.lengths),
-                    np.asarray(self.active), np.asarray(owned),
-                    np.asarray(self.next_tok),
-                    np.asarray(remaining), np.asarray(k, jnp.int32),
-                    sub)
-                self.pool = self.pool._replace(k=pk, v=pv)
-            emitted = np.asarray(emitted)
-            out = np.asarray(out)
-            self._perf["host_sync_count"] += 1
-            self._m["host_syncs"].inc()
-            self._m["decode_steps"].inc(int(emitted.max(initial=0)))
-            t_now = time.perf_counter() - self._t0
-            for i in range(self.sched.num_slots):
-                if not self.active[i] or emitted[i] == 0:
-                    continue
-                n = int(emitted[i])
-                self.lengths[i] += n  # each fed token's KV was appended
-                self.next_tok[i] = out[i, n - 1]
-                self.slots[i].generated.extend(int(t) for t in out[i, :n])
-                self.slots[i].host_syncs += 1
-                if self.on_tokens is not None:
-                    self.on_tokens(self.slots[i].req.rid,
-                                   [int(t) for t in out[i, :n]])
-                cl = int(self.ctx_len[i])
-                self.ctx_buf[i, cl:cl + n] = out[i, :n]
-                self.ctx_len[i] = cl + n
-                if self._finished(self.slots[i]):
-                    self._evict(i, results, t_now)
-            self._tracer.span(
-                "decode-burst", t_burst, tick=tick, k=k, width=mp,
-                emitted=int(emitted.sum()))
-            # mid-burst cancellation window (plain decode): cancels
-            # injected while the burst ran land here, same tick
-            if faults is not None:
-                for rid in faults.mid_burst_cancels():
-                    self.cancel(rid)
-            if self._cancel_req:
-                for i in range(self.sched.num_slots):
-                    if (self.active[i]
-                            and self.slots[i].req.rid in self._cancel_req):
-                        self._evict(i, results, t_now, status="cancelled")
-            if self.sched.debug_conservation:
-                self._check_conservation()
+            self._wait_s = 0.0
+            with self._tracer.region("tick", step_num=tick,
+                                     tick=tick) as span:
+                rng = self._serve_tick(tick, pending, results, rng, intake,
+                                       stop)
+            # host time on the path between bursts: the tick less its
+            # waits on the device and for arrivals
+            self._m["sched_host_s"].inc(span.elapsed - self._wait_s)
         wall = time.perf_counter() - self._t0
         if faults is not None:
             faults.finish(self)  # return stolen pages before the audit
@@ -2770,6 +2844,8 @@ class PagedServingEngine:
             "prefill_chunks": int(d.value("prefill_chunks")),
             "prefill_tokens_computed": int(d.value("prefill_tokens")),
             "prefill_wall_s": float(d.value("prefill_wall_s")),
+            "decode_bursts": int(d.value("decode_bursts")),
+            "sched_host_s": float(d.value("sched_host_seconds")),
             "ttft_hist": d.hist("ttft_seconds"),
             "tpot_hist": d.hist("tpot_seconds"),
             "latency_hist": d.hist("request_latency_seconds"),
@@ -2779,7 +2855,9 @@ class PagedServingEngine:
         # dispatch/compile observability: cumulative over the engine's
         # lifetime (compile cost is paid once and amortized across runs —
         # see serving/compile_cache.py and docs/serving.md "Performance")
-        stats["perf"] = dict(self._perf, warmed=self._warmed)
+        stats["perf"] = dict(
+            self._perf, warmed=self._warmed,
+            host_sync_count=int(self._m["host_syncs"].value))
         # family adapter view (serving/families.py): which capability
         # plane served this run, plus state-cache byte accounting for
         # state-slot families

@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serving import telemetry
+
 
 def _pow2_pad_ids(page_ids: np.ndarray) -> np.ndarray:
     """Pad a page-id vector to the next power of two with trash-page 0s."""
@@ -68,23 +70,24 @@ class SpilledPages:
         return int(sum(a.nbytes for a in (*self.k, *self.v)))
 
 
-def spill_pages(pool, page_ids: np.ndarray, tracer=None) -> SpilledPages:
+def spill_pages(pool, page_ids: np.ndarray,
+                tracer: telemetry.Tracer = telemetry.NULL_TRACER
+                ) -> SpilledPages:
     """Device -> host copy of `page_ids` out of a paged pool.
 
     `pool` is any object with QuantizedKV `.k`/`.v` pool trees of arrays
     (L, P, n_kv, page_size, X). Returns the packed payload; the caller
     releases the page references afterwards (the bytes here are a copy,
-    not a view). `tracer` (a telemetry.Tracer) gets a "spill-copy" span
-    covering the device->host transfer."""
-    t0 = tracer.now() if tracer is not None else 0.0
+    not a view). The "spill-copy" span of `tracer` covers the device->host
+    transfer."""
     ids = _pow2_pad_ids(np.asarray(page_ids, np.int32))
-    idx = jnp.asarray(ids)
-    k = jax.tree.map(lambda a: np.asarray(a[:, idx]), pool.k)
-    v = jax.tree.map(lambda a: np.asarray(a[:, idx]), pool.v)
-    out = SpilledPages(k, v, len(page_ids))
-    if tracer is not None:
-        tracer.span("spill-copy", t0, pages=len(page_ids),
-                    bucket=len(ids), bytes=out.nbytes())
+    with tracer.region("spill-copy", pages=len(page_ids),
+                       bucket=len(ids)) as span:
+        idx = jnp.asarray(ids)
+        k = jax.tree.map(lambda a: np.asarray(a[:, idx]), pool.k)
+        v = jax.tree.map(lambda a: np.asarray(a[:, idx]), pool.v)
+        out = SpilledPages(k, v, len(page_ids))
+        span.set(bytes=out.nbytes())
     return out
 
 
@@ -96,28 +99,27 @@ def _upload(pool_a, host_a, ids):
 
 
 def restore_pages(pool, spilled: SpilledPages, new_ids: np.ndarray,
-                  tracer=None):
+                  tracer: telemetry.Tracer = telemetry.NULL_TRACER):
     """Host -> device upload of a spilled payload into freshly allocated
     pages. `new_ids` must have exactly `spilled.n_pages` entries; the ids
     need not match the original ones (pages are position-independent).
     Returns the new pool (buffers donated-in-spirit via jit; the caller
     replaces its pool reference). Padded payload rows scatter into the
     trash page 0 — duplicate trash writes are unordered but the trash
-    page holds no data by contract. `tracer` gets a "restore-copy" span
-    covering the host->device upload."""
+    page holds no data by contract. The "restore-copy" span of `tracer`
+    covers the host->device upload."""
     new_ids = np.asarray(new_ids, np.int32)
     if len(new_ids) != spilled.n_pages:
         raise ValueError(
             f"restore needs {spilled.n_pages} pages, got {len(new_ids)}")
-    t0 = tracer.now() if tracer is not None else 0.0
-    ids = jnp.asarray(_pow2_pad_ids(new_ids))
-    k = jax.tree.map(lambda a, h: _upload(a, jnp.asarray(h), ids),
-                     pool.k, spilled.k)
-    v = jax.tree.map(lambda a, h: _upload(a, jnp.asarray(h), ids),
-                     pool.v, spilled.v)
-    if tracer is not None:
-        tracer.span("restore-copy", t0, pages=spilled.n_pages,
-                    bucket=int(ids.shape[0]), bytes=spilled.nbytes())
+    padded = _pow2_pad_ids(new_ids)
+    with tracer.region("restore-copy", pages=spilled.n_pages,
+                       bucket=len(padded), bytes=spilled.nbytes()):
+        ids = jnp.asarray(padded)
+        k = jax.tree.map(lambda a, h: _upload(a, jnp.asarray(h), ids),
+                         pool.k, spilled.k)
+        v = jax.tree.map(lambda a, h: _upload(a, jnp.asarray(h), ids),
+                         pool.v, spilled.v)
     return pool._replace(k=k, v=v)
 
 
@@ -205,7 +207,7 @@ def make_migrate_fn(qz1, qz2):
     def bc(n):  # (L,) -> (L, 1, 1, 1, 1) broadcast over pool slices
         return jnp.asarray(n).reshape(-1, 1, 1, 1, 1)
 
-    def run(p1k, p1v, ids, p2k, p2v, new_ids):
+    def tier_migrate(p1k, p1v, ids, p2k, p2v, new_ids):
         def requant(pool_a_tree, n1, n2, norm_cfg, dst_tree):
             g = jax.tree.map(lambda a: a[:, ids], pool_a_tree)
             x = qz1.decode(g, n1, norm_cfg)
@@ -218,4 +220,4 @@ def make_migrate_fn(qz1, qz2):
         v2 = requant(p1v, bc(nv1), bc(nv2), qz1.config.v_norm, p2v)
         return k2, v2
 
-    return jax.jit(run, donate_argnums=(3, 4))
+    return jax.jit(tier_migrate, donate_argnums=(3, 4))

@@ -15,19 +15,23 @@ one source of truth those snapshots become views over:
                     is bitwise-identical to an unmetered one by
                     construction (pinned in tests/test_telemetry.py).
 
-  Tracer            a ring-buffered structured event recorder. The
-                    scheduler emits a span per control-plane move (admit,
-                    prefill-chunk, decode-burst, spec-round, spill,
-                    restore, degrade, shed, cancel, watchdog, fault) with
-                    wall time, page/byte deltas, and request ids; the ring
-                    bound (`SchedulerConfig.trace_capacity`) makes the
-                    recorder soak-safe. Export is Chrome/Perfetto
-                    ``trace_event`` JSON (``GET /trace``, or
-                    ``benchmarks/soak.py --trace-out``) — load it at
-                    https://ui.perfetto.dev. Tracing is the part gated by
-                    `Telemetry.enabled`: a disabled tracer's `span`/
-                    `instant` return at the first instruction, so the
-                    hot loop pays one attribute test and nothing else.
+  Tracer            spans with two sinks. Under a profiler session,
+                    `Tracer.region(name, **args)` opens a
+                    `jax.profiler.TraceAnnotation`, so the span lands in
+                    the same `.xplane.pb` as the device ops, on the
+                    profiler's clock (with no session that costs one
+                    check). When `enabled` it also pushes the span into
+                    a ring of host events: the scheduler's tick, intake,
+                    admit, prefix-load, prefill-chunk, decode-burst,
+                    dispatch, readback, emit, idle-wait, spec-round,
+                    spill, restore and degrade spans, plus instants
+                    (shed, cancel, watchdog, fault), with page/byte
+                    deltas and request ids. The ring bound
+                    (`SchedulerConfig.trace_capacity`) makes it
+                    soak-safe. Export is Chrome/Perfetto ``trace_event``
+                    JSON (``GET /trace``, or ``benchmarks/soak.py
+                    --trace-out``) — load it at https://ui.perfetto.dev.
+                    The ring is the part gated by `Telemetry.enabled`.
 
   Telemetry         the facade the engine owns: `.registry` + `.tracer`.
 
@@ -48,6 +52,8 @@ import re
 import threading
 import time
 from typing import Optional
+
+from jax import profiler as jax_profiler
 
 #: default explicit bucket bounds (seconds) for latency-shaped histograms
 #: (TTFT, TPOT, end-to-end). Upper bounds, +Inf implicit.
@@ -317,19 +323,58 @@ class RegistryDelta:
 
 
 # ---------------------------------------------------------------- tracer ----
+class Region:
+    """One open span of `Tracer.region`.
+
+    `set(**args)` adds args known only at exit (such as a burst's emitted
+    count); they reach the ring, not the profiler, whose annotation took
+    its args at entry. `elapsed` is the span's length in seconds once it
+    has closed, measured whether or not the tracer is enabled."""
+
+    __slots__ = ("_tracer", "_name", "_tid", "_args", "_ann", "_t0",
+                 "elapsed")
+
+    def __init__(self, tracer: "Tracer", name: str, tid: int, args: dict,
+                 ann):
+        self._tracer, self._name, self._tid = tracer, name, tid
+        self._args, self._ann = args, ann
+        self._t0 = self.elapsed = 0.0
+
+    def set(self, **args) -> None:
+        self._args.update(args)
+
+    def __enter__(self) -> "Region":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.elapsed = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tr = self._tracer
+        if tr.enabled:
+            tr._push({"name": self._name, "ph": "X",
+                      "ts": self._t0 - tr._epoch, "dur": self.elapsed,
+                      "tid": self._tid, "args": self._args})
+
+
 class Tracer:
-    """Ring-buffered structured event recorder.
+    """Spans on the profiler's clock, plus a ring of host events.
 
-    Events are host dicts: ``{"name", "ph", "ts", "dur", "tid", "args"}``
-    with `ts`/`dur` in SECONDS relative to the tracer's epoch (perf
-    counter at construction; `reset_epoch` realigns — the engine calls it
-    at `run()` entry so trace timestamps read as trace time). The ring
-    (`capacity`) bounds memory for soak-length runs: old events fall off
-    the front, newest always survive — the property the watchdog's
-    flight-recorder dump relies on.
+    `region(...)` is the one span API (see `Region`). Ring events are host
+    dicts: ``{"name", "ph", "ts", "dur", "tid", "args"}`` with `ts`/`dur`
+    in SECONDS relative to the tracer's epoch (perf counter at
+    construction; `reset_epoch` realigns — the engine calls it at `run()`
+    entry so trace timestamps read as trace time). The ring (`capacity`)
+    bounds memory for soak-length runs: old events fall off the front,
+    newest always survive — the property the watchdog's flight-recorder
+    dump relies on.
 
-    Disabled tracers (`enabled=False`) return from `span`/`instant`
-    immediately; instrumentation sites don't need their own guards.
+    A disabled tracer (`enabled=False`) pushes nothing into the ring; its
+    regions still annotate a running profiler session and still time
+    themselves.
     """
 
     def __init__(self, capacity: int = 4096, enabled: bool = True):
@@ -344,25 +389,27 @@ class Tracer:
         self.emitted = 0  # events ever emitted
 
     # -------------------------------------------------------------- record --
-    def now(self) -> float:
-        """Timestamp for a later `span(...)` call. 0.0 when disabled so
-        the disabled path never touches the clock."""
-        return time.perf_counter() if self.enabled else 0.0
-
     def reset_epoch(self) -> None:
         if self.enabled:
             self._epoch = time.perf_counter()
 
-    def span(self, name: str, t_start: float, tid: int = 0,
-             **args) -> None:
-        """Record a complete span from `t_start` (a `now()` result) to
-        the current instant."""
-        if not self.enabled:
-            return
-        t1 = time.perf_counter()
-        self._push({"name": name, "ph": "X",
-                    "ts": t_start - self._epoch, "dur": t1 - t_start,
-                    "tid": tid, "args": args})
+    def region(self, name: str, tid: int = 0, *,
+               step_num: Optional[int] = None, **args) -> Region:
+        """A span around a `with` block. Under a profiler session the
+        profile gets `name` with `args` (a `StepTraceAnnotation` when
+        `step_num` is given); with none, the annotation is skipped after
+        one check. The ring, when enabled, gets the same name and args
+        plus any the block `set`s. Pass args as values, never as
+        pre-formatted strings: the profiler formats them only under a
+        session."""
+        ann = None
+        if jax_profiler.TraceAnnotation.is_enabled():
+            if step_num is None:
+                ann = jax_profiler.TraceAnnotation(name, **args)
+            else:
+                ann = jax_profiler.StepTraceAnnotation(
+                    name, step_num=step_num, **args)
+        return Region(self, name, tid, args, ann)
 
     def instant(self, name: str, tid: int = 0, **args) -> None:
         if not self.enabled:
@@ -396,11 +443,12 @@ class Tracer:
         `displayTimeUnit` and metadata ride along). `ts`/`dur` convert to
         microseconds, the format's unit. Load at https://ui.perfetto.dev
         or chrome://tracing."""
+        ring = self.events()  # a snapshot: the serve loop keeps pushing
         events = [{
             "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
             "ts": 0, "args": {"name": "repro-serving"},
         }]
-        tids = sorted({ev["tid"] for ev in self._ring})
+        tids = sorted({ev["tid"] for ev in ring})
         for tid in tids:
             events.append({
                 "name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
@@ -408,7 +456,7 @@ class Tracer:
                 "args": {"name": ("scheduler" if tid == 0
                                   else f"slot {tid - 1}")},
             })
-        for ev in self._ring:
+        for ev in ring:
             out = {
                 "name": ev["name"], "ph": ev["ph"], "pid": pid,
                 "tid": ev["tid"], "ts": round(ev["ts"] * 1e6, 3),
@@ -431,6 +479,11 @@ class Tracer:
 
     def to_perfetto_json(self, **kw) -> str:
         return json.dumps(self.to_perfetto(**kw))
+
+
+#: a tracer with its ring off, for callers that were given none: its
+#: regions still annotate a profiler session
+NULL_TRACER = Tracer(capacity=16, enabled=False)
 
 
 def validate_trace(doc: dict) -> list[str]:

@@ -128,8 +128,8 @@ def test_trace_ring_bounds_and_perfetto_schema():
     tr = telemetry.Tracer(capacity=16)
     tr.reset_epoch()
     for i in range(100):
-        t0 = tr.now()
-        tr.span("work", t0, tick=i)
+        with tr.region("work", tick=i):
+            pass
     evs = tr.events()
     assert len(evs) == 16  # ring-bounded
     assert tr.dropped == 84 and tr.emitted == 100
@@ -139,11 +139,165 @@ def test_trace_ring_bounds_and_perfetto_schema():
     assert doc["otherData"]["dropped"] == 84
     # disabled tracer costs nothing and records nothing
     off = telemetry.Tracer(capacity=16, enabled=False)
-    off.span("x", off.now())
+    with off.region("x") as r:
+        pass
     off.instant("y")
+    assert r.elapsed >= 0.0  # still timed with the ring off
     assert off.events() == [] and off.emitted == 0
     with pytest.raises(ValueError):
         telemetry.Tracer(capacity=4)  # below the floor
+
+
+# ------------------------------------------ spans on the profiler clock ----
+def _profiled_host_events(trace_dir, names) -> list:
+    """(name, start_ns, end_ns, args) of the host events named one of
+    `names` in the one .xplane.pb a `jax.profiler.trace(trace_dir)`
+    session wrote."""
+    from jax.profiler import ProfileData
+
+    paths = list(trace_dir.glob("**/*.xplane.pb"))
+    assert len(paths) == 1, paths
+    out = []
+    for plane in ProfileData.from_file(str(paths[0])).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name not in names:
+                    continue
+                out.append((ev.name, ev.start_ns,
+                            ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_region_ring_only_when_enabled(tmp_path, enabled):
+    """A region always writes its span into a running profiler session;
+    the ring gets it only when the tracer is enabled, with the args set at
+    exit as well."""
+    tr = telemetry.Tracer(capacity=16, enabled=enabled)
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.region("outer-span", rid=7) as outer:
+            with tr.region("inner-span", tid=2, k=3) as inner:
+                inner.set(emitted=5)
+        with tr.region("tick-span", step_num=4, tick=4):
+            pass
+    evs = {name: (t0, t1, args) for name, t0, t1, args
+           in _profiled_host_events(
+               tmp_path, ("outer-span", "inner-span", "tick-span"))}
+    assert {"outer-span", "inner-span", "tick-span"} <= set(evs)
+    assert evs["outer-span"][2]["rid"] == 7
+    assert evs["inner-span"][2]["k"] == 3
+    assert "emitted" not in evs["inner-span"][2]  # exit args: ring only
+    assert evs["tick-span"][2]["step_num"] == 4
+    o0, o1, _ = evs["outer-span"]
+    i0, i1, _ = evs["inner-span"]
+    assert o0 <= i0 and i1 <= o1
+    assert 0.0 < inner.elapsed <= outer.elapsed
+    ring = tr.events()
+    if not enabled:
+        assert ring == [] and tr.emitted == 0
+        return
+    assert [e["name"] for e in ring] == ["inner-span", "outer-span",
+                                         "tick-span"]
+    assert ring[0]["args"] == {"k": 3, "emitted": 5} and ring[0]["tid"] == 2
+    assert ring[1]["dur"] == pytest.approx(outer.elapsed)
+    assert telemetry.validate_trace(tr.to_perfetto()) == []
+
+
+def test_engine_spans_on_profiler_clock(setup, tmp_path):
+    """A profiled run of a tiny paged engine (tracer ring off, as the
+    benchmark runs it) writes the scheduler's spans into the profile,
+    nested tick > admit > prefill-chunk > dispatch/readback and
+    tick > decode-burst > dispatch/readback/emit."""
+    cfg, qz, params = setup
+    eng = scheduler.PagedServingEngine(
+        params, cfg, backends_lib.QuantXLABackend(cfg, qz),
+        _sched(telemetry=False))
+    eng.run(_requests(2, seed=9))  # compile outside the profile
+    with jax.profiler.trace(str(tmp_path)):
+        _, stats = eng.run(_requests(3, seed=9))
+    names = ("tick", "admit", "prefill-chunk", "decode-burst", "dispatch",
+             "readback", "emit")
+    evs = _profiled_host_events(tmp_path, names)
+    assert {e[0] for e in evs} == set(names)
+
+    def parent(ev):
+        holders = [p for p in evs if p is not ev and p[1] <= ev[1]
+                   and ev[2] <= p[2] and p[2] - p[1] > ev[2] - ev[1]]
+        return max(holders, key=lambda p: (p[1], -p[2]))[0] \
+            if holders else None
+
+    want = {"tick": {None}, "admit": {"tick"},
+            "prefill-chunk": {"admit"}, "decode-burst": {"tick"},
+            "emit": {"decode-burst"},
+            "readback": {"prefill-chunk", "decode-burst"},
+            "dispatch": {"prefill-chunk", "decode-burst", "tick"}}
+    for ev in evs:
+        assert parent(ev) in want[ev[0]], (ev, parent(ev))
+    ticks = [e for e in evs if e[0] == "tick"]
+    assert sorted(e[3]["step_num"] for e in ticks) == list(range(len(ticks)))
+    bursts = [e for e in evs if e[0] == "decode-burst"]
+    assert len(bursts) == stats["decode_bursts"] > 0
+    kinds = {e[3]["kind"] for e in evs if e[0] == "dispatch"}
+    assert {"decode", "prefill", "rng_split"} <= kinds
+    assert all(e[3]["rid"] in range(3) for e in evs if e[0] == "admit")
+
+
+@pytest.fixture(scope="module")
+def warmed(setup):
+    """A prefix-sharing engine with every step variant compiled ahead of
+    time, the shared-prefix ones included."""
+    cfg, qz, params = setup
+    eng = scheduler.PagedServingEngine(
+        params, cfg, backends_lib.QuantXLABackend(cfg, qz),
+        _sched(prefix_cache="share", prefix_pages=2, max_context=16))
+    eng.warmup(skips=(0, eng.sched.prefill_chunk))
+    return eng
+
+
+def test_step_executables_named(warmed):
+    """Each warmed step executable carries its own module name, so the
+    profile's module line tells decode bursts, prefills and prefix loads
+    apart."""
+    eng = warmed
+    chunk = eng.sched.prefill_chunk
+    width = eng.sched.max_pages
+    name = lambda key: eng.executable(key).as_text().split("\n", 1)[0]
+    assert name(("decode", width)).startswith("HloModule jit_decode_burst,")
+    assert name(("prefill", chunk, 0)).startswith("HloModule jit_prefill,")
+    n = chunk // eng.sched.page_size
+    assert name(("prefix_load", n)).startswith("HloModule jit_prefix_load,")
+
+
+def test_decode_executable_carries_scopes(warmed):
+    """The decode burst's ops carry the step body's named scopes in their
+    metadata: encode-on-write (`kv_append`) and attention (`attend`) can be
+    read off a device trace by scope."""
+    text = warmed.executable(("decode", warmed.sched.max_pages)).as_text()
+    assert "jit(decode_burst)/while/body/" in text
+    for scope in ("qkv", "kv_append", "attend", "mlp", "lm_head",
+                  "sample"):
+        assert f"/{scope}/" in text, scope
+
+
+def test_host_syncs_counted_once(setup):
+    """stats["perf"]["host_sync_count"] is the registry's cumulative
+    host_syncs; the per-run host time and burst count are registry deltas
+    too."""
+    cfg, qz, params = setup
+    eng = scheduler.PagedServingEngine(
+        params, cfg, backends_lib.QuantXLABackend(cfg, qz), _sched())
+    _, s1 = eng.run(_requests(3, seed=6))
+    _, s2 = eng.run(_requests(3, seed=6))
+    reg = eng.telemetry.registry
+    assert s2["perf"]["host_sync_count"] == reg.counter("host_syncs").value
+    # one readback per admission and one per decode burst
+    per_run = s2["perf"]["host_sync_count"] - s1["perf"]["host_sync_count"]
+    assert per_run == 3 + s2["decode_bursts"]
+    assert s1["decode_bursts"] == s2["decode_bursts"] > 0
+    assert 0.0 < s2["sched_host_s"] < s2["wall_s"]
+    assert 0.0 < s2["prefill_wall_s"] < s2["wall_s"]
 
 
 # ------------------------------------------- stats as registry views -------
