@@ -28,12 +28,17 @@ call and un-permutes the (tiny) output once per call; the hot loop then
 builds each (d_pad, page_size) tile with one concatenate.
 
 Pool layout: every pool leaf is (P, n_kv, page_size, X). A grid step's
-block is one page of one kv head, a (page_size, X) tile whose two minor
-dims are whole array dims — the form the TPU lowering accepts for any X
-(index words, norm codes, range scalars). Grid: (B, n_kv, max_pages),
-accumulating online-softmax state in VMEM scratch across the sequential
-page dimension; the page id of each step comes from the scalar-prefetched
-page table in the BlockSpec index_map.
+block is one page of `hb` kv heads, an (hb, page_size, X) tile whose two
+minor dims are whole array dims — the form the TPU lowering accepts for
+any X (index words, norm codes, range scalars). Grid: (B, n_kv / hb,
+max_pages), accumulating online-softmax state in VMEM scratch across the
+sequential page dimension; the page id of each step comes from the
+scalar-prefetched page table in the BlockSpec index_map. Inside a step
+a static loop runs the one-head arithmetic unchanged on each of the hb
+heads, so the output does not depend on hb. `hb` comes from the pool's
+shapes (`default_head_block`). Dequantization sets the kernel's pace; with
+one head a step the next step's DMAs outlast the head in hand and part of
+their time shows, with a few heads they finish under the arithmetic.
 
 Serving integration: `lengths` is a per-sequence (B,) vector (ragged
 batches) and the codebook sizes `n_bins_k`/`n_bins_v` are *runtime*
@@ -65,6 +70,15 @@ NEG_INF = -1e30
 # softmax scratch and the pipeline's double buffering.
 DEFAULT_VMEM_BUDGET = 4 * 1024 * 1024
 
+# VMEM for one paged grid step's pool tiles, double-buffered and counted
+# as laid out in VMEM (`_vmem_tile_bytes`). The kernel's dequantization,
+# not its DMA, sets its pace: a block of a few heads lets the next block's
+# DMA run under this block's arithmetic, while each further head only
+# lengthens the unrolled body. On a TPU v5e at 128-token pages and
+# head_dim 128 (416 KiB a head-page) this budget's 2 heads a step ran
+# fastest; 4 and 8 heads ran 5 % and 10 % slower.
+PAGED_VMEM_BUDGET = 2 * 1024 * 1024
+
 # the kernel's dots run in full f32 on the MXU (several bf16 passes), the
 # precision the dequantized tiles are computed in; the CPU ignores it
 _F32 = jax.lax.Precision.HIGHEST
@@ -86,6 +100,34 @@ def default_block_t(dp: int, row_stream_bytes: int,
     per_row = 2 * dp * 4 + row_stream_bytes
     bt = vmem_budget // (2 * per_row)
     return max(128, min(2048, (bt // 128) * 128))
+
+
+def _vmem_tile_bytes(rows: int, cols: int, dtype) -> int:
+    """Bytes a (rows, cols) tile takes in VMEM: the minor dim padded to
+    128 lanes, the second-minor to the dtype's sublane tile (8 rows of
+    32-bit, 16 of 16-bit, 32 of 8-bit)."""
+    itemsize = np.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    padded_rows = -(-rows // sublanes) * sublanes
+    padded_cols = -(-cols // 128) * 128
+    return padded_rows * padded_cols * itemsize
+
+
+def default_head_block(pool, vmem_budget: int = PAGED_VMEM_BUDGET) -> int:
+    """kv heads per paged grid step: the largest divisor of n_kv whose
+    double-buffered pool tiles fit the budget (1 if none does).
+
+    `pool` is the 8 leaves (P, n_kv, page_size, X), arrays or shapes. The
+    tiles are counted padded, as VMEM holds them: the range scalars'
+    (page_size, 1) f32 tiles and the narrow index/norm streams take far
+    more VMEM than HBM bytes, and the padded count is what has to fit.
+    """
+    nkv = pool[0].shape[1]
+    per_head = sum(_vmem_tile_bytes(a.shape[2], a.shape[3], a.dtype)
+                   for a in pool)
+    return max((hb for hb in range(1, nkv + 1)
+                if nkv % hb == 0 and 2 * hb * per_head <= vmem_budget),
+               default=1)
 
 
 def _dequant_block(idx_raw, nq_raw, rmin, rmax, *, n_bins, bits, log,
@@ -120,18 +162,18 @@ def _dequant_block(idx_raw, nq_raw, rmin, rmax, *, n_bins, bits, log,
     return jnp.concatenate([r * jnp.cos(theta), r * jnp.sin(theta)], axis=0)
 
 
-def _page_tiles(refs, bins_ref, col_ok, geom):
-    """Dequantize this grid step's K and V pages -> two (dp, ps) tiles,
-    with columns past the frontier zeroed (unwritten pool bytes may decode
-    to NaN/Inf, and 0 * NaN would poison the dots)."""
+def _page_tiles(refs, h, bins_ref, col_ok, geom):
+    """Dequantize head h's K and V pages of this grid step -> two (dp, ps)
+    tiles, with columns past the frontier zeroed (unwritten pool bytes may
+    decode to NaN/Inf, and 0 * NaN would poison the dots)."""
     kidx, knq, krmin, krmax, vidx, vnq, vrmin, vrmax = refs
     common = dict(pairs=geom["pairs"], idx_bits=geom["idx_bits"])
     y_k = _dequant_block(
-        kidx[...], knq[...], krmin[...], krmax[...], n_bins=bins_ref[0],
+        kidx[h], knq[h], krmin[h], krmax[h], n_bins=bins_ref[0],
         bits=geom["k_bits"], log=geom["k_log"],
         nq_packed=geom["k_nq_packed"], **common)
     y_v = _dequant_block(
-        vidx[...], vnq[...], vrmin[...], vrmax[...], n_bins=bins_ref[1],
+        vidx[h], vnq[h], vrmin[h], vrmax[h], n_bins=bins_ref[1],
         bits=geom["v_bits"], log=geom["v_log"],
         nq_packed=geom["v_nq_packed"], **common)
     return jnp.where(col_ok, y_k, 0.0), jnp.where(col_ok, y_v, 0.0)
@@ -139,11 +181,13 @@ def _page_tiles(refs, bins_ref, col_ok, geom):
 
 def paged_qattn_kernel(pt_ref, len_ref, bins_ref, q_ref, *refs,
                        page_size: int, geom: dict):
-    """One (slot, kv-head, page) grid step of flash-decode.
+    """One (slot, kv-head block, page) grid step of flash-decode.
 
     The K/V block for grid step p is whatever physical page `pt[b, p]`
     names — the gather happens in the BlockSpec index_map, so the DMA
-    engine streams exactly the pages the slot owns.
+    engine streams exactly the pages the slot owns. The block holds hb kv
+    heads; each runs the one-head update below on its own slice of the
+    q block, the pool tiles and the scratch.
 
     Per-page work bound: a page past this slot's frontier contributes
     exactly nothing (masked scores are NEG_INF -> p == 0, m unchanged), so
@@ -166,25 +210,27 @@ def paged_qattn_kernel(pt_ref, len_ref, bins_ref, q_ref, *refs,
 
     @pl.when(p_step * page_size < length)
     def _work():
-        q = q_ref[...]  # (g, dp) pre-rotated, pre-scaled, split-half
         col_pos = p_step * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
         col_ok = col_pos < length
-        y_k, y_v = _page_tiles(pool_refs, bins_ref, col_ok, geom)
-        s = jax.lax.dot_general(
-            q.astype(jnp.float32), y_k, (((1,), (0,)), ((), ())),
-            precision=_F32)  # (g, ps)
-        s = jnp.where(col_ok, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        m_scr[...] = m_new
-        pv = jax.lax.dot_general(p, y_v, (((1,), (1,)), ((), ())),
-                                 precision=_F32)  # (g, dp)
-        acc_scr[...] = acc_scr[...] * corr + pv
+        for h in range(q_ref.shape[0]):  # the block's kv heads, unrolled
+            q = q_ref[h]  # (g, dp) pre-rotated, pre-scaled, split-half
+            y_k, y_v = _page_tiles(pool_refs, h, bins_ref, col_ok, geom)
+            s = jax.lax.dot_general(
+                q.astype(jnp.float32), y_k, (((1,), (0,)), ((), ())),
+                precision=_F32)  # (g, ps)
+            s = jnp.where(col_ok, s, NEG_INF)
+
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            m_scr[h] = m_new
+            pv = jax.lax.dot_general(p, y_v, (((1,), (1,)), ((), ())),
+                                     precision=_F32)  # (g, dp)
+            acc_scr[h] = acc_scr[h] * corr + pv
 
     @pl.when(p_step == pl.num_programs(2) - 1)
     def _fin():
@@ -197,8 +243,9 @@ def paged_qattn_multi_kernel(pt_ref, len_ref, bins_ref, q_ref, *refs,
     """Speculative-verify attention: all q_len query rows of a slot share
     ONE walk over its pages.
 
-    The q block carries all q_len*g query rows for a (slot, kv-head) pair
-    and each page is dequantized ONCE; row r (query position j = r // g)
+    The q block carries all q_len*g query rows of each kv head in the
+    step's block, and each head's page is dequantized ONCE; row r (query
+    position j = r // g)
     applies its own causal frontier
 
         lengths[slot] + j + 1
@@ -234,39 +281,41 @@ def paged_qattn_multi_kernel(pt_ref, len_ref, bins_ref, q_ref, *refs,
     # contribute nothing to any row
     @pl.when(p_step * page_size < length + q_len)
     def _work():
-        q = q_ref[...]  # (q_len*g, dp)
         col_pos = p_step * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
-        y_k, y_v = _page_tiles(pool_refs, bins_ref,
-                               col_pos < length + q_len, geom)
-        s = jnp.concatenate(
-            [jax.lax.dot_general(
-                q[j * g:(j + 1) * g].astype(jnp.float32), y_k,
-                (((1,), (0,)), ((), ())), precision=_F32)  # (g, ps)
-             for j in range(q_len)], axis=0)  # (q_len*g, ps)
         # query position j's causal frontier: the committed tokens plus
         # the j+1 this dispatch appended (its own key included)
         row_j = jax.lax.broadcasted_iota(
             jnp.int32, (q_len * g, 1), 0) // g
-        s = jnp.where(col_pos < length + 1 + row_j, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_scr[...] = m_new
-        l_prev = l_scr[...]
-        acc_prev = acc_scr[...]
-        l_new, acc_new = [], []
-        for j in range(q_len):
-            rows = slice(j * g, (j + 1) * g)
-            p = jnp.exp(s[rows] - m_new[rows])
-            corr = jnp.exp(m_prev[rows] - m_new[rows])
-            l_new.append(l_prev[rows] * corr
-                         + jnp.sum(p, axis=-1, keepdims=True))
-            pv = jax.lax.dot_general(p, y_v, (((1,), (1,)), ((), ())),
-                                     precision=_F32)
-            acc_new.append(acc_prev[rows] * corr + pv)
-        l_scr[...] = jnp.concatenate(l_new, axis=0)
-        acc_scr[...] = jnp.concatenate(acc_new, axis=0)
+        for h in range(q_ref.shape[0]):  # the block's kv heads, unrolled
+            q = q_ref[h]  # (q_len*g, dp)
+            y_k, y_v = _page_tiles(pool_refs, h, bins_ref,
+                                   col_pos < length + q_len, geom)
+            s = jnp.concatenate(
+                [jax.lax.dot_general(
+                    q[j * g:(j + 1) * g].astype(jnp.float32), y_k,
+                    (((1,), (0,)), ((), ())), precision=_F32)  # (g, ps)
+                 for j in range(q_len)], axis=0)  # (q_len*g, ps)
+            s = jnp.where(col_pos < length + 1 + row_j, s, NEG_INF)
+
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_scr[h] = m_new
+            l_prev = l_scr[h]
+            acc_prev = acc_scr[h]
+            l_new, acc_new = [], []
+            for j in range(q_len):
+                rows = slice(j * g, (j + 1) * g)
+                p = jnp.exp(s[rows] - m_new[rows])
+                corr = jnp.exp(m_prev[rows] - m_new[rows])
+                l_new.append(l_prev[rows] * corr
+                             + jnp.sum(p, axis=-1, keepdims=True))
+                pv = jax.lax.dot_general(p, y_v, (((1,), (1,)), ((), ())),
+                                         precision=_F32)
+                acc_new.append(acc_prev[rows] * corr + pv)
+            l_scr[h] = jnp.concatenate(l_new, axis=0)
+            acc_scr[h] = jnp.concatenate(acc_new, axis=0)
 
     @pl.when(p_step == pl.num_programs(2) - 1)
     def _fin():
@@ -289,14 +338,18 @@ def _from_split_half(x: jax.Array) -> jax.Array:
 
 def _paged_call(kernel, q_rot, pool, page_table, lengths, n_bins_k,
                 n_bins_v, *, interpret: bool):
-    """Run a paged kernel body over the pool: grid (B, n_kv, max_pages),
-    page table / lengths / bins scalar-prefetched into SMEM, one
-    (page_size, X) tile of every pool leaf per step. q_rot is
-    (B, n_kv, rows, dp) in interleaved order; returns the same shape, f32.
+    """Run a paged kernel body over the pool: grid (B, n_kv / hb,
+    max_pages), page table / lengths / bins scalar-prefetched into SMEM,
+    one (hb, page_size, X) tile of every pool leaf per step, with hb =
+    `default_head_block(pool)`. A page-table entry past a slot's frontier
+    names the trash page, so consecutive dead steps name the same block
+    and their DMA is skipped. q_rot is (B, n_kv, rows, dp) in interleaved
+    order; returns the same shape, f32.
     """
     b, nkv, rows, dp = q_rot.shape
     page_size = pool[0].shape[2]
     mp = page_table.shape[1]
+    hb = default_head_block(pool)
     bins = jnp.stack([
         jnp.asarray(n_bins_k, jnp.int32).reshape(()),
         jnp.asarray(n_bins_v, jnp.int32).reshape(()),
@@ -305,20 +358,20 @@ def _paged_call(kernel, q_rot, pool, page_table, lengths, n_bins_k,
 
     def pool_spec(arr):
         return pl.BlockSpec(
-            (sq, sq, page_size, arr.shape[-1]),
-            lambda bi, ni, pi, pt, lens, bins_: (pt[bi, pi], ni, 0, 0))
+            (sq, hb, page_size, arr.shape[-1]),
+            lambda bi, hi, pi, pt, lens, bins_: (pt[bi, pi], hi, 0, 0))
 
-    q_spec = pl.BlockSpec((sq, sq, rows, dp),
-                          lambda bi, ni, pi, *_: (bi, ni, 0, 0))
+    q_spec = pl.BlockSpec((sq, hb, rows, dp),
+                          lambda bi, hi, pi, *_: (bi, hi, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # page_table, lengths, bins
-        grid=(b, nkv, mp),
+        grid=(b, nkv // hb, mp),
         in_specs=[q_spec, *[pool_spec(a) for a in pool]],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, dp), jnp.float32),
+            pltpu.VMEM((hb, rows, 1), jnp.float32),
+            pltpu.VMEM((hb, rows, 1), jnp.float32),
+            pltpu.VMEM((hb, rows, dp), jnp.float32),
         ],
     )
     out_perm = pl.pallas_call(
@@ -365,7 +418,8 @@ def paged_qattn(
     interpret: bool,
 ) -> jax.Array:
     """Flash-decode over the paged pool. The block size IS the page size —
-    one grid step streams one physical page per (slot, kv-head)."""
+    one grid step streams one physical page of a block of kv heads per
+    slot (`_paged_call`)."""
     geom = _geom(q_rot, idx_bits, k_bits, k_log, k_nq_packed, v_bits, v_log,
                  v_nq_packed)
     kernel = functools.partial(paged_qattn_kernel,

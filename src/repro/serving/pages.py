@@ -46,8 +46,9 @@ class PagedKVCache(NamedTuple):
     """Device-side paged pool + per-slot indirection.
 
     k/v:        QuantizedKV pools, arrays (L, P, n_kv, page_size, ...) —
-                one page of one kv head is a (page_size, X) tile, the
-                block the Pallas kernel streams per grid step
+                one page of one kv head is a (page_size, X) tile; the
+                Pallas kernel streams one page of a block of kv heads
+                per grid step
     page_table: (B, max_pages) int32 physical page ids (0 = unused/trash;
                 entries past a slot's allocation are masked via lengths)
     lengths:    (B,) int32 — valid tokens per decode slot

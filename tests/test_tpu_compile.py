@@ -7,10 +7,16 @@ installed, no chip is attached, nothing runs. Each compile takes about a
 second and must contain the Mosaic kernel (`tpu_custom_call`), i.e. the
 program calls the compiled kernel and not an interpreted body.
 
-Geometries: qwen3-0.6b's published attention widths (head_dim 64, 8 kv
-heads, 2 q heads per kv head) with its early-boost schedule (8-bit index
-width), and head_dim 128 with the uniform schedule (7-bit codes that
-straddle words), both bit-packed with 8-bit K norms and 4-bit log V norms.
+Geometries: head_dim 64 with 8 kv heads and 2 q heads per kv head under
+the early-boost schedule (8-bit index width); head_dim 128 with the
+uniform schedule (7-bit codes that straddle words); and the benchmark's
+two models at their published widths under the early-boost schedule:
+qwen3-0.6b (head_dim 128, 8 kv heads, 2 q heads per kv head) and
+deepseek-llm-7b (head_dim 128, 32 kv heads, MHA). All are bit-packed with
+8-bit K norms and 4-bit log V norms. At 128-token pages the paged
+kernels take 2 kv heads per grid step, at 16-token pages all 8 or 32, so
+these compiles show that (hb, page_size, X) pool blocks lower and fit the
+chip's VMEM.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, so with several test
@@ -29,11 +35,19 @@ from repro.kernels.qattn import qattn as qattn_k
 from repro.serving import pages
 
 GEOMETRIES = {
-    # id: (head_dim, quant schedule, n_early)
-    "qwen3-hd64-iw8": (64, "early_boost", 4),
-    "hd128-iw7": (128, "uniform", 0),
+    # id: (head_dim, quant schedule, n_early, q heads, kv heads)
+    "qwen3-hd64-iw8": (64, "early_boost", 4, 16, 8),
+    "hd128-iw7": (128, "uniform", 0, 16, 8),
+    "qwen3-0.6b": (128, "early_boost", 4, 16, 8),
+    "deepseek-7b": (128, "early_boost", 4, 32, 32),
 }
 SLOTS, NUM_PAGES, MAX_PAGES = 4, 64, 8
+# (page size, geometry): the two small geometries at 16- and 128-token
+# pages, the benchmark's models at their 128-token pages
+PAGED = [(16, "qwen3-hd64-iw8"), (16, "hd128-iw7"), (128, "qwen3-hd64-iw8"),
+         (128, "hd128-iw7"), (128, "qwen3-0.6b"), (128, "deepseek-7b")]
+MULTI = [(16, "qwen3-hd64-iw8"), (16, "hd128-iw7"), (128, "qwen3-0.6b"),
+         (128, "deepseek-7b")]
 
 
 @pytest.fixture(scope="module")
@@ -55,10 +69,10 @@ def one_chip(topo):
 
 
 def _geometry(name: str):
-    head_dim, schedule, n_early = GEOMETRIES[name]
+    head_dim, schedule, n_early, n_heads, n_kv = GEOMETRIES[name]
     cfg = ModelConfig(name=name, family="decoder", num_layers=28,
-                      d_model=1024, num_heads=16, num_kv_heads=8, d_ff=3072,
-                      vocab_size=1024, head_dim=head_dim)
+                      d_model=1024, num_heads=n_heads, num_kv_heads=n_kv,
+                      d_ff=3072, vocab_size=1024, head_dim=head_dim)
     qz = KVQuantizer(QuantConfig(schedule=schedule, n_early=n_early).build(
         head_dim, cfg.num_layers))
     assert qz.config.resolved_storage == "bitpack"
@@ -84,8 +98,8 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("geometry", list(GEOMETRIES))
-@pytest.mark.parametrize("page_size", [16, 128])
+@pytest.mark.parametrize("page_size, geometry", PAGED,
+                         ids=[f"{ps}-{g}" for ps, g in PAGED])
 def test_paged_qattn_compiles_for_v5e(one_chip, geometry, page_size):
     cfg, qz = _geometry(geometry)
     nk, nv = (int(x[0]) for x in qz.config.schedule.as_arrays())
@@ -101,8 +115,9 @@ def test_paged_qattn_compiles_for_v5e(one_chip, geometry, page_size):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("geometry", list(GEOMETRIES))
-def test_paged_qattn_multi_compiles_for_v5e(one_chip, geometry):
+@pytest.mark.parametrize("page_size, geometry", MULTI,
+                         ids=[g for _, g in MULTI])
+def test_paged_qattn_multi_compiles_for_v5e(one_chip, geometry, page_size):
     cfg, qz = _geometry(geometry)
     nk, nv = (int(x[0]) for x in qz.config.schedule.as_arrays())
     q_len = 5
@@ -114,7 +129,7 @@ def test_paged_qattn_multi_compiles_for_v5e(one_chip, geometry):
     text = _compiled_text(
         lambda q, kv, t, n: qattn_ops.paged_attend_multi_quant_cache_op(
             q, *kv, nk, nv, t, n, cfg, qz, interpret=False),
-        q, _layer_pool(cfg, qz, 16, one_chip), table, lengths)
+        q, _layer_pool(cfg, qz, page_size, one_chip), table, lengths)
     assert "tpu_custom_call" in text
 
 
