@@ -3,7 +3,8 @@
 After the window has closed and the program's state is freed, a sample
 drawn from the seed of the requests the window finished, with the one that
 served the most tokens always in it, is run through the plain reference
-(`reference.py`) once over its prompt and served tokens. Every served
+(`served_logits` of the cell's `arch/<arch>.py`, on the helpers of
+`reference.py`) once over its prompt and served tokens. Every served
 token was the program's greedy choice; the number compared is the widest
 gap by which a served token's reference logit lies below the reference's
 best logit at that position (0 where the two agree).
@@ -56,7 +57,7 @@ def gaps(params, cell, prompt, served, t_pad: int, modes=("highest",)):
     kw = dict(model=reference.freeze(m), quant=reference.freeze(q),
               chunk=int(cell.mix["prefill_chunk"]),
               requant=cell.mix["prefix_cache"] != "off", rows=rows)
-    ref = reference.served_logits(params, jnp.asarray(toks),
+    ref = cell.arch.served_logits(params, jnp.asarray(toks),
                                   jnp.asarray(len(prompt), jnp.int32),
                                   mode="highest", **kw)
     n = len(served)
@@ -66,7 +67,7 @@ def gaps(params, cell, prompt, served, t_pad: int, modes=("highest",)):
     for mode in modes:
         if mode == "highest":
             continue
-        low = reference.served_logits(params, jnp.asarray(toks),
+        low = cell.arch.served_logits(params, jnp.asarray(toks),
                                       jnp.asarray(len(prompt), jnp.int32),
                                       mode=mode, **kw)
         out[mode] = np.asarray(reference.gap_of(

@@ -3,9 +3,12 @@ files, warm up the shapes its traffic uses, drive the measured window,
 read the device, and check what the window served.
 
 The cell's parts are found by name: `configs/<config>.json` (model sizes
-and how it is served), `traffic/<mix>.json` (the load and the server's
-settings for it, read by `traffic.py`), `cells/<workload>.json` (the
-correctness limits), `metrics/<metric>.py` (one reader per metric).
+and how it is served), `arch/<arch>.py` (everything known of the
+architecture that the configuration's `"arch"` key names, `dense_decoder`
+where it names none: see `arch/__init__.py`), `traffic/<mix>.json` (the
+load and the server's settings for it, read by `traffic.py`),
+`cells/<workload>.json` (the correctness limits), `metrics/<metric>.py`
+(one reader per metric).
 """
 from __future__ import annotations
 
@@ -35,6 +38,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    arch: object  # the module arch/<arch>.py of the configuration
     mix: dict
     limits: dict
     end_to_end: list  # BENCHMARK.json metric entries that this cell reports
@@ -55,9 +59,12 @@ def load_cell(workload: str, spec: Optional[dict] = None) -> Cell:
     w = next((w for w in spec["workloads"] if w["name"] == workload), None)
     if w is None:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
+    import arch
+
+    config = _json(HERE / "configs" / f"{w['config']}.json")
     return Cell(
-        name=workload, chips=int(w["chips"]),
-        config=_json(HERE / "configs" / f"{w['config']}.json"),
+        name=workload, chips=int(w["chips"]), config=config,
+        arch=arch.of(config),
         mix=_json(HERE / "traffic" / f"{w['traffic']}.json"),
         limits=_json(HERE / "cells" / f"{workload}.json"),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, workload)],
@@ -75,37 +82,21 @@ def reader(metric: str):
 
 
 # ------------------------------------------------------- served system --
-def model_config(c: dict):
-    from repro.configs.base import ModelConfig
-
-    s = c["serving"]
-    return ModelConfig(
-        name=c["name"], family="decoder",
-        num_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        head_dim=c["head_dim"], qk_norm=c["qk_norm"],
-        qkv_bias=c["attention_bias"], rope_theta=c["rope_theta"],
-        tie_embeddings=c["tie_word_embeddings"], norm_eps=c["rms_norm_eps"],
-        act=c["hidden_act"], param_dtype=s["param_dtype"],
-        compute_dtype=s["compute_dtype"])
-
-
-def quantizer(c: dict):
+def quantizer(q: dict, layers: list):
+    """The program's K/V quantizer for a `serving.kv_quant` block, its
+    schedule over `layers`, the (q heads, kv heads, head_dim) of each
+    layer whose K/V live in pages (`attention_layers` of the arch)."""
     from repro.configs.base import QuantConfig
     from repro.core.quantizer import KVQuantizer
 
-    q = c["serving"]["kv_quant"]
-    L = c["num_hidden_layers"]
     qc = QuantConfig(
-        schedule=q["schedule"], n_early=min(q["n_early"], L),
+        schedule=q["schedule"], n_early=min(q["n_early"], len(layers)),
         boost_k=q["boost_k"], boost_v=q["boost_v"], base_k=q["base_k"],
         base_v=q["base_v"], k_norm_bits=q["k_norm_bits"],
         k_norm_log=q["k_norm_log"], v_norm_bits=q["v_norm_bits"],
         v_norm_log=q["v_norm_log"], seed=q["rotation_seed"],
         storage=q["storage"])
-    return KVQuantizer(qc.build(c["head_dim"], L))
+    return KVQuantizer(qc.build(layers[0][2], len(layers)))
 
 
 def _cdiv(a: int, b: int) -> int:
@@ -152,9 +143,11 @@ def scheduler_config(mix: dict, sz: Sizes):
         telemetry=False)
 
 
-def make_weights(seed: int, cfg):
+def make_weights(seed: int, cfg, init_leaf):
     """Every weight of the served model from `seed`, made on the device in
-    one jitted call, in the dtype it is served in."""
+    one jitted call, in the dtype it is served in: leaf i of the parameter
+    tree is `init_leaf(path, shape, z)` (see `arch/__init__.py`) of the
+    standard-normal draw z from `fold_in(key, i)`."""
     import jax
     import jax.numpy as jnp
 
@@ -166,16 +159,10 @@ def make_weights(seed: int, cfg):
     def build(key):
         out = []
         for i, (path, sds) in enumerate(flat):
-            name = str(getattr(path[-1], "key", path[-1]))
+            name = "/".join(str(getattr(k, "key", k)) for k in path)
             z = jax.random.normal(jax.random.fold_in(key, i), sds.shape,
                                   jnp.float32)
-            if "norm" in name:
-                v = 1.0 + 0.1 * z
-            elif name == "embed":
-                v = 0.02 * z
-            else:
-                v = z / np.sqrt(sds.shape[-2])
-            out.append(v.astype(sds.dtype))
+            out.append(init_leaf(name, sds.shape, z).astype(sds.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     state = np.random.SeedSequence(int(seed) % 2**64).generate_state(2)
@@ -195,13 +182,7 @@ def decode_width(engine, n_tokens: int) -> int:
                if w >= need)
 
 
-def prefill_key(engine, plen: int, skip: int) -> tuple:
-    """(suffix width, cached prefix) of the prefill an admission of a
-    `plen`-token prompt with `skip` cached tokens dispatches."""
-    return (engine._bucket_width(plen - skip), skip)
-
-
-def reachable(mix: dict, items, engine) -> tuple[dict, dict]:
+def reachable(mix: dict, items, engine, arch) -> tuple[dict, dict]:
     """The dispatch shapes the window's requests can reach: prefill keys
     and decode page-table widths, each with a request that reaches it
     (and for a width, the decode steps it takes that request to get
@@ -215,7 +196,7 @@ def reachable(mix: dict, items, engine) -> tuple[dict, dict]:
         skip = 0
         if mix["prefix_cache"] == "share" and it.shared:
             skip = prefix_lib.usable_prefix_tokens(it.shared, plen, chunk)
-        need_pf.setdefault(prefill_key(engine, plen, skip), it)
+        need_pf.setdefault(arch.prefill_key(engine, plen, skip), it)
         for n in range(plen + 1, plen + it.max_new_tokens + 1, ps):
             need_w.setdefault(decode_width(engine, n), (it, n - plen))
         w = decode_width(engine, plen + it.max_new_tokens)
@@ -223,12 +204,13 @@ def reachable(mix: dict, items, engine) -> tuple[dict, dict]:
     return need_pf, need_w
 
 
-def warm_plan(mix: dict, items, engine, vocab: int, seed: int) -> list:
+def warm_plan(mix: dict, items, engine, arch, vocab: int,
+              seed: int) -> list:
     """Solo requests, one per engine run, that between them dispatch every
     prefill variant and every decode width the window's requests can
     reach. Each copies a window request's shared prefix and lengths, with
     fresh token ids after the prefix."""
-    need_pf, need_w = reachable(mix, items, engine)
+    need_pf, need_w = reachable(mix, items, engine, arch)
     rng = np.random.default_rng([int(seed) % 2**64, 2])
     plan, covered = [], set()
 
@@ -431,13 +413,14 @@ def setup(cell: Cell, seed: int, seconds: float, *,
     counter = _CompileCounter()
     split = {"process_start_s": time.perf_counter() - t_start}
 
-    cfg = model_config(cell.config)
-    qz = quantizer(cell.config)
+    cfg = cell.arch.model_config(cell.config)
+    qz = quantizer(cell.config["serving"]["kv_quant"],
+                   cell.arch.attention_layers(cell.config))
     tr = traffic_lib.generate(cell.mix, cfg.vocab_size, seed, seconds)
     sched = scheduler_config(cell.mix, sizes(cell.mix, tr.window,
                                              tr.documents))
     t = time.perf_counter()
-    params = make_weights(seed, cfg)
+    params = make_weights(seed, cfg, cell.arch.init_leaf)
     split["weights_s"] = time.perf_counter() - t
     backend = backends.QuantPallasBackend(cfg, qz, interpret=interpret)
     engine = scheduler.PagedServingEngine(params, cfg, backend, sched)
@@ -448,7 +431,8 @@ def setup(cell: Cell, seed: int, seconds: float, *,
                     for i, d in enumerate(tr.documents)])
     split["documents_s"] = time.perf_counter() - t
     t = time.perf_counter()
-    plan = warm_plan(cell.mix, tr.window, engine, cfg.vocab_size, seed)
+    plan = warm_plan(cell.mix, tr.window, engine, cell.arch, cfg.vocab_size,
+                     seed)
     for i, (toks, gen) in enumerate(plan):
         engine.run([scheduler.Request(rid=-100 - i, tokens=toks,
                                       max_new_tokens=gen)])

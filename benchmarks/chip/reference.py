@@ -1,16 +1,14 @@
-"""Plain reference of the served decoders, for the correctness check.
+"""What every architecture's plain reference shares, for the correctness
+check: float32 products at the check's precision, RMSNorm, RoPE, and the
+TurboAngle paper's quantizer (y = H D x with a seeded +-1 diagonal D and
+the normalized Hadamard H; each consecutive pair of y stored as a
+uniformly quantized angle, reconstructed at its bin centre, and a min-max
+quantized norm, linear or in log space). Each architecture's own forward
+pass is `served_logits` in `arch/<arch>.py`. Like them, this imports
+nothing of the program under test and takes only the weights the
+benchmark made and the configuration's file.
 
-Straightforward jax.numpy in float32, written from the published model
-descriptions (Qwen3, DeepSeek LLM: pre-norm RMSNorm decoder blocks, GQA
-attention with rotate-half RoPE, optional per-head q/k RMSNorm, SwiGLU MLP,
-final RMSNorm, tied or untied LM head) and from the TurboAngle paper's
-quantizer (y = H D x with a seeded +-1 diagonal D and the normalized
-Hadamard H; each consecutive pair of y stored as a uniformly quantized
-angle, reconstructed at its bin centre, and a min-max quantized norm,
-linear or in log space). It imports nothing of the program under test and
-takes only the weights the benchmark made and the configuration's file.
-
-What the served system computes, and so what this reproduces:
+What the served system computes, and so what a reference reproduces:
 
 * prompt positions attend to raw keys/values inside their own prefill
   chunk; to earlier chunks they attend through the quantizer when the
@@ -18,15 +16,14 @@ What the served system computes, and so what this reproduces:
 * every generated position attends to the quantized keys/values of all
   positions up to and including its own (encode-on-write, then read).
 
-The model runs layer by layer over the whole padded sequence, attention in
-query blocks of one prefill chunk, so that a long context fits beside the
-weights. `mode="highest"` takes every product at full float32 precision;
-`mode="high"` takes them in three bfloat16 passes (hi*hi + hi*lo + lo*hi),
-written out so that it means the same on any backend: the control.
+A reference runs layer by layer over the whole padded sequence, attention
+in query blocks of one prefill chunk, so that a long context fits beside
+the weights. `mode="highest"` takes every product at full float32
+precision; `mode="high"` takes them in three bfloat16 passes (hi*hi +
+hi*lo + lo*hi), written out so that it means the same on any backend: the
+control.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -116,96 +113,6 @@ def layer_bins(quant: dict, num_layers: int) -> tuple[np.ndarray, np.ndarray]:
     nk = [quant["boost_k"]] * e + [quant["base_k"]] * (num_layers - e)
     nv = [quant["boost_v"]] * e + [quant["base_v"]] * (num_layers - e)
     return np.asarray(nk, np.float32), np.asarray(nv, np.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("model", "quant", "chunk",
-                                             "requant", "rows", "mode"))
-def served_logits(params, tokens, n_prompt, *, model, quant, chunk: int,
-                  requant: bool, rows: int, mode: str):
-    """Logits at positions n_prompt-1 ... n_prompt-1+rows-1 of `tokens`
-    (padded to a multiple of `chunk`): the positions whose argmax is the
-    1st, 2nd, ... generated token. `model`/`quant` are hashable tuples of
-    the configuration's items (see `freeze`)."""
-    m, q = dict(model), dict(quant)
-    dot = make_dot(mode)
-    t_pad = tokens.shape[0]
-    nq, nkv = m["num_attention_heads"], m["num_key_value_heads"]
-    hd = m["head_dim"]
-    eps = float(m["rms_norm_eps"])
-    h = jnp.asarray(hadamard(hd))
-    signs = rotation_signs(int(q["rotation_seed"]), hd)
-    nk, nv = layer_bins(q, m["num_hidden_layers"])
-    pos = jnp.arange(t_pad)
-    g = nq // nkv
-    scale = 1.0 / np.sqrt(hd)
-
-    def attend(qh, k, v, kq, vq):
-        """qh (T, nq, hd); k, v raw and kq, vq quantized (T, nkv, hd)."""
-        qg = qh.reshape(t_pad, nkv, g, hd)
-
-        def block(b):
-            s0 = b * chunk
-            t = s0 + jnp.arange(chunk)
-            qb = jax.lax.dynamic_slice_in_dim(qg, s0, chunk, 0)
-            causal = pos[None, :] <= t[:, None]  # (C, T)
-            prompt_row = (t < n_prompt)[:, None]
-            sq = dot("qngh,knh->ngqk", qb, kq) * scale
-            if requant:  # raw only inside the row's own chunk
-                loc = jnp.arange(chunk)
-                raw = prompt_row & (loc[None, :] <= loc[:, None])
-                kr = jax.lax.dynamic_slice_in_dim(k, s0, chunk, 0)
-                vr = jax.lax.dynamic_slice_in_dim(v, s0, chunk, 0)
-                sr = dot("qngh,knh->ngqk", qb, kr) * scale
-                slab = jax.lax.dynamic_slice_in_dim(sq, s0, chunk, 3)
-                s = jax.lax.dynamic_update_slice_in_dim(
-                    sq, jnp.where(raw, sr, slab), s0, 3)
-                p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-                pslab = jax.lax.dynamic_slice_in_dim(p, s0, chunk, 3)
-                pq = jax.lax.dynamic_update_slice_in_dim(
-                    p, jnp.where(raw, 0.0, pslab), s0, 3)
-                return (dot("ngqk,knh->qngh", pq, vq)
-                        + dot("ngqk,knh->qngh", jnp.where(raw, pslab, 0.0),
-                              vr))
-            raw = prompt_row & causal  # prompt rows read raw everywhere
-            s = jnp.where(raw, dot("qngh,knh->ngqk", qb, k) * scale, sq)
-            p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
-            return (dot("ngqk,knh->qngh", jnp.where(raw, 0.0, p), vq)
-                    + dot("ngqk,knh->qngh", jnp.where(raw, p, 0.0), v))
-
-        out = jax.lax.map(block, jnp.arange(t_pad // chunk))
-        return out.reshape(t_pad, nq * hd)
-
-    def layer(x, xs):
-        lp, bk, bv = xs
-        a = lp["attn"]
-        hx = rms_norm(x, lp["norm1"], eps)
-        qh = dot("td,dk->tk", hx, a["wq"]).reshape(t_pad, nq, hd)
-        k = dot("td,dk->tk", hx, a["wk"]).reshape(t_pad, nkv, hd)
-        v = dot("td,dk->tk", hx, a["wv"]).reshape(t_pad, nkv, hd)
-        if m["qk_norm"]:
-            qh = rms_norm(qh, a["q_norm"], eps)
-            k = rms_norm(k, a["k_norm"], eps)
-        qh = rope(qh, pos, m["rope_theta"])
-        k = rope(k, pos, m["rope_theta"])
-        kq = fake_quant(k, bk, q["k_norm_bits"], q["k_norm_log"], signs, h,
-                        dot)
-        vq = fake_quant(v, bv, q["v_norm_bits"], q["v_norm_log"], signs, h,
-                        dot)
-        x = x + dot("tk,kd->td", attend(qh, k, v, kq, vq), a["wo"])
-        hx = rms_norm(x, lp["norm2"], eps)
-        mp = lp["mlp"]
-        up = dot("td,df->tf", hx, mp["w_up"])
-        gate = jax.nn.silu(dot("td,df->tf", hx, mp["w_gate"]))
-        return x + dot("tf,fd->td", gate * up, mp["w_down"]), None
-
-    x = params["embed"][tokens].astype(F32)
-    x, _ = jax.lax.scan(layer, x, (params["layers"], jnp.asarray(nk),
-                                   jnp.asarray(nv)))
-    x = jax.lax.dynamic_slice_in_dim(x, n_prompt - 1, rows, 0)
-    x = rms_norm(x, params["final_norm"], eps)
-    head = (params["embed"].T if m["tie_word_embeddings"]
-            else params["lm_head"])
-    return dot("td,dv->tv", x, head)
 
 
 def freeze(d: dict) -> tuple:

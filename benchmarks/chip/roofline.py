@@ -1,30 +1,35 @@
-"""The work that the served decoders' kernels and steps need, counted from
+"""The work that the served models' kernels and steps need, counted from
 the configuration's sizes alone (never from what an implementation
 happens to read), so that no change to the program moves the yardstick.
 
-`m` is a configuration file's dict (Hugging Face key names); `q` is its
-`serving.kv_quant` block.
+`m` is a configuration file's dict; its architecture module
+(`arch/<arch>.py`) says which weights a token multiplies through and
+which layers attend through the page pool. `q` is its `serving.kv_quant`
+block.
 """
 from __future__ import annotations
 
 import math
 
+import arch
+
 
 def matmul_params(m: dict) -> int:
     """Weights a token multiplies through in one forward pass, LM head
     included (the embedding lookup is a gather, not a product)."""
-    d, f = m["hidden_size"], m["intermediate_size"]
-    nq, nkv, hd = (m["num_attention_heads"], m["num_key_value_heads"],
-                   m["head_dim"])
-    attn = d * nq * hd + 2 * d * nkv * hd + nq * hd * d
-    return m["num_hidden_layers"] * (attn + 3 * d * f) + d * m["vocab_size"]
+    return sum(arch.of(m).matmul_params(m))
+
+
+def _attention_width(m: dict) -> int:
+    """Multiply-adds of one query token's q.k (and of its p.v) per context
+    position, all layers: q heads x head_dim summed over the layers."""
+    return sum(nq * hd for nq, _, hd in arch.of(m).attention_layers(m))
 
 
 def attention_flops(m: dict, context: int) -> int:
     """Exact attention of one query token over `context` positions, all
     layers: q.k and p.v, two FLOPs per multiply-add."""
-    return (4 * m["num_attention_heads"] * m["head_dim"] * context
-            * m["num_hidden_layers"])
+    return 4 * _attention_width(m) * context
 
 
 def kv_bits(q: dict, head_dim: int, num_layers: int) -> list:
@@ -43,10 +48,11 @@ def kv_bits(q: dict, head_dim: int, num_layers: int) -> list:
 
 
 def kv_bytes_per_position(m: dict, q: dict) -> float:
-    """Packed K and V codes of one cached position over all layers."""
-    hd = m["head_dim"]
-    per_elem = sum(kv_bits(q, hd, m["num_hidden_layers"]))
-    return m["num_key_value_heads"] * hd * per_elem / 8
+    """Packed K and V codes of one cached position over all layers that
+    keep K/V in the pool."""
+    layers = arch.of(m).attention_layers(m)
+    return sum(nkv * hd * kv_bits(q, hd, len(layers))[i] / 8
+               for i, (_, nkv, hd) in enumerate(layers))
 
 
 def qattn_work(m: dict, q: dict, tokens: int, context_sum: int
@@ -55,11 +61,10 @@ def qattn_work(m: dict, q: dict, tokens: int, context_sum: int
     tokens whose contexts add up to `context_sum` positions: the packed
     codes of every live position once per layer, plus each token's f32
     query and output per layer."""
-    L, nq, hd = (m["num_hidden_layers"], m["num_attention_heads"],
-                 m["head_dim"])
-    flops = 4 * nq * hd * context_sum * L
+    width = _attention_width(m)
+    flops = 4 * width * context_sum
     nbytes = (context_sum * kv_bytes_per_position(m, q)
-              + tokens * L * 2 * nq * hd * 4)
+              + tokens * 2 * width * 4)
     return float(flops), float(nbytes)
 
 
@@ -72,15 +77,14 @@ def model_flops(m: dict, decode_contexts: int, decode_tokens: int,
     pairs; each computed prompt position costs the layer matmuls and
     causal attention over its position + 1, and one LM head row per
     request produces its first token."""
-    p_all = matmul_params(m)
-    p_head = m["hidden_size"] * m["vocab_size"]
+    p_layers, p_head = arch.of(m).matmul_params(m)
+    p_all = p_layers + p_head
+    attn = 4.0 * _attention_width(m)
     total = 2.0 * p_all * decode_tokens
-    total += 4.0 * m["num_attention_heads"] * m["head_dim"] \
-        * m["num_hidden_layers"] * decode_contexts
+    total += attn * decode_contexts
     for plen, cached in prefills:
         n = plen - cached
         ctx = (cached + 1 + plen) * n / 2  # sum of positions + 1
         total += 2.0 * (p_all - p_head) * n + 2.0 * p_head
-        total += 4.0 * m["num_attention_heads"] * m["head_dim"] \
-            * m["num_hidden_layers"] * ctx
+        total += attn * ctx
     return total

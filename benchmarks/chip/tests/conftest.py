@@ -10,18 +10,12 @@ sys.path[:0] = [str(HERE.parents[1] / "src"), str(HERE)]
 
 import pytest  # noqa: E402
 
-#: widths of the CPU rehearsals (interpret mode): every cell keeps its own
-#: structure (GQA or MHA, q/k norms, tied head, prefix sharing, loop)
-TINY = dict(num_hidden_layers=2, hidden_size=64, intermediate_size=128,
-            head_dim=16, vocab_size=512)
-
-
 def shrink(cell):
-    """A cell cut to a size the CPU runs in seconds."""
-    c = cell.config
-    kv_per_q = c["num_key_value_heads"] / c["num_attention_heads"]
-    c.update(TINY, num_attention_heads=4,
-             num_key_value_heads=max(1, int(4 * kv_per_q)))
+    """A cell cut to a size the CPU runs in seconds (interpret mode): the
+    model to its architecture's rehearsal widths (`shrink` of its
+    `arch/<arch>.py`), the traffic to a few short requests that keep the
+    mix's prefix sharing and loop."""
+    cell.config = cell.arch.shrink(cell.config)
     m = cell.mix
     m.pop("pool_pages", None)
     m.update(page_size=16, prefill_chunk=32)

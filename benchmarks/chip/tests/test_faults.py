@@ -42,7 +42,7 @@ def test_state_left_unchanged_is_caught(tiny_cell, monkeypatch):
     assert not correct(run(tiny_cell))
 
 
-def test_control_reads_wider_than_the_program():
+def test_control_reads_wider_than_the_program(monkeypatch):
     """The control is the reference in three bfloat16 passes in the
     program's place. Its logits differ from the reference's at `highest`,
     and its gap, read over the same served requests, is the one the
@@ -51,6 +51,8 @@ def test_control_reads_wider_than_the_program():
     so no argmax moves and it reads 0 like the program; at the cells' own
     sizes on the chip it moves argmaxes through the quantizer's bins and
     reads above every cell's limit (`tools/control.py`, PERF.md)."""
+    import dataclasses
+
     import jax.numpy as jnp
     import numpy as np
 
@@ -58,7 +60,10 @@ def test_control_reads_wider_than_the_program():
     import reference
 
     cell = conftest.shrink(harness.load_cell("qwen3-0.6b.doc-decode"))
-    cell.config["vocab_size"] = 4096
+    tiny = cell.arch.model_config
+    monkeypatch.setattr(cell.arch, "model_config",
+                        lambda c: dataclasses.replace(tiny(c),
+                                                      vocab_size=4096))
     out = run(cell, control=True)
     c = out.checks
     assert c["control_gap"]["value"] >= c["logit_gap"]["value"]
@@ -68,12 +73,14 @@ def test_control_reads_wider_than_the_program():
                                      value=2 * c["logit_gap"]["limit"]))
     assert not harness.verdict(harness.control_checks(above))
 
-    params = harness.make_weights(SEED, harness.model_config(cell.config))
+    params = harness.make_weights(SEED,
+                                  cell.arch.model_config(cell.config),
+                                  cell.arch.init_leaf)
     toks = np.random.default_rng(0).integers(0, 4096, 64).astype(np.int32)
     kw = dict(model=reference.freeze(cell.config),
               quant=reference.freeze(cell.config["serving"]["kv_quant"]),
               chunk=32, requant=False, rows=8)
-    hi, lo = (np.asarray(reference.served_logits(
+    hi, lo = (np.asarray(cell.arch.served_logits(
         params, jnp.asarray(toks), jnp.asarray(40, jnp.int32), mode=m, **kw))
         for m in ("highest", "high"))
     assert 0 < np.abs(hi - lo).max() < 1e-2 * np.abs(hi).max()

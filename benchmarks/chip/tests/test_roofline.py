@@ -2,6 +2,7 @@
 published widths."""
 import pytest
 
+import arch
 import harness
 import roofline
 
@@ -32,7 +33,8 @@ def test_deepseek_matmul_params():
 def test_kv_bits(name, per_layer_bits):
     m = cfg(name)
     q = m["serving"]["kv_quant"]
-    assert roofline.kv_bits(q, m["head_dim"], m["num_hidden_layers"]) \
+    layers = arch.of(m).attention_layers(m)
+    assert roofline.kv_bits(q, layers[0][2], len(layers)) \
         == pytest.approx(per_layer_bits)
 
 
@@ -57,3 +59,25 @@ def test_model_flops_by_hand():
     want = 2 * p * 2 + attn * 21 + 2 * (p - head) * 3 + 2 * head \
         + attn * (1 + 2 + 3)
     assert f == pytest.approx(want)
+
+
+@pytest.mark.parametrize("name,flops,work,step_bytes", [
+    ("qwen3-0.6b", 11756922961920.0, (283180040192.0, 64448739584.0),
+     1246596451.1338725),
+    ("deepseek-7b-l8", 39143719698432.0, (161817165824.0, 73128353792.0),
+     4140929461.937107),
+])
+def test_counts_pinned_at_published_widths(name, flops, work, step_bytes,
+                                           monkeypatch):
+    """The counts behind `decode_mfu`, `qattn_roofline` and the spans
+    tool's `decode_step_bytes`, equal to the last bit to what they read
+    before the counts went through the architecture modules."""
+    monkeypatch.syspath_prepend(str(harness.HERE / "tools"))
+    import spans
+
+    m = cfg(name)
+    q = m["serving"]["kv_quant"]
+    assert roofline.model_flops(m, 1234567, 8901,
+                                [(4200, 4096), (700, 0)]) == flops
+    assert roofline.qattn_work(m, q, 8901, 1234567) == work
+    assert spans.decode_step_bytes(m, 1113, 8901, 1234567) == step_bytes

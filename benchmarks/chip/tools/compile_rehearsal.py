@@ -53,8 +53,9 @@ def main(argv=None) -> int:
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
-    cfg = harness.model_config(cell.config)
-    qz = harness.quantizer(cell.config)
+    cfg = cell.arch.model_config(cell.config)
+    qz = harness.quantizer(cell.config["serving"]["kv_quant"],
+                           cell.arch.attention_layers(cell.config))
     tr = traffic_lib.generate(cell.mix, cfg.vocab_size, 0, args.seconds)
     sz = harness.sizes(cell.mix, tr.window, tr.documents)
     if args.pages:
@@ -79,8 +80,8 @@ def main(argv=None) -> int:
     print(f"{args.workload}: max_context {sz.max_context}, pages "
           f"{sz.num_pages}, weights {nbytes(params)} B, pool "
           f"{nbytes((eng.pool.k, eng.pool.v))} B, resident {resident} B")
-    need_pf, need_w = harness.reachable(cell.mix, tr.window, eng)
-    want_pf = set(need_pf) | {harness.prefill_key(eng, len(d), 0)
+    need_pf, need_w = harness.reachable(cell.mix, tr.window, eng, cell.arch)
+    want_pf = set(need_pf) | {cell.arch.prefill_key(eng, len(d), 0)
                               for d in tr.documents}
     skips = sorted({k[1] for k in want_pf})
     kinds = args.kinds.split(",")

@@ -28,6 +28,7 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
+    import arch
     import harness
     from repro.serving import backends, scheduler
 
@@ -35,10 +36,12 @@ def main(argv=None) -> int:
         print("record_trace.py: needs a TPU", file=sys.stderr)
         return 1
     c = harness._json(HERE / "configs" / "qwen3-0.6b.json")
-    c.update(num_hidden_layers=2, vocab_size=4096)
-    cfg = harness.model_config(c)
-    qz = harness.quantizer(c)
-    params = harness.make_weights(0, cfg)
+    a = arch.of(c)
+    layers = a.attention_layers(c)[:2]  # the first two layers only
+    cfg = dataclasses.replace(a.model_config(c), num_layers=len(layers),
+                              vocab_size=4096)
+    qz = harness.quantizer(c["serving"]["kv_quant"], layers)
+    params = harness.make_weights(0, cfg, a.init_leaf)
     sched = scheduler.SchedulerConfig(num_slots=2, page_size=16,
                                       num_pages=64, max_context=256,
                                       prefill_chunk=64, telemetry=False)

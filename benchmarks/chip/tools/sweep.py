@@ -45,7 +45,7 @@ def main(argv=None) -> int:
     rates = [float(r) for r in args.rates.split(",")]
     cell.mix["rate_per_s"] = max(rates)  # the longest request list to warm
     sv = harness.setup(cell, args.seed, args.seconds)
-    vocab = cell.config["vocab_size"]
+    vocab = cell.arch.model_config(cell.config).vocab_size
     for rate in rates:
         cell.mix["rate_per_s"] = rate
         tr = traffic_lib.generate(cell.mix, vocab, args.seed, args.seconds)
